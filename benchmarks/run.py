@@ -265,7 +265,7 @@ def bench_offload_sharded(quick: bool) -> list:
     site count so sharded sites silently falling back to native fail
     the bench-regression gate, not just the timing.
     """
-    from jax.experimental.shard_map import shard_map
+    from jax import shard_map
     from jax.sharding import PartitionSpec as P
 
     from repro.core import PrecisionPolicy, offload

@@ -99,6 +99,9 @@ def adaptive():
 
 
 if __name__ == "__main__":
+    from repro.launch.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
     accuracy_sweep()
     automatic_offload()
     adaptive()

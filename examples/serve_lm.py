@@ -26,7 +26,8 @@ from repro.train.optimizer import AdamW
 from train_lm import PRESETS, ckpt_dir_for  # noqa: E402  (same presets)
 
 
-def main():
+def main(argv=None):
+    """Serve 4 requests; returns the finished requests."""
     ap = argparse.ArgumentParser()
     ap.add_argument("--preset", choices=sorted(PRESETS), default="reduced")
     ap.add_argument("--max-new-tokens", type=int, default=24)
@@ -57,10 +58,17 @@ def main():
                     help="offload every GEMM at this split count "
                          "(a plain PrecisionPolicy; no plan artifact "
                          "needed — handy with --warm-cache-dir)")
+    ap.add_argument("--min-dim", type=int, default=128,
+                    help="with --splits: offload only GEMMs whose m, k "
+                         "and n are all at least this (decode GEMMs "
+                         "have m = one row per slot)")
     ap.add_argument("--warm-cache-dir", default="",
                     help="persist jaxpr-transform decisions/programs "
                          "here so a restarted server warm-starts "
                          "without re-tracing (needs --plan/--splits)")
+    ap.add_argument("--keep-logits", action="store_true",
+                    help="keep each emitted token's logits row on the "
+                         "returned requests (Request.logits)")
     ap.add_argument("--ckpt-dir", default="",
                     help="override the per-preset checkpoint dir")
     ap.add_argument("--metrics-dir", default="",
@@ -75,7 +83,7 @@ def main():
                          "seconds after decoding finishes, so an "
                          "external scraper (the CI smoke) can read "
                          "the final counters")
-    args = ap.parse_args()
+    args = ap.parse_args(argv)
 
     arch, overrides, _, _ = PRESETS[args.preset]
     cfg = get_config(arch).replace(**overrides)
@@ -105,7 +113,8 @@ def main():
     if args.splits:
         from repro.core import PrecisionPolicy
 
-        policy = PrecisionPolicy(default_splits=args.splits)
+        policy = PrecisionPolicy(default_splits=args.splits,
+                                 min_dim=args.min_dim)
     metrics = None
     if args.metrics_dir != "none":
         from repro.obs import MetricsRun
@@ -130,7 +139,8 @@ def main():
                     max_new_tokens=args.max_new_tokens,
                     temperature=args.temperature,
                     seed=args.seed + i,
-                    latency_target_s=args.latency_target_s)
+                    latency_target_s=args.latency_target_s,
+                    logits=[] if args.keep_logits else None)
             for i in range(4)]
     try:
         done = engine.run(reqs)
@@ -151,7 +161,11 @@ def main():
     if metrics is not None:
         print(f"[serve] telemetry: {metrics.sink.path}")
     print("[serve] OK")
+    return done
 
 
 if __name__ == "__main__":
+    from repro.launch.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
     main()

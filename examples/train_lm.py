@@ -12,6 +12,8 @@ Presets (same architecture, different scale):
   tiny     2 x d128 blocks,  512 vocab  (~0.4M params; CI smoke)
   reduced  6 x d256 blocks, 4096 vocab  (~8M params; CPU default)
   100m    12 x d1024 blocks, 16k vocab  (~158M params; a real run)
+  360m    32 x d960 blocks,  49k vocab  (~409M params with an untied
+          lm_head; SmolLM-360M at full width, for one 16 GB TPU v5e)
 
   PYTHONPATH=src python examples/train_lm.py --steps 300
   PYTHONPATH=src python examples/train_lm.py --steps 4 --backend fp64_int8_4
@@ -30,6 +32,9 @@ PRESETS = {
     "tiny": ("tiny", {}, 64, 4),
     "reduced": ("reduced", {}, 128, 4),
     "100m": ("reduced_100m", {}, 256, 8),
+    # Batch 1 x 512: the largest that fits one v5e with fp64_int8_4
+    # (params + AdamW moments are ~5 GB; the emulated step adds ~6 GB).
+    "360m": ("smollm_360m", {}, 512, 1),
 }
 
 
@@ -38,7 +43,7 @@ def ckpt_dir_for(preset: str) -> str:
     return f"runs/ckpt/lm_{preset}"
 
 
-def main():
+def main(argv=None):
     ap = argparse.ArgumentParser()
     ap.add_argument("--steps", type=int, default=300)
     ap.add_argument("--preset", choices=sorted(PRESETS), default="reduced")
@@ -62,7 +67,7 @@ def main():
     ap.add_argument("--ckpt-dir", default="",
                     help="override the per-preset checkpoint dir "
                          "(plans pin training numerics per lineage)")
-    args = ap.parse_args()
+    args = ap.parse_args(argv)
 
     arch, overrides, seq_len, batch = PRESETS[args.preset]
     argv = ["--arch", arch,
@@ -101,4 +106,7 @@ def main():
 
 
 if __name__ == "__main__":
+    from repro.launch.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
     main()

@@ -1,7 +1,7 @@
 """Roofline analysis of dry-run artifacts (paper §4 performance model).
 
-The container has no accelerator, so TPU-side performance claims are
-made through a roofline model evaluated over *dry-run artifacts*: JSON
+Where no chip run exists, TPU-side performance is *modeled* (never
+measured) through a roofline evaluated over *dry-run artifacts*: JSON
 files describing the per-cell work of a lowered program (flops, HBM
 bytes, collective bytes, device count).  :func:`analyze_cell` converts
 one artifact into the three roofline times and names the binding

@@ -38,10 +38,11 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.core.backends import get_backend
+from repro.core.ozaki import real_pair_matmul
 from repro.core.precision import PrecisionPolicy
 
 __all__ = ["MustConfig", "build_system", "run_contour",
-           "relative_errors"]
+           "lapack_contour", "relative_errors"]
 
 
 @dataclasses.dataclass
@@ -98,13 +99,23 @@ def _make_gemm(mode: str) -> Callable[[np.ndarray, np.ndarray],
     :func:`repro.core.backends.get_backend` for the grammar); the bound
     policy selects the ``"f64"`` accumulator, the historical choice of
     this workload (it mirrors ozIMMU on FP64-capable hardware).
+
+    Complex blocks are split into f64 ``(re, im)`` pairs on the host
+    and joined again there: only real f64 arrays reach the device,
+    whose compiler (XLA:TPU) has no complex128 matmul.
     """
     backend = get_backend(mode, policy=PrecisionPolicy(accumulator="f64"))
 
+    def real_gemm(x, y, real_out):
+        return backend(x, y, out_dtype=real_out, site="zblock_lu")
+
+    def pair(x: np.ndarray):
+        return jnp.asarray(x.real), jnp.asarray(x.imag)
+
     def gemm(a: np.ndarray, b: np.ndarray) -> np.ndarray:
-        c = backend(jnp.asarray(a), jnp.asarray(b),
-                    out_dtype=jnp.complex128, site="zblock_lu")
-        return np.asarray(c)
+        cr, ci = real_pair_matmul(real_gemm, pair(a), pair(b),
+                                  jnp.float64)
+        return np.asarray(cr) + 1j * np.asarray(ci)
 
     return gemm
 
@@ -180,14 +191,24 @@ def run_contour(cfg: MustConfig, mode: str,
     * ``etot`` — band-energy analogue:    -1/pi Im sum_k w_k z_k Tr G.
     """
     gemm = _make_gemm(mode)
+    return _sweep(cfg, mode, system,
+                  lambda m_mat: _blocked_inverse(m_mat, cfg.block, gemm))
+
+
+def lapack_contour(cfg: MustConfig, system: Dict[str, np.ndarray]) -> Dict:
+    """:func:`run_contour`'s result with ``G(z)`` from one host LAPACK
+    inverse per energy — a reference that no block GEMM touches."""
+    return _sweep(cfg, "lapack", system, np.linalg.inv)
+
+
+def _sweep(cfg: MustConfig, mode: str, system, inverse) -> Dict:
     h = system["H"]
     z, w = contour_points(cfg)
     n = cfg.n
     g_diag = np.zeros((cfg.n_energies, n), dtype=np.complex128)
     tr_g = np.zeros(cfg.n_energies, dtype=np.complex128)
     for idx, zk in enumerate(z):
-        m_mat = zk * np.eye(n, dtype=np.complex128) - h
-        g = _blocked_inverse(m_mat, cfg.block, gemm)
+        g = inverse(zk * np.eye(n, dtype=np.complex128) - h)
         g_diag[idx] = np.diagonal(g)
         tr_g[idx] = np.trace(g)
     ne = float(-np.imag(np.sum(w * tr_g)) / np.pi)

@@ -105,12 +105,17 @@ class GemmBackend:
 
 
 class DgemmBackend(GemmBackend):
-    """Native XLA matmul — the reference engine (and the A/B control)."""
+    """Native XLA matmul — the reference engine (and the A/B control).
+
+    Runs at ``Precision.HIGHEST``: a TPU's DEFAULT f32 matmul is one
+    bf16 pass, too coarse for the reference every emulation error
+    (calibration, the numerics monitor) is measured against.
+    """
 
     def matmul(self, a, b, *, out_dtype=None, num_splits=None,
                site: str = "default"):
         del num_splits, site
-        c = a @ b
+        c = jnp.matmul(a, b, precision=jax.lax.Precision.HIGHEST)
         return c.astype(out_dtype) if out_dtype is not None else c
 
 
@@ -145,9 +150,11 @@ class OzakiBackend(GemmBackend):
 class PallasBackend(OzakiBackend):
     """Fused Pallas split-GEMM kernel (:mod:`repro.kernels.ops`).
 
-    Interpret mode is selected automatically off-TPU so the same spec
-    string works everywhere.  Complex operands decompose into four real
-    kernel launches (same scheme as the jnp reference path).
+    The kernel compiles through Mosaic on a TPU and runs in the Pallas
+    interpreter on the CPU (the test platform); any other platform is
+    refused rather than silently interpreted.  Complex operands
+    decompose into four real kernel launches (same scheme as the jnp
+    reference path).
 
     Block sizes come from the analytic model in
     :mod:`repro.kernels.tile_model` — consulted per (m, k, n, s), no
@@ -159,7 +166,12 @@ class PallasBackend(OzakiBackend):
     def __init__(self, spec, policy, splits: Optional[int] = None,
                  fused: bool = False):
         super().__init__(spec, policy, splits)
-        self.interpret = jax.default_backend() != "tpu"
+        platform = jax.default_backend()
+        if platform not in ("tpu", "cpu"):
+            raise RuntimeError(
+                f"{spec!r} needs a TPU (or the CPU, interpreted); the "
+                f"default backend is {platform!r}")
+        self.interpret = platform == "cpu"
         self.fused = fused
 
     def tile_decision(self, m, k, n, num_splits, dtype=None):

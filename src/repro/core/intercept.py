@@ -15,21 +15,22 @@ What the transform covers:
 * batched and rank-N ``dot_general`` — batch/free/contraction axes are
   normalized to ``(B, M, K) @ (B, K, N)`` by transpose+reshape and the
   2-D backend is ``vmap``-ped over the merged batch axis (loop-free);
-* sites inside ``pjit`` / ``remat`` (``jax.checkpoint``) bodies, which
-  are inlined transparently;
+* sites inside nested ``jit`` / ``remat2`` (``jax.checkpoint``) bodies,
+  which are inlined transparently;
 * sites inside ``scan`` / ``while`` / ``cond`` bodies, which are
   rebuilt with transformed bodies;
-* sites inside ``shard_map`` / ``pmap`` bodies (multi-device SPMD):
+* sites inside ``shard_map`` bodies (multi-device SPMD; ``jax.pmap``
+  stages as a ``shard_map`` too, so its sites are ``shmap`` sites):
   the body is rebuilt around the rewriter under the same mesh and
-  partition specs (``check_rep=False``); collective-adjacent equations
+  partition specs (``check_vma=False``); collective-adjacent equations
   are canonicalized — plain collectives re-bind as-is, while the
-  replication-rewrite artifacts are undone (``pbroadcast`` dropped,
-  ``psum2`` -> ``lax.psum``; replaying them verbatim corrupts the
-  transpose rule) — and the size gate sees the *per-shard* operand
+  varying-axis artifacts are undone (``pvary`` dropped,
+  ``psum_invariant`` -> ``lax.psum``; replaying them verbatim corrupts
+  the transpose rule) — and the size gate sees the *per-shard* operand
   shapes, so every device runs the same Ozaki split schedule a
   single-device run would;
 * ``jit``-ted inner functions with ``NamedSharding``-annotated
-  arguments: the ``pjit`` body is inlined for site discovery and its
+  arguments: the ``jit`` body is inlined for site discovery and its
   in/out shardings are re-applied as ``with_sharding_constraint``, so
   the transformed program still partitions the same way under
   ``jax.jit``;
@@ -46,7 +47,7 @@ Site naming is structural and **shared verbatim** between
 ``dot_general`` sites of a scope in program order (call-like primitives
 are inlined into the enclosing scope), and control-flow/SPMD bodies
 extend the path — ``scan0/dot1``, ``while2/cond/dot0``,
-``cond1/br0/dot0``, ``shmap0/dot1``, ``pmap0/scan0/dot0``.
+``cond1/br0/dot0``, ``shmap0/dot1``, ``shmap0/scan0/dot0``.
 ``PrecisionPolicy.site_splits`` keys against exactly these names, which
 is the paper's "enumerate first, then tune per site" workflow.
 
@@ -81,15 +82,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-try:  # jax >= 0.4.35 exposes the jaxpr IR under jax.extend.core
-    from jax.extend import core as jex_core
-except ImportError:  # pragma: no cover - older jax
-    from jax import core as jex_core
-
-try:  # not auto-imported by `import jax`
-    from jax import export as _jax_export
-except ImportError:  # pragma: no cover - very old jax
-    _jax_export = None
+from jax import export as _jax_export  # not auto-imported by `import jax`
+from jax.extend import core as jex_core
 
 from .backends import GemmBackend, get_backend
 from .precision import PrecisionPolicy
@@ -99,9 +93,10 @@ __all__ = ["offload", "site_report", "transform_jaxpr", "Site",
 
 # Call-like primitives whose body jaxpr is inlined into the enclosing
 # scope: they neither change shapes nor iterate, so their sites share
-# the enclosing scope's dot numbering.  ("remat2" is the actual
-# primitive behind jax.checkpoint/jax.remat; inlining it only trades
-# the rematerialization schedule, not values or derivatives.)
+# the enclosing scope's dot numbering.  ("jit" is a nested jax.jit;
+# "remat2" is the primitive behind jax.checkpoint/jax.remat, and
+# inlining it only trades the rematerialization schedule, not values
+# or derivatives.)
 # Control-flow primitives (scan/while/cond) get their own scope path
 # and dedicated rebuild handlers below.  Custom-derivative calls
 # (custom_jvp_call / custom_vjp_call*) are deliberately NOT inlined:
@@ -110,8 +105,7 @@ __all__ = ["offload", "site_report", "transform_jaxpr", "Site",
 # silently replace the user's rule under jax.grad.  They take the
 # default native re-bind and their internal matmuls stay native; wrap
 # the function's *caller* if those sites matter.
-_INLINE_PRIMITIVES = {"pjit", "closed_call", "remat", "remat2",
-                      "checkpoint"}
+_INLINE_PRIMITIVES = {"jit", "closed_call", "remat2"}
 
 
 def _check_overrides(policy: PrecisionPolicy, decisions) -> None:
@@ -149,8 +143,8 @@ class Site:
     ``m``/``k``/``n``/``batch``, the static trip multiplicity ``mult``
     (how many times one step executes this site — the enclosing
     ``scan`` lengths multiplied out), the enclosing SPMD axes
-    ``spmd_axes`` (``(name, size)`` pairs of the ``shard_map``/``pmap``
-    meshes the site runs under), the resolved per-site ``backend``
+    ``spmd_axes`` (``(name, size)`` pairs of the ``shard_map`` meshes
+    the site runs under), the resolved per-site ``backend``
     spec, ``eligible`` — whether the site passed the dtype and size
     gates (a plan-demoted site is eligible but not offloaded) — and,
     for Pallas-family backends, ``tiles``: the analytic tile model's
@@ -262,14 +256,6 @@ def _walk_sites(jaxpr, prefix: str = "", dot_counter=None,
                         f"{prefix}shmap{flow_counter[0]}/", out=out,
                         mult=mult,
                         spmd=spmd + _mesh_axes(eqn.params["mesh"]))
-            flow_counter[0] += 1
-        elif prim == "xla_pmap":
-            body = eqn.params["call_jaxpr"]
-            axis = ((str(eqn.params["axis_name"]),
-                     int(eqn.params["global_axis_size"])),)
-            _walk_sites(getattr(body, "jaxpr", body),
-                        f"{prefix}pmap{flow_counter[0]}/", out=out,
-                        mult=mult, spmd=spmd + axis)
             flow_counter[0] += 1
         elif prim == "scan":
             body = eqn.params["jaxpr"]
@@ -467,7 +453,7 @@ def transform_jaxpr(closed, policy: PrecisionPolicy,
     backend call — never inside the ``custom_vjp`` (debug effects
     cannot stage through custom-derivative rules) — so inside a
     ``scan`` body it fires once per iteration and inside a
-    ``shard_map``/``pmap`` body once per mesh shard.  The callback
+    ``shard_map`` body once per mesh shard.  The callback
     deliberately carries **zero** array operands: the payload is
     host-built at transform time, the hook adds no device compute, and
     — load-bearing, not just an optimization — an operand-carrying
@@ -574,12 +560,12 @@ def transform_jaxpr(closed, policy: PrecisionPolicy,
                 else:
                     outvals = [eqn.primitive.bind(*invals, **eqn.params)]
             elif prim in _INLINE_PRIMITIVES:
-                # Inlining a pjit discards its partitioning params, so
+                # Inlining a jit discards its partitioning params, so
                 # NamedSharding annotations on the inner jit are
                 # re-applied as sharding constraints around the inlined
                 # body — offload(jax.jit(fn, in_shardings=...)) keeps
                 # partitioning exactly as the user declared it.
-                if prim == "pjit":
+                if prim == "jit":
                     invals = _apply_shardings(
                         invals, eqn.params.get("in_shardings"))
                 outvals = None
@@ -591,7 +577,7 @@ def transform_jaxpr(closed, policy: PrecisionPolicy,
                     outvals = eqn.primitive.bind(*invals, **eqn.params)
                     if not eqn.primitive.multiple_results:
                         outvals = [outvals]
-                elif prim == "pjit":
+                elif prim == "jit":
                     outvals = _apply_shardings(
                         outvals, eqn.params.get("out_shardings"))
             elif prim == "shard_map":
@@ -599,22 +585,18 @@ def transform_jaxpr(closed, policy: PrecisionPolicy,
                 flow_counter[0] += 1
                 outvals = _eval_shard_map(eqn, invals, eval_rewritten,
                                           pfx)
-            elif prim == "xla_pmap":
-                pfx = f"{prefix}pmap{flow_counter[0]}/"
-                flow_counter[0] += 1
-                outvals = _eval_pmap(eqn, invals, eval_rewritten, pfx)
-            elif prim == "pbroadcast":
-                # shard_map's replication-tracking rewrite (check_rep)
-                # stages pbroadcast markers into the body; they are
-                # physically the identity, and replaying them under the
-                # check_rep=False rebuild corrupts the transpose rule —
+            elif prim == "pvary":
+                # shard_map's varying-axis tracking (check_vma) stages
+                # pvary markers into the body; they are physically the
+                # identity, and replaying them under the
+                # check_vma=False rebuild corrupts the transpose rule —
                 # drop them.
                 outvals = list(invals)
-            elif prim == "psum2":
-                # Same story for psum2 (the rewritten psum): replay it
-                # as the plain collective so values AND cotangents come
-                # out right under the check_rep=False rebuild.  One
-                # bind over *all* operands: a bucketed gradient
+            elif prim == "psum_invariant":
+                # Same story for psum_invariant (the tracked psum):
+                # replay it as the plain collective so values AND
+                # cotangents come out right under the check_vma=False
+                # rebuild.  One bind over *all* operands: a bucketed gradient
                 # all-reduce stages one multi-operand psum per bucket,
                 # and replaying it per operand would silently de-fuse
                 # the buckets the overlap path exists to create.
@@ -717,7 +699,7 @@ def _eval_cond(eqn, invals, eval_body, prefix):
 
 
 def _apply_shardings(vals, shardings):
-    """Constrain ``vals`` to the concrete shardings of a pjit eqn.
+    """Constrain ``vals`` to the concrete shardings of a jit eqn.
 
     Entries that are not actual :class:`jax.sharding.Sharding` objects
     (``UnspecifiedValue`` placeholders from a plain ``jax.jit``) leave
@@ -733,56 +715,28 @@ def _apply_shardings(vals, shardings):
     return out
 
 
-def _names_to_specs(names_seq, var_seq):
-    """shard_map ``in_names``/``out_names`` dicts -> PartitionSpecs."""
-    return tuple(
-        jax.sharding.PartitionSpec(
-            *[names.get(d) for d in range(v.aval.ndim)])
-        for names, v in zip(names_seq, var_seq))
-
-
 def _eval_shard_map(eqn, invals, eval_body, prefix):
     """Rebuild a ``shard_map`` with its body routed through the rewriter.
 
-    The body is re-traced under the original mesh and partition specs
-    (recovered from ``in_names``/``out_names``), so per-shard sites run
-    the backend on their local block and collectives replay in place.
-    ``check_rep=False``: the recorded body already carries the
-    replication-rewrite artifacts (``psum2``/``pbroadcast``), which the
-    evaluator canonicalizes back to plain collectives — running the
-    rewrite machinery again on top of them would double-apply it (and
-    it has no rules for the offloaded sites' ``custom_vjp`` wrappers).
+    The body is re-traced under the original mesh, manual axes and
+    partition specs, so per-shard sites run the backend on their local
+    block and collectives replay in place.  ``check_vma=False``: the
+    recorded body already carries the varying-axis artifacts
+    (``pvary``/``psum_invariant``), which the evaluator canonicalizes
+    back to plain collectives — running the tracking again on top of
+    them would double-apply it (and it has no rules for the offloaded
+    sites' ``custom_vjp`` wrappers).
     """
-    from jax.experimental import shard_map as _shard_map  # deferred
-
     p = eqn.params
     body = p["jaxpr"]
-    in_specs = _names_to_specs(p["in_names"], eqn.invars)
-    out_specs = _names_to_specs(p["out_names"], eqn.outvars)
 
     def body_fun(*args):
         return tuple(eval_body(body, (), list(args), prefix))
 
-    rebuilt = _shard_map.shard_map(
-        body_fun, mesh=p["mesh"], in_specs=in_specs,
-        out_specs=out_specs, check_rep=False)
-    return list(rebuilt(*invals))
-
-
-def _eval_pmap(eqn, invals, eval_body, prefix):
-    """Rebuild a ``pmap`` with its per-device body rewritten."""
-    p = eqn.params
-    body = p["call_jaxpr"]
-    jaxpr = getattr(body, "jaxpr", body)
-    consts = getattr(body, "consts", ())
-
-    def body_fun(*args):
-        return tuple(eval_body(jaxpr, consts, list(args), prefix))
-
-    rebuilt = jax.pmap(body_fun, axis_name=p["axis_name"],
-                       in_axes=p["in_axes"], out_axes=p["out_axes"],
-                       devices=p.get("devices"),
-                       backend=p.get("backend"))
+    rebuilt = jax.shard_map(
+        body_fun, mesh=p["mesh"], in_specs=p["in_specs"],
+        out_specs=p["out_specs"], axis_names=p["manual_axes"],
+        check_vma=False)
     return list(rebuilt(*invals))
 
 
@@ -892,8 +846,6 @@ class _DiskCache:
         except OSError:
             return None, None
         exported = None
-        if _jax_export is None:
-            return raw, None
         try:
             with open(self._path(key, "bin"), "rb") as f:
                 exported = _jax_export.deserialize(bytearray(f.read()))
@@ -957,8 +909,6 @@ def _export_entry(transformed, out_tree, args, kwargs):
                                   transformed.consts, *flat)
         return jax.tree_util.tree_unflatten(out_tree, out)
 
-    if _jax_export is None:  # pragma: no cover - very old jax
-        return None
     try:
         exp = _jax_export.export(jax.jit(run))(*args, **kwargs)
         return exp.serialize()
@@ -987,7 +937,7 @@ def offload(fn, policy: PrecisionPolicy | None = None, *,
     program is cached and later calls only evaluate it, so
     ``jax.jit(offload(fn, policy))`` compiles with no per-call
     re-tracing.  Batched/rank-N sites, sites inside ``scan``/``while``/
-    ``cond``/``shard_map``/``pmap`` bodies, and reverse-mode AD are all
+    ``cond``/``shard_map`` bodies, and reverse-mode AD are all
     supported; see the module docstring.
 
     ``plan`` accepts a :class:`repro.tune.PrecisionPlan`: when no

@@ -41,6 +41,7 @@ __all__ = [
     "SLICE_BITS",
     "complex_matmul_via_real",
     "num_pair_gemms",
+    "real_pair_matmul",
     "pair_indices",
     "slice_matrix",
     "ozaki_matmul",
@@ -74,13 +75,33 @@ def pair_indices(num_splits: int) -> tuple[np.ndarray, np.ndarray]:
     return ii, jj
 
 
+def _exact_pow2(e: jax.Array, dtype) -> jax.Array:
+    """``2.0**e`` for an int32 array ``e``, exact wherever ``dtype``
+    holds the power (inf above its range, as ``ldexp`` gives).
+
+    A product of constant powers of two, one per set bit of ``|e|``:
+    every partial product lies between 1 and the result, so none
+    rounds.  ``jnp.exp2`` is approximate on some backends, and
+    ``jnp.ldexp`` on f64 bitcasts through s64, which XLA:TPU refuses.
+    """
+    dtype = np.dtype(dtype)
+    out = jnp.ones(e.shape, dtype)
+    mag = jnp.abs(e)
+    for bit in range(int(np.finfo(dtype).maxexp).bit_length()):
+        with np.errstate(over="ignore"):
+            up = np.array(2.0, dtype) ** (1 << bit)
+        down = np.array(0.5, dtype) ** (1 << bit)
+        factor = jnp.where(e < 0, down, up)
+        out = jnp.where((mag >> bit) & 1 == 1, out * factor, out)
+    return out
+
+
 def _pow2_scale(x: jax.Array, axis: int) -> jax.Array:
     """Per-row/col power-of-two scale sigma with |x| / sigma <= 1/2."""
     absmax = jnp.max(jnp.abs(x), axis=axis, keepdims=True)
     # exponent e with 2**e >= 2*absmax; zero rows get sigma = 1.
-    # NB: jnp.exp2 is approximate on some backends, ldexp is exact.
     e = jnp.where(absmax > 0, jnp.ceil(jnp.log2(absmax)) + 1.0, 0.0)
-    return jnp.ldexp(jnp.ones_like(absmax), e.astype(jnp.int32))
+    return _exact_pow2(e.astype(jnp.int32), absmax.dtype)
 
 
 def slice_matrix(x: jax.Array, num_splits: int, axis: int,
@@ -137,35 +158,40 @@ def _two_sum(acc, term):
     return s, err
 
 
+def _fold_df32(acc, comp, prod, w):
+    """Fold one INT32 pair product, weighted by ``w``, into (acc, comp).
+
+    ``prod`` is split exactly into hi/lo float32 parts (hi is integral
+    and |prod| stays far below 2**31 for practical k/slice_bits, so the
+    cast back to int32 is exact — and unlike int64 it does not warn
+    when jax_enable_x64 is off).  ``w`` is a non-negative power of two,
+    so the weighting is exact in f32.  The one step shared by the jnp
+    path and the Pallas kernels, which keeps them bit-identical.
+    """
+    hi = prod.astype(jnp.float32)
+    lo = (prod - hi.astype(prod.dtype)).astype(jnp.float32)
+    acc, err = _two_sum(acc, hi * w)
+    comp = comp + err
+    acc, err = _two_sum(acc, lo * w)
+    return acc, comp + err
+
+
 def _accumulate_df32(prod, shifts, slice_bits, num_splits):
     """Compensated double-float32 accumulation.
 
-    Each INT32 pair product is split exactly into hi/lo float32 parts,
-    weighted by a *non-negative* power-of-two shift (so the weighting is
-    exact in f32 and never underflows), and folded into a compensated
-    (sum, err) float32 pair.  The caller divides by the deferred scale
-    2**(w*(s+1)) at combine time.
+    Each INT32 pair product is folded into a compensated (sum, err)
+    float32 pair by :func:`_fold_df32`, with a *non-negative*
+    power-of-two weight (exact in f32, never underflows).  The caller
+    divides by the deferred scale 2**(w*(s+1)) at combine time.
     """
     smax = num_splits - 1
-    hi = prod.astype(jnp.float32)
-    # hi is integral and |prod| stays far below 2**31 for practical
-    # k/slice_bits, so casting back to the int32 input dtype is exact —
-    # and unlike int64 it does not warn when jax_enable_x64 is off
-    # (the LM examples train in pure float32 without x64).
-    lo = (prod - hi.astype(prod.dtype)).astype(jnp.float32)
     # Positive shifts: pair (i, j) gets weight 2**(w*(smax - i - j)).
     # Exact host-side powers of two (jnp.exp2 is approximate on CPU).
     w = np.ldexp(np.float32(1.0), (smax - np.asarray(shifts)) * slice_bits)
-    w = jnp.asarray(w, jnp.float32)[:, None, None]
-    t_hi = hi * w  # exact: power-of-two weight, well inside f32 range
-    t_lo = lo * w
     acc = jnp.zeros(prod.shape[1:], jnp.float32)
     comp = jnp.zeros(prod.shape[1:], jnp.float32)
     for p in range(prod.shape[0]):  # pairs ordered large -> small
-        acc, err = _two_sum(acc, t_hi[p])
-        comp = comp + err
-        acc, err = _two_sum(acc, t_lo[p])
-        comp = comp + err
+        acc, comp = _fold_df32(acc, comp, prod[p], jnp.float32(w[p]))
     deferred = 2.0 ** (-slice_bits * (smax + 2))
     return acc, comp, deferred
 
@@ -198,21 +224,33 @@ def _real_ozaki(a, b, num_splits, accumulator, out_dtype, slice_bits):
     return c * scale
 
 
+def real_pair_matmul(real_matmul, a, b, real_out):
+    """Complex product of ``(re, im)`` pairs from four real GEMMs.
+
+    ``real_matmul(x, y, real_out)`` runs one real matmul.  Returns the
+    ``(re, im)`` pair of the product.  Callers that must keep complex
+    arrays off the device (XLA:TPU has no complex128 matmul) split and
+    join on the host and pass real pairs straight in.
+    """
+    ar, ai = a
+    br, bi = b
+    cr = real_matmul(ar, br, real_out) - real_matmul(ai, bi, real_out)
+    ci = real_matmul(ar, bi, real_out) + real_matmul(ai, br, real_out)
+    return cr, ci
+
+
 def complex_matmul_via_real(real_matmul, a, b, out_dtype):
     """Complex product from four real GEMMs — shared by every engine.
 
-    ``real_matmul(x, y, real_out_dtype)`` runs one real matmul; the
-    decomposition, the real working dtype (f64 for complex128, f32
-    otherwise) and the final cast live here so the jnp and Pallas
-    paths cannot drift apart.
+    The decomposition (:func:`real_pair_matmul`), the real working
+    dtype (f64 for complex128, f32 otherwise) and the final cast live
+    here so the jnp and Pallas paths cannot drift apart.
     """
     out_dtype = jnp.dtype(out_dtype)
     real_out = jnp.float64 if out_dtype in (jnp.complex128, jnp.float64) \
         else jnp.float32
-    ar, ai = jnp.real(a), jnp.imag(a)
-    br, bi = jnp.real(b), jnp.imag(b)
-    cr = real_matmul(ar, br, real_out) - real_matmul(ai, bi, real_out)
-    ci = real_matmul(ar, bi, real_out) + real_matmul(ai, br, real_out)
+    cr, ci = real_pair_matmul(real_matmul, (jnp.real(a), jnp.imag(a)),
+                              (jnp.real(b), jnp.imag(b)), real_out)
     return jax.lax.complex(cr, ci).astype(out_dtype)
 
 

@@ -2,11 +2,12 @@
 
 One kernel computes the whole emulated GEMM: the grid walks
 ``(m-tiles, n-tiles, slice-pairs, k-tiles)`` and every step issues one
-INT8xINT8->INT32 tile product on the MXU, weights it by the pair's
-power-of-two shift, and folds it into a compensated float32 accumulator
-held in the revisited output tiles (TwoSum, so the ~48-bit "df32"
-accuracy of the reference path survives the single-f32 output
-constraint of FP64-free hardware).  The kernel emits separate hi/lo
+INT8xINT8->INT32 tile product on the MXU and sums it over the k-tiles
+in an int32 VMEM scratch; after a pair's last k-tile the full product
+is weighted by the pair's power-of-two shift and folded into a
+compensated float32 accumulator held in the revisited output tiles
+(TwoSum, the reference path's own step, so the ~48-bit "df32" accuracy
+survives the single-f32 output constraint of FP64-free hardware).  The kernel emits separate hi/lo
 f32 outputs; the wrapper combines them in the requested output dtype.
 
 **v2 (default,** :func:`split_gemm_pallas` **)** never materializes
@@ -17,9 +18,8 @@ the BlockSpec index maps; the pair weight is reconstructed in-kernel
 from its integer exponent by exact bit manipulation.  HBM slice reads
 drop from the O(s²·m·k) gathered pair copies of v1 to the O(s·m·k)
 slice arrays themselves (see :mod:`repro.kernels.tile_model`, the
-accounting authority).  The legacy pair-materializing kernel survives
-as :func:`split_gemm_pallas_v1` for A/B equivalence tests and the
-traffic benchmarks.
+accounting authority).  The v1 kernel is kept as
+:func:`split_gemm_pallas_v1` for A/B equivalence tests.
 
 **Fused slicing** (:func:`split_gemm_pallas_fused`, opt-in via
 ``ozaki_matmul(..., fuse_slicing=True)`` or the ``pallas_int8*:fused``
@@ -55,11 +55,10 @@ import functools
 
 import jax
 import jax.numpy as jnp
-import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.core.ozaki import (SLICE_BITS, _two_sum, pair_indices,
+from repro.core.ozaki import (SLICE_BITS, _fold_df32, pair_indices,
                               slice_matrix)
 from repro.kernels import slicing, tile_model
 from repro.kernels.tile_model import LANE, SUBLANE_INT8, align_up
@@ -72,39 +71,56 @@ __all__ = [
 ]
 
 
-def _pow2_f32(e):
-    """Exact f32 ``2.0**e`` from an int32 exponent via bit assembly.
+def _pow2_f32(e, shape):
+    """Exact f32 ``2.0**e``, broadcast to ``shape``, from an int32
+    scalar exponent via bit assembly.
 
     Valid for e in [-126, 127]; the kernels only need non-negative
     shifts <= (s-1)*slice_bits.  Avoids ``exp2`` (inexact on some
-    backends) and table lookups inside the kernel.
+    backends) and table lookups inside the kernel.  The bits are
+    broadcast before the bitcast because Mosaic bitcasts vectors only,
+    and ``e`` is a scalar read from SMEM.
     """
-    return jax.lax.bitcast_convert_type(
-        ((e + 127) << 23).astype(jnp.int32), jnp.float32)
+    bits = jnp.full(shape, (e + 127) << 23, jnp.int32)
+    return jax.lax.bitcast_convert_type(bits, jnp.float32)
 
 
-def _accumulate(hi_ref, lo_ref, part, w, first):
-    """Weight one INT32 tile product and fold it into the hi/lo refs.
+def _accumulate(hi_ref, lo_ref, acc_ref, part, wexp):
+    """Sum one INT32 tile product over the k-tiles, then fold it in.
 
-    The shared tail of every kernel body: the power-of-two weight keeps
-    the term exact in f32 (the int32 partial fits f32's mantissa for
-    k-tiles <= 2**(24-2*slice_bits+2)), and the TwoSum is the same
-    compensated step as the jnp df32 reference path — shared arithmetic
-    keeps the paths bit-identical by construction.
+    The shared tail of every kernel body.  The k reduction stays in
+    int32 (exact) in the ``acc_ref`` scratch; after the last k-tile the
+    pair's full product is weighted by ``2**wexp`` and folded into the
+    hi/lo refs by :func:`repro.core.ozaki._fold_df32`, the step the jnp
+    df32 path takes for the same pair.  So the kernel equals that path
+    bit for bit at any tiling, and its compensation sees one term per
+    pair rather than one per k-tile.
     """
-    @pl.when(first)
+    p = pl.program_id(2)
+    kt = pl.program_id(3)
+
+    @pl.when(kt == 0)
+    def _():
+        acc_ref[...] = part
+
+    @pl.when(kt > 0)
+    def _():
+        acc_ref[...] += part
+
+    @pl.when(jnp.logical_and(p == 0, kt == 0))
     def _():
         hi_ref[...] = jnp.zeros_like(hi_ref)
         lo_ref[...] = jnp.zeros_like(lo_ref)
 
-    term = part.astype(jnp.float32) * w
-    s, err = _two_sum(hi_ref[...], term)
-    hi_ref[...] = s
-    lo_ref[...] = lo_ref[...] + err
+    @pl.when(kt == pl.num_programs(3) - 1)
+    def _():
+        prod = acc_ref[...]
+        hi_ref[...], lo_ref[...] = _fold_df32(
+            hi_ref[...], lo_ref[...], prod, _pow2_f32(wexp, prod.shape))
 
 
 def _split_gemm_kernel_v2(ii_ref, jj_ref, wexp_ref, a_ref, b_ref,
-                          hi_ref, lo_ref):
+                          hi_ref, lo_ref, acc_ref):
     """Grid: (m/bm, n/bn, num_pairs, k/bk). One INT8 tile product.
 
     The slice pair for step ``p`` was already selected by the BlockSpec
@@ -113,24 +129,20 @@ def _split_gemm_kernel_v2(ii_ref, jj_ref, wexp_ref, a_ref, b_ref,
     Output tiles are revisited across the two reduction grid dims
     (pair index, k-tile) and double as the compensated accumulator.
     """
-    p = pl.program_id(2)
-    kt = pl.program_id(3)
     del ii_ref, jj_ref  # consumed by the index maps
     part = jax.lax.dot_general(
         a_ref[0], b_ref[0],
         dimension_numbers=(((1,), (0,)), ((), ())),
         preferred_element_type=jnp.int32)
-    w = _pow2_f32(wexp_ref[p])
-    _accumulate(hi_ref, lo_ref, part, w,
-                jnp.logical_and(p == 0, kt == 0))
+    _accumulate(hi_ref, lo_ref, acc_ref, part,
+                wexp_ref[pl.program_id(2)])
 
 
 def _split_gemm_kernel_fused(ii_ref, jj_ref, wexp_ref, ah_ref, al_ref,
-                             bh_ref, bl_ref, hi_ref, lo_ref, *,
+                             bh_ref, bl_ref, hi_ref, lo_ref, acc_ref, *,
                              num_splits, slice_bits):
     """Fused variant: quantize f32-pair tiles to int8 in VMEM first."""
     p = pl.program_id(2)
-    kt = pl.program_id(3)
     a_q = slicing.quantize_tile(ah_ref[...], al_ref[...], ii_ref[p],
                                 num_splits, slice_bits)
     b_q = slicing.quantize_tile(bh_ref[...], bl_ref[...], jj_ref[p],
@@ -139,21 +151,20 @@ def _split_gemm_kernel_fused(ii_ref, jj_ref, wexp_ref, ah_ref, al_ref,
         a_q, b_q,
         dimension_numbers=(((1,), (0,)), ((), ())),
         preferred_element_type=jnp.int32)
-    w = _pow2_f32(wexp_ref[p])
-    _accumulate(hi_ref, lo_ref, part, w,
-                jnp.logical_and(p == 0, kt == 0))
+    _accumulate(hi_ref, lo_ref, acc_ref, part, wexp_ref[p])
 
 
-def _split_gemm_kernel_v1(a_ref, b_ref, w_ref, hi_ref, lo_ref):
-    """Legacy v1 body: operands are pre-gathered (pairs, ., .) arrays."""
-    p = pl.program_id(2)
-    kt = pl.program_id(3)
-    part = jax.lax.dot_general(
-        a_ref[0], b_ref[0],
-        dimension_numbers=(((1,), (0,)), ((), ())),
-        preferred_element_type=jnp.int32)
-    _accumulate(hi_ref, lo_ref, part, w_ref[0],
-                jnp.logical_and(p == 0, kt == 0))
+def _compiler_params(bm: int, bn: int, bk: int, fused: bool = False):
+    """Mosaic's scoped-VMEM limit for one kernel's blocks.
+
+    The tile model's footprint of the step (an upper bound on what
+    Mosaic allocates), never below the model's budget: blocks the model
+    picks itself fit the budget, and explicit larger blocks get what
+    they need.  A v5e core has 128 MiB of VMEM.
+    """
+    need = tile_model.vmem_bytes(bm, bn, bk, fused=fused)
+    return pltpu.CompilerParams(vmem_limit_bytes=max(
+        need, tile_model.DEFAULT_PARAMS.vmem_budget))
 
 
 def _pad_to(x, multiple, axis):
@@ -199,7 +210,7 @@ def split_gemm_pallas(a_sl, b_sl, num_splits: int,
       deferred shift keeps all in-kernel weights >= 1 so they stay
       exact in f32).
 
-    Unlike v1 this never gathers slice pairs: the scalar-prefetch
+    This never gathers slice pairs: the scalar-prefetch
     schedule drives the BlockSpec index maps straight into the
     ``(s, ., .)`` slice arrays, so HBM holds (and the grid reads) s
     slice layers instead of s*(s+1)/2 pair copies.
@@ -233,6 +244,7 @@ def split_gemm_pallas(a_sl, b_sl, num_splits: int,
             pl.BlockSpec((bm, bn),
                          lambda i, j, p, kt, ii, jj, we: (i, j)),
         ],
+        scratch_shapes=[pltpu.VMEM((bm, bn), jnp.int32)],
     )
     hi, lo = pl.pallas_call(
         _split_gemm_kernel_v2,
@@ -241,6 +253,7 @@ def split_gemm_pallas(a_sl, b_sl, num_splits: int,
             jax.ShapeDtypeStruct((mp, np_), jnp.float32),
             jax.ShapeDtypeStruct((mp, np_), jnp.float32),
         ],
+        compiler_params=_compiler_params(bm, bn, bk),
         interpret=interpret,
     )(ii, jj, wexp, a_sl, b_sl)
     return hi[:m, :n], lo[:m, :n]
@@ -295,6 +308,7 @@ def split_gemm_pallas_fused(a_hi, a_lo, b_hi, b_lo, num_splits: int,
             pl.BlockSpec((bm, bn),
                          lambda i, j, p, kt, ii, jj, we: (i, j)),
         ],
+        scratch_shapes=[pltpu.VMEM((bm, bn), jnp.int32)],
     )
     hi, lo = pl.pallas_call(
         functools.partial(_split_gemm_kernel_fused,
@@ -304,6 +318,7 @@ def split_gemm_pallas_fused(a_hi, a_lo, b_hi, b_lo, num_splits: int,
             jax.ShapeDtypeStruct((mp, np_), jnp.float32),
             jax.ShapeDtypeStruct((mp, np_), jnp.float32),
         ],
+        compiler_params=_compiler_params(bm, bn, bk, fused=True),
         interpret=interpret,
     )(ii, jj, wexp, a_hi, a_lo, b_hi, b_lo)
     return hi[:m, :n], lo[:m, :n]
@@ -320,17 +335,14 @@ def split_gemm_pallas_v1(a_sl, b_sl, num_splits: int,
 
     Kept as the A/B reference for the v2 traffic claim (see
     ``tile_model.traffic``) and for bit-identity regression tests —
-    same schedule, same TwoSum, so v1 == v2 exactly.  Do not use for
-    new call sites: it stages s*(s+1)/2 pair copies in HBM.
+    same schedule, same accumulation, so v1 == v2 exactly.  Do not use
+    for new call sites: it stages s*(s+1)/2 pair copies in HBM.
     """
     _, m, k = a_sl.shape
     _, _, n = b_sl.shape
-    ii, jj = pair_indices(num_splits)
-    smax = num_splits - 1
-    a_pairs = jnp.take(a_sl, jnp.asarray(ii), axis=0)
-    b_pairs = jnp.take(b_sl, jnp.asarray(jj), axis=0)
-    weights = jnp.asarray(
-        np.ldexp(np.float32(1.0), (smax - (ii + jj)) * slice_bits))
+    ii, jj, wexp = _pair_schedule_arrays(num_splits, slice_bits)
+    a_pairs = jnp.take(a_sl, ii, axis=0)
+    b_pairs = jnp.take(b_sl, jj, axis=0)
 
     bm = _block(m, block_m, SUBLANE_INT8)
     bn = _block(n, block_n, LANE)
@@ -339,27 +351,37 @@ def split_gemm_pallas_v1(a_sl, b_sl, num_splits: int,
     b_pairs = _pad_to(_pad_to(b_pairs, bk, 1), bn, 2)
     mp, kp = a_pairs.shape[1:]
     np_ = b_pairs.shape[2]
-    num_pairs = len(ii)
-    grid = (mp // bm, np_ // bn, num_pairs, kp // bk)
+    grid = (mp // bm, np_ // bn, ii.shape[0], kp // bk)
 
-    hi, lo = pl.pallas_call(
-        _split_gemm_kernel_v1,
+    # The v2 body; only the index maps differ: step p reads gathered
+    # pair p instead of looking its slices up in the schedule.
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=3,
         grid=grid,
         in_specs=[
-            pl.BlockSpec((1, bm, bk), lambda i, j, p, kt: (p, i, kt)),
-            pl.BlockSpec((1, bk, bn), lambda i, j, p, kt: (p, kt, j)),
-            pl.BlockSpec((1,), lambda i, j, p, kt: (p,)),
+            pl.BlockSpec((1, bm, bk),
+                         lambda i, j, p, kt, ii, jj, we: (p, i, kt)),
+            pl.BlockSpec((1, bk, bn),
+                         lambda i, j, p, kt, ii, jj, we: (p, kt, j)),
         ],
         out_specs=[
-            pl.BlockSpec((bm, bn), lambda i, j, p, kt: (i, j)),
-            pl.BlockSpec((bm, bn), lambda i, j, p, kt: (i, j)),
+            pl.BlockSpec((bm, bn),
+                         lambda i, j, p, kt, ii, jj, we: (i, j)),
+            pl.BlockSpec((bm, bn),
+                         lambda i, j, p, kt, ii, jj, we: (i, j)),
         ],
+        scratch_shapes=[pltpu.VMEM((bm, bn), jnp.int32)],
+    )
+    hi, lo = pl.pallas_call(
+        _split_gemm_kernel_v2,
+        grid_spec=grid_spec,
         out_shape=[
             jax.ShapeDtypeStruct((mp, np_), jnp.float32),
             jax.ShapeDtypeStruct((mp, np_), jnp.float32),
         ],
+        compiler_params=_compiler_params(bm, bn, bk),
         interpret=interpret,
-    )(a_pairs, b_pairs, weights)
+    )(ii, jj, wexp, a_pairs, b_pairs)
     return hi[:m, :n], lo[:m, :n]
 
 

@@ -118,7 +118,6 @@ def build_sharded_train_step(model: Model, opt: AdamW, mesh,
     drive the size gate and plan lookup, exactly as a single device
     of that shard size would.
     """
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import PartitionSpec as P
 
     from repro.shard import (TP_AXIS, train_state_specs, validate_tp)
@@ -145,13 +144,13 @@ def build_sharded_train_step(model: Model, opt: AdamW, mesh,
         params, opt_state = opt.update(grads, params, opt_state)
         return params, opt_state, loss
 
-    # check_rep=False: the tp model's custom_vjp collective wrappers
-    # have no replication-tracking rules, and all cross-shard sums
+    # check_vma=False: the tp model's custom_vjp collective wrappers
+    # have no varying-axis tracking rules, and all cross-shard sums
     # here are explicit psums anyway.
-    return shard_map(per_shard_step, mesh=mesh,
-                     in_specs=(param_specs, opt_specs, P(dp)),
-                     out_specs=(param_specs, opt_specs, P()),
-                     check_rep=False)
+    return jax.shard_map(per_shard_step, mesh=mesh,
+                         in_specs=(param_specs, opt_specs, P(dp)),
+                         out_specs=(param_specs, opt_specs, P()),
+                         check_vma=False)
 
 
 def _describe_sites(sites) -> None:
@@ -452,7 +451,9 @@ def main(argv: Optional[Sequence[str]] = None) -> List[float]:
     if policy is not None:
         sites = wrapped.sites(params, opt_state, data.batch(start))
         _describe_sites(sites)
-        step_fn = jax.jit(wrapped)
+        # Donating the state lets the update write in place: at full
+        # width a second copy of params + AdamW moments does not fit.
+        step_fn = jax.jit(wrapped, donate_argnums=(0, 1))
         int8_per_step = count_int8_gemms(sites)
         if metrics is not None:
             metrics.declare_sites(sites)
@@ -464,7 +465,7 @@ def main(argv: Optional[Sequence[str]] = None) -> List[float]:
                     registry=metrics.registry, sink=metrics.sink,
                     log=log)
     else:
-        step_fn = jax.jit(train_step)
+        step_fn = jax.jit(train_step, donate_argnums=(0, 1))
         int8_per_step = 0
 
     losses: List[float] = []
@@ -520,4 +521,7 @@ def main(argv: Optional[Sequence[str]] = None) -> List[float]:
 
 
 if __name__ == "__main__":
+    from repro.launch.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
     main()

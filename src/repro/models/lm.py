@@ -280,6 +280,10 @@ class Model:
         Computed in at-least-f32: f32 for f32/bf16 activations
         (unchanged), f64 for an f64 model — downcasting would cap
         data-parallel == single-device loss agreement at f32 ulps.
+        The mean is taken per sequence first and then over the batch,
+        the grouping a batch-sharded (dp) step reduces in, so the two
+        agree to the rounding of the last B-term mean rather than of a
+        single B*T-term f32 sum.
         """
         inputs, targets = tokens[:, :-1], tokens[:, 1:]
         logits = self.apply(params, inputs)
@@ -287,7 +291,7 @@ class Model:
                                                  jnp.float32))
         logp = jax.nn.log_softmax(logits, axis=-1)
         nll = -jnp.take_along_axis(logp, targets[..., None], axis=-1)
-        return jnp.mean(nll)
+        return jnp.mean(jnp.mean(nll, axis=(1, 2)))
 
     # -- KV-cache programs (serving) ---------------------------------
 

@@ -101,14 +101,18 @@ class _ProbeGemm(GemmBackend):
             ref_dtype = jnp.complex128 if is_cplx else jnp.float64
             if not jax.config.jax_enable_x64:
                 ref_dtype = jnp.complex64 if is_cplx else jnp.float32
-            ref = jnp.matmul(a.astype(ref_dtype), b.astype(ref_dtype))
+            # HIGHEST: a TPU's DEFAULT f32 matmul is one bf16 pass.
+            hi = jax.lax.Precision.HIGHEST
+            ref = jnp.matmul(a.astype(ref_dtype), b.astype(ref_dtype),
+                             precision=hi)
             emul = ozaki_matmul(
                 a, b, num_splits=self.policy.splits_for(site),
                 accumulator=self.policy.accumulator,
                 out_dtype=ref_dtype,
                 slice_bits=self.policy.slice_bits)
-            denom = jnp.abs(a).astype(jnp.abs(ref).dtype) @ \
-                jnp.abs(b).astype(jnp.abs(ref).dtype)
+            denom = jnp.matmul(jnp.abs(a).astype(jnp.abs(ref).dtype),
+                               jnp.abs(b).astype(jnp.abs(ref).dtype),
+                               precision=hi)
             denom = jnp.where(denom == 0, 1.0, denom)
             err = jnp.max(jnp.abs(emul - ref) / denom)
             meta = self._meta.get(site)
@@ -168,7 +172,9 @@ class NumericsMonitor:
         self.sink = sink
         self.log = log or get_logger("numerics")
         self._probe = _ProbeGemm(policy)
-        self._wrapped = offload(fn, policy, backend=self._probe)
+        # Jitted: one compiled pass, not an op-by-op evaluation of the
+        # whole step, which on a chip compiles every op on its own.
+        self._wrapped = jax.jit(offload(fn, policy, backend=self._probe))
         self.last_report: Optional[NumericsReport] = None
 
     def _resolve_budget(self) -> float:

@@ -274,6 +274,12 @@ class Runner:
         seed and the emission index, so batching never changes a
         sampled request's tokens)."""
         toks = np.array(self.model.greedy(logits_dev))  # writable copy
+        keep = [i for i, r in enumerate(reqs)
+                if r is not None and r.logits is not None]
+        if keep:
+            lg = np.asarray(logits_dev)
+            for i in keep:
+                reqs[i].logits.append(lg[i].copy())
         hot = [i for i, r in enumerate(reqs)
                if r is not None and r.temperature > 0]
         if hot:
@@ -442,6 +448,7 @@ class Runner:
                     reqs: List) -> np.ndarray:
         """One masked decode step across all slots; returns sampled
         tokens for the active ones (others carry garbage)."""
+        reqs = [r if a else None for r, a in zip(reqs, active)]
         if self.layout == "paged":
             for slot in np.flatnonzero(active):
                 self.kv.ensure(int(slot), int(self._len[slot]) + 1)

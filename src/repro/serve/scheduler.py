@@ -49,6 +49,8 @@ class Request:
     same tokens — regardless of batch neighbours).
     ``latency_target_s`` is the admission scheduler's deadline input
     (EDF policy) and is recorded against realized TTFT either way.
+    Pass ``logits=[]`` to keep, beside each token of ``out``, the
+    float32 logits row it was chosen from.
     """
 
     prompt: List[int]
@@ -56,6 +58,7 @@ class Request:
     temperature: float = 0.0
     seed: int = 0
     latency_target_s: Optional[float] = None
+    logits: Optional[list] = None
     out: List[int] = dataclasses.field(default_factory=list)
     done: bool = False
 

@@ -165,13 +165,17 @@ class _CalibrationGemm(GemmBackend):
             ref_dtype = jnp.complex64 if is_cplx else jnp.float32
         floor = 64.0 * float(np.finfo(np.dtype(ref_dtype)).eps)
         self.floors[site] = max(self.floors.get(site, 0.0), floor)
-        ref = jnp.matmul(a.astype(ref_dtype), b.astype(ref_dtype))
+        # HIGHEST: a TPU's DEFAULT f32 matmul is one bf16 pass.
+        hi = jax.lax.Precision.HIGHEST
+        ref = jnp.matmul(a.astype(ref_dtype), b.astype(ref_dtype),
+                         precision=hi)
         emul = ozaki_matmul(a, b, num_splits=self.probe_splits,
                             accumulator=self.policy.accumulator,
                             out_dtype=ref_dtype,
                             slice_bits=self.policy.slice_bits)
-        denom = jnp.abs(a).astype(jnp.abs(ref).dtype) @ \
-            jnp.abs(b).astype(jnp.abs(ref).dtype)
+        denom = jnp.matmul(jnp.abs(a).astype(jnp.abs(ref).dtype),
+                           jnp.abs(b).astype(jnp.abs(ref).dtype),
+                           precision=hi)
         denom = jnp.where(denom == 0, 1.0, denom)
         err = jnp.max(jnp.abs(emul - ref) / denom)
         amax_l = jnp.max(jnp.abs(a))

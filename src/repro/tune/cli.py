@@ -161,7 +161,6 @@ def main(argv: Optional[Sequence[str]] = None) -> List[str]:
             return (params, opt_state, batch)
     else:
         if mesh is not None:
-            from jax.experimental.shard_map import shard_map
             from jax.sharding import PartitionSpec as P
 
             axis = mesh.axis_names[0]
@@ -170,9 +169,10 @@ def main(argv: Optional[Sequence[str]] = None) -> List[str]:
                 def per_shard(p_s, b_s):
                     return jax.lax.pmean(model.loss(p_s, b_s), axis)
 
-                return shard_map(per_shard, mesh=mesh,
-                                 in_specs=(P(), P(axis)),
-                                 out_specs=P())(p, batch)
+                return jax.shard_map(per_shard, mesh=mesh,
+                                     in_specs=(P(), P(axis)),
+                                     out_specs=P(),
+                                     check_vma=False)(p, batch)
         else:
             fn = model.loss
 

@@ -97,11 +97,38 @@ class TestPolicyBinding:
             assert err < 1e-11, acc
 
 
+def _dot_precisions(fn, *args):
+    """``precision`` params of every dot_general ``fn`` stages."""
+    import jax
+
+    jaxpr = jax.make_jaxpr(fn)(*args).jaxpr
+    return [e.params["precision"] for e in jaxpr.eqns
+            if e.primitive.name == "dot_general"]
+
+
 class TestBackendNumerics:
     def test_dgemm_matches_native(self):
         a, b = _gauss(64, 3), _gauss(64, 4)
         np.testing.assert_array_equal(
             np.asarray(get_backend("dgemm")(a, b)), np.asarray(a @ b))
+
+    def test_dgemm_reference_runs_at_highest_precision(self):
+        # The reference every emulation error is measured against must
+        # not be a TPU's one-pass bf16 DEFAULT.  CPU values cannot show
+        # it, so read the staged precision.
+        import jax
+
+        a = _gauss(16, 3, jnp.float32)
+        highest = (jax.lax.Precision.HIGHEST,) * 2
+        assert _dot_precisions(get_backend("dgemm"), a, a) == [highest]
+
+    def test_pallas_backend_refuses_other_platforms(self, monkeypatch):
+        import jax
+
+        assert get_backend("pallas_int8_4").interpret  # CPU: interpreted
+        monkeypatch.setattr(jax, "default_backend", lambda: "gpu")
+        with pytest.raises(RuntimeError, match="needs a TPU"):
+            get_backend("pallas_int8_4")
 
     def test_ozaki_accuracy_ladder(self):
         a, b = _gauss(128, 5), _gauss(128, 6)

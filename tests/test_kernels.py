@@ -175,8 +175,7 @@ class TestFusedSlicing:
     def test_fused_f64_bitwise_vs_its_jnp_spec(self, num_splits):
         # The fused kernel's spec for f64 sources is slice_matrix_fused:
         # feeding its slices through the pre-sliced v2 kernel at the
-        # same blocks (the compensated accumulation order depends on
-        # the k-tiling) must reproduce the fused output exactly.
+        # same blocks must reproduce the fused output exactly.
         from repro.kernels import tile_model
 
         s = num_splits

@@ -99,3 +99,33 @@ class TestConfig:
         assert np.max(np.abs(h - h.conj().T)) == 0.0
         near = np.sum(np.abs(evals - cfg.fermi) < 3 * cfg.cluster_width)
         assert near >= cfg.cluster_frac * cfg.n * 0.5
+
+
+class TestDeviceDtypes:
+    def test_block_gemm_sends_only_real_f64_to_the_backend(self):
+        # XLA:TPU has no complex128 matmul: complex blocks are split
+        # into (re, im) pairs on the host, and only real f64 arrays
+        # reach a device program.
+        from repro.core import GemmBackend, register_backend
+        from repro.core import backends as B
+
+        seen = []
+
+        class Recording(GemmBackend):
+            def matmul(self, a, b, *, out_dtype=None, num_splits=None,
+                       site="default"):
+                seen.append((a.dtype, b.dtype, out_dtype))
+                return a @ b
+
+        register_backend("recording", lambda spec, policy, splits, arg:
+                         Recording(spec, policy))
+        try:
+            gemm = MU._make_gemm("recording")
+            rng = np.random.default_rng(3)
+            a = rng.standard_normal((8, 8)) + 1j * rng.standard_normal((8, 8))
+            b = rng.standard_normal((8, 8)) + 1j * rng.standard_normal((8, 8))
+            np.testing.assert_allclose(gemm(a, b), a @ b, rtol=1e-13)
+        finally:
+            B._FACTORIES.pop("recording", None)
+        assert len(seen) == 4
+        assert all(np.dtype(x) == np.float64 for row in seen for x in row)

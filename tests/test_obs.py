@@ -418,6 +418,29 @@ class TestNumericsMonitor:
         with pytest.raises(ValueError, match="plan or a policy"):
             NumericsMonitor(self._fn)
 
+    @pytest.mark.parametrize("kind", ["monitor", "calibrator"])
+    def test_reference_dots_run_at_highest_precision(self, operands,
+                                                     kind):
+        # The error reference and its |A|@|B| normalizer must not be a
+        # TPU's one-pass bf16 DEFAULT matmul; only the program's own
+        # (native) product keeps the program's precision.
+        from repro.core import offload
+        from repro.obs.numerics import _ProbeGemm
+        from repro.tune.calibrate import _CalibrationGemm, _Recorder
+
+        a, b = operands
+        pol = PrecisionPolicy(backend="fp64_int8", default_splits=4,
+                              min_dim=64)
+        probe = (_ProbeGemm(pol) if kind == "monitor"
+                 else _CalibrationGemm(pol, 4, _Recorder()))
+        jaxpr = jax.make_jaxpr(offload(self._fn, pol, backend=probe))(
+            a, b).jaxpr
+        precisions = [e.params["precision"] for e in jaxpr.eqns
+                      if e.primitive.name == "dot_general"]
+        highest = (jax.lax.Precision.HIGHEST,) * 2
+        assert precisions.count(highest) == 2
+        assert len(precisions) == 3
+
 
 SMALL = LMConfig(name="test_obs_serve", vocab_size=128, num_layers=1,
                  d_model=64, num_heads=2, num_kv_heads=1, head_dim=32,

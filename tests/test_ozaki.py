@@ -71,6 +71,33 @@ class TestSlicing:
         frac, _ = np.frexp(np.asarray(sigma))
         assert np.all(frac == 0.5)  # exact powers of two
 
+    @pytest.mark.parametrize("dtype", [np.float32, np.float64])
+    def test_exact_pow2_matches_ldexp_over_whole_range(self, dtype):
+        # sigma is built without ldexp (whose f64 lowering XLA:TPU
+        # refuses); it must still be bitwise the device's ldexp for
+        # every exponent the dtype can carry, subnormal results and
+        # overflow included.
+        from repro.core.ozaki import _exact_pow2
+
+        info = np.finfo(dtype)
+        lo = info.minexp - info.nmant
+        e = jnp.arange(lo, info.maxexp + 2, dtype=jnp.int32)
+        want = np.asarray(jnp.ldexp(jnp.ones(e.shape, dtype), e))
+        got = np.asarray(_exact_pow2(e, dtype))
+        np.testing.assert_array_equal(got.view(np.uint8),
+                                      want.view(np.uint8))
+
+    def test_sigma_spans_f64_exponents(self):
+        # Row scales far outside f32's exponent range stay exact.
+        x = np.array([[3.0e-300, 1.0], [7.0e250, -1.0]])
+        x = x * np.array([[1.0, 0.0], [1.0, 0.0]])
+        _, sigma = slice_matrix(x, 3, axis=1)
+        absmax = np.abs(x).max(axis=1)
+        frac, _ = np.frexp(np.asarray(sigma))
+        assert np.all(frac == 0.5)
+        assert np.all((absmax <= np.asarray(sigma) / 2)
+                      & (absmax > np.asarray(sigma) / 4))
+
     def test_pair_count(self):
         for s in range(1, 10):
             ii, jj = pair_indices(s)

@@ -58,6 +58,25 @@ class TestEngine:
                 [Request(prompt=prompt, max_new_tokens=5)])
             assert req.out == solo.out
 
+    def test_kept_logits_align_with_tokens(self, model_params):
+        # Chunked prefill keeps some slots prefilling while others
+        # decode: each kept row must belong to an emitted token.
+        model, params = model_params
+        prompts = _prompts([4, 9, 6, 11, 5], seed=3)
+        reqs = [Request(prompt=p, max_new_tokens=6,
+                        logits=[] if i % 2 == 0 else None)
+                for i, p in enumerate(prompts)]
+        Engine(model, params, batch_slots=2, max_len=64,
+               chunk_tokens=3).run(reqs)
+        for i, req in enumerate(reqs):
+            if i % 2:
+                assert req.logits is None
+                continue
+            assert len(req.logits) == len(req.out) == 6
+            assert [int(np.argmax(row)) for row in req.logits] == req.out
+            assert all(row.shape == (SMALL.vocab_size,)
+                       and row.dtype == np.float32 for row in req.logits)
+
     def test_eos_evicts_early(self, model_params):
         model, params = model_params
         prompt = _prompts([6], seed=2)[0]

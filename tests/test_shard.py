@@ -13,7 +13,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
-from jax.experimental.shard_map import shard_map
+from jax import shard_map
 from jax.sharding import NamedSharding, PartitionSpec as P
 
 from repro.configs import LMConfig
@@ -171,7 +171,7 @@ class TestCollectives:
 
         def run(body):
             return shard_map(body, mesh=mesh8, in_specs=(P("dp"),),
-                             out_specs=P(), check_rep=False)(tree)
+                             out_specs=P(), check_vma=False)(tree)
 
         got = run(lambda t: bucketed_psum(t, "dp",
                                           bucket_bytes=1 << 20,
@@ -189,7 +189,7 @@ class TestCollectives:
 
         def run(body):
             return shard_map(body, mesh=mesh8, in_specs=(P("dp"),),
-                             out_specs=P(), check_rep=False)(x)
+                             out_specs=P(), check_vma=False)(x)
 
         ref = run(lambda s: jax.lax.psum(s, "dp") / 8)
         got = run(lambda s: ring_all_reduce(s, "dp", 8, mean=True))
@@ -322,10 +322,11 @@ class TestPmapOffload:
                               accumulator="f64")
         w = offload(f, pol)
         sites = w.sites(x, y)
-        assert [s.name for s in sites] == ["pmap0/dot0"]
+        # jax.pmap stages as jit(shard_map): its body is a shmap scope.
+        assert [s.name for s in sites] == ["shmap0/dot0"]
         assert sites[0].offloaded and sites[0].lhs_shape == (48, 160)
         assert [s.name for s in site_report(f, pol)(x, y)] == \
-            ["pmap0/dot0"]
+            ["shmap0/dot0"]
         np.testing.assert_allclose(np.asarray(w(x, y)),
                                    np.asarray(f(x, y)), rtol=0,
                                    atol=1e-9)

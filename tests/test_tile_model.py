@@ -17,18 +17,19 @@ from repro.kernels import tile_model as tm
 class TestHandComputedFigures:
     def test_vmem_bytes_presliced(self):
         # 2 * (bm*bk + bk*bn) int8 double-buffered inputs
-        # + 2 * 4 * bm*bn f32 hi/lo accumulators.
+        # + 40 * bm*bn: double-buffered f32 hi/lo outputs, int32 k-sum
+        # and tile product, the fold's f32 temporaries.
         assert tm.vmem_bytes(128, 128, 128) == \
-            2 * (128 * 128 + 128 * 128) + 2 * 4 * 128 * 128 == 196608
+            2 * (128 * 128 + 128 * 128) + 40 * 128 * 128 == 720896
         assert tm.vmem_bytes(32, 128, 256) == \
-            2 * (32 * 256 + 256 * 128) + 2 * 4 * 32 * 128
+            2 * (32 * 256 + 256 * 128) + 40 * 32 * 128
 
     def test_vmem_bytes_fused(self):
-        # Fused streams f32 hi+lo halves (8 B/elem) and adds int8
-        # slice scratch for the quantized tiles.
+        # Fused streams f32 hi+lo halves (8 B/elem, double-buffered)
+        # and holds a 20 B/elem f32 working set for the quantization.
         e = 128 * 128 + 128 * 128
         assert tm.vmem_bytes(128, 128, 128, fused=True) == \
-            2 * 8 * e + e + 2 * 4 * 128 * 128 == 688128
+            (2 * 8 + 20) * e + 40 * 128 * 128 == 1835008
 
     def test_mxu_tile_cycles(self):
         # One 128^3 MAC block per 128 cycles on the 128x128 array.
@@ -48,7 +49,7 @@ class TestHandComputedFigures:
         # the full 128^3 block wins on cycles-per-flop.
         d = tm.select_tiles(128, 128, 128, 6, dtype="float32")
         assert (d.block_m, d.block_n, d.block_k) == (128, 128, 128)
-        assert d.vmem_bytes == 196608
+        assert d.vmem_bytes == 720896
         assert d.mxu_cycles_step == 128
         assert d.pairs == 21
         assert d.kernel_invocations == 21  # 1 * 1 * 21 pairs * 1

@@ -513,7 +513,7 @@ class TestLMTunedPlanAcceptance:
 class TestShardedCalibration:
     @needs8
     def test_dp8_plan_byte_identical_to_single_device(self):
-        from jax.experimental.shard_map import shard_map
+        from jax import shard_map
         from jax.sharding import PartitionSpec as P
 
         from repro.shard import build_mesh, data_parallel_sharding
