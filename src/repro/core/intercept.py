@@ -221,6 +221,37 @@ def _mesh_axes(mesh) -> Tuple[Tuple[str, int], ...]:
                  for name in mesh.axis_names)
 
 
+def _local_shards(mesh) -> int:
+    """Shards of a shard_map mesh that run in this process.
+
+    A concrete mesh knows its local devices; an abstract one is taken
+    to spread evenly over the processes.
+    """
+    if isinstance(mesh, jax.sharding.Mesh):
+        return len(mesh.local_devices)
+    return max(1, math.prod(s for _, s in _mesh_axes(mesh))
+               // jax.process_count())
+
+
+def _dynamic_trip(prefix: str) -> bool:
+    """Whether a scope path lies under ``while`` or ``cond``, whose
+    executions per call only the running program knows."""
+    return any(part.startswith(("while", "cond"))
+               for part in prefix.split("/"))
+
+
+def site_scope(site: "Site") -> str:
+    """The ``jax.named_scope`` a site's subgraph runs under.
+
+    ``ozaki_<site>`` for an offloaded site, ``native_<site>`` for one
+    left native, with the path's ``/`` written as ``.`` so that the
+    scope stays one component of the op names in the device trace:
+    ``ozaki_scan0.dot3``.
+    """
+    kind = "ozaki" if site.offloaded else "native"
+    return f"{kind}_{site.name.replace('/', '.')}"
+
+
 def _walk_sites(jaxpr, prefix: str = "", dot_counter=None,
                 flow_counter=None, out=None, mult: int = 1,
                 spmd=()) -> List[Tuple[Any, str, int, tuple]]:
@@ -423,13 +454,14 @@ def _site_dot(backend: GemmBackend, site: Site, dims: "_DotDims",
 
     def dot_bwd(res, g):
         lhs, rhs = res
-        l3 = dims.pack_lhs(lhs)
-        r3 = dims.pack_rhs(rhs)
-        g3 = dims.pack_out(g)
-        swap = lambda x: jnp.swapaxes(x, -1, -2)  # noqa: E731
-        dl = bmm(g3, swap(r3), lhs.dtype)
-        dr = bmm(swap(l3), g3, rhs.dtype)
-        return dims.unpack_lhs(dl), dims.unpack_rhs(dr)
+        with jax.named_scope(site_scope(site)):
+            l3 = dims.pack_lhs(lhs)
+            r3 = dims.pack_rhs(rhs)
+            g3 = dims.pack_out(g)
+            swap = lambda x: jnp.swapaxes(x, -1, -2)  # noqa: E731
+            dl = bmm(g3, swap(r3), lhs.dtype)
+            dr = bmm(swap(l3), g3, rhs.dtype)
+            return dims.unpack_lhs(dl), dims.unpack_rhs(dr)
 
     dot.defvjp(dot_fwd, dot_bwd)
     return dot
@@ -446,24 +478,38 @@ def transform_jaxpr(closed, policy: PrecisionPolicy,
     discovery order.  The transform runs once; evaluating the result
     (``jax.core.eval_jaxpr``) never re-traces the user function.
 
+    Every ``dot_general`` site runs under one ``jax.named_scope``,
+    :func:`site_scope`: ``ozaki_<site>`` (``/`` written as ``.``) over
+    an offloaded site's whole backend subgraph and its ``custom_vjp``
+    backward, ``native_<site>`` over a site left native.  Scopes change
+    only the ops' metadata (``op_name``), never the arithmetic, and
+    name each site's ops in a device trace.
+
     ``on_site_event`` is the telemetry hook: a host callable receiving
     one static payload dict (site name, backend spec, splits, shapes,
-    extents, flops) per *execution* of each offloaded site.  It is
-    staged as a ``jax.debug.callback`` **sibling** of the site's
-    backend call — never inside the ``custom_vjp`` (debug effects
-    cannot stage through custom-derivative rules) — so inside a
-    ``scan`` body it fires once per iteration and inside a
-    ``shard_map`` body once per mesh shard.  The callback
-    deliberately carries **zero** array operands: the payload is
-    host-built at transform time, the hook adds no device compute, and
-    — load-bearing, not just an optimization — an operand-carrying
-    callback inside a loop body is *dropped entirely* by JAX's
-    partial-eval when the loop is differentiated, whereas the
-    zero-operand form is merely hoisted.  Consequence: under
-    reverse-mode AD a loop-body site reports once per step, not once
-    per iteration (forward-only programs count exactly).  Handlers run
-    on the runtime's callback threads and must follow the
-    np-asarray-first rule: never launch jax ops from the handler.
+    extents, flops) per *execution* of each offloaded site — per
+    ``scan`` iteration, per local mesh shard of a ``shard_map``.  Where
+    that count is static (a site at top level, under ``scan`` or under
+    ``shard_map``: ``Site.mult`` times the local shards), one
+    zero-operand ``jax.debug.callback`` at the top level of the
+    transformed program makes all of a call's reports at once, so the
+    device waits on the host once a call, not once a site execution.
+    A site under ``while`` or ``cond``, whose trip count only execution
+    knows, keeps its own callback beside its backend call, outside its
+    scope and never inside the ``custom_vjp`` (debug effects cannot
+    stage through custom-derivative rules): it fires once per
+    iteration or taken branch.  Callbacks carry **zero** array
+    operands: the payload is host-built at transform time, the hook
+    adds no device compute, and — load-bearing, not just an
+    optimization — an operand-carrying callback inside a loop body is
+    *dropped entirely* by JAX's partial-eval when the loop is
+    differentiated, whereas the zero-operand form is merely hoisted.
+    Consequence: under reverse-mode AD *outside* the transformed
+    function, a ``while``/``cond`` site reports once per call, not once
+    per iteration; every other site still reports ``Site.mult`` times
+    (forward-only programs count exactly).  Handlers run on the
+    runtime's callback threads and must follow the np-asarray-first
+    rule: never launch jax ops from the handler.
     """
     backend = backend or get_backend(policy.backend, policy=policy)
     sites: List[Site] = []
@@ -498,11 +544,8 @@ def transform_jaxpr(closed, policy: PrecisionPolicy,
             engines[spec] = get_backend(spec, policy=policy)
         return engines[spec]
 
-    def stage_site_event(site: Site) -> None:
-        # Static payload, built host-side once per staging; the
-        # callback takes zero array operands so it costs nothing on
-        # device and cannot trip the np-asarray-first rule itself.
-        payload = {
+    def site_payload(site: Site) -> dict:
+        return {
             "site": site.name,
             "backend": site.backend or policy.backend,
             "splits": int(site.splits),
@@ -515,8 +558,32 @@ def transform_jaxpr(closed, policy: PrecisionPolicy,
             "flops": site.flops,
             "tiles": dict(site.tiles) if site.tiles else None,
         }
-        jax.debug.callback(
-            lambda _p=payload: on_site_event(dict(_p)))
+
+    def stage_site_events(counted) -> None:
+        # Static (payload, executions) pairs, built host-side once per
+        # staging; the callback takes zero array operands so it costs
+        # nothing on device and cannot trip the np-asarray-first rule
+        # itself.
+        counted = tuple(counted)
+
+        def report():
+            for payload, execs in counted:
+                for _ in range(execs):
+                    on_site_event(dict(payload))
+
+        jax.debug.callback(report)
+
+    # Executions per call of each offloaded site with a static count,
+    # in program order (reported by the one per-call callback), and the
+    # local shards of each shard_map scope, keyed by its path prefix.
+    static_execs: Dict[str, int] = {}
+    local_shards: Dict[str, int] = {}
+
+    def shards_of(prefix: str) -> int:
+        parts = prefix.split("/")
+        return math.prod(local_shards["/".join(parts[:i + 1]) + "/"]
+                         for i, part in enumerate(parts)
+                         if part.startswith("shmap"))
 
     def read_env(env, v):
         return v.val if isinstance(v, jex_core.Literal) else env[v]
@@ -549,16 +616,22 @@ def transform_jaxpr(closed, policy: PrecisionPolicy,
                 # demoted to native — or re-calibration under a
                 # from_plan policy would re-promote pathological
                 # sites unmeasured.
-                if site.offloaded or (authoritative and site.eligible):
-                    dims = _DotDims(eqn.params["dimension_numbers"],
-                                    site.lhs_shape, site.rhs_shape)
-                    fn = _site_dot(engine_for(site), site, dims,
-                                   eqn.outvars[0].aval.dtype)
-                    if on_site_event is not None and site.offloaded:
-                        stage_site_event(site)
-                    outvals = [fn(invals[0], invals[1])]
-                else:
-                    outvals = [eqn.primitive.bind(*invals, **eqn.params)]
+                if on_site_event is not None and site.offloaded:
+                    if _dynamic_trip(prefix):
+                        stage_site_events([(site_payload(site), 1)])
+                    else:
+                        static_execs[site.name] = (site.mult
+                                                   * shards_of(prefix))
+                with jax.named_scope(site_scope(site)):
+                    if site.offloaded or (authoritative and site.eligible):
+                        dims = _DotDims(eqn.params["dimension_numbers"],
+                                        site.lhs_shape, site.rhs_shape)
+                        fn = _site_dot(engine_for(site), site, dims,
+                                       eqn.outvars[0].aval.dtype)
+                        outvals = [fn(invals[0], invals[1])]
+                    else:
+                        outvals = [eqn.primitive.bind(*invals,
+                                                      **eqn.params)]
             elif prim in _INLINE_PRIMITIVES:
                 # Inlining a jit discards its partitioning params, so
                 # NamedSharding annotations on the inner jit are
@@ -583,6 +656,7 @@ def transform_jaxpr(closed, policy: PrecisionPolicy,
             elif prim == "shard_map":
                 pfx = f"{prefix}shmap{flow_counter[0]}/"
                 flow_counter[0] += 1
+                local_shards[pfx] = _local_shards(eqn.params["mesh"])
                 outvals = _eval_shard_map(eqn, invals, eval_rewritten,
                                           pfx)
             elif prim == "pvary":
@@ -633,7 +707,11 @@ def transform_jaxpr(closed, policy: PrecisionPolicy,
         return [read_env(env, v) for v in jaxpr.outvars]
 
     def interp(*flat_args):
-        return eval_rewritten(closed.jaxpr, closed.consts, flat_args)
+        outs = eval_rewritten(closed.jaxpr, closed.consts, flat_args)
+        if static_execs:
+            stage_site_events((site_payload(decisions[name]), execs)
+                              for name, execs in static_execs.items())
+        return outs
 
     in_specs = [jax.ShapeDtypeStruct(v.aval.shape, v.aval.dtype)
                 for v in closed.jaxpr.invars]
@@ -957,9 +1035,11 @@ def offload(fn, policy: PrecisionPolicy | None = None, *,
     its recording backend swapped in.
 
     ``on_site_event`` enables per-site execution telemetry: a host
-    callable invoked (via ``jax.debug.callback``) with a static payload
-    dict once per execution of each offloaded site — per ``scan``
-    iteration, per mesh shard; see :func:`transform_jaxpr`.  Pass
+    callable invoked with a static payload dict once per execution of
+    each offloaded site — per ``scan`` iteration, per local mesh shard.
+    One zero-operand ``jax.debug.callback`` a call makes the reports of
+    every site whose count is static; a site under ``while``/``cond``
+    keeps a callback of its own; see :func:`transform_jaxpr`.  Pass
     ``MetricsRun.site_event_handler()`` from :mod:`repro.obs` to count
     executions into a metrics run.  Note debug callbacks are
     asynchronous: call ``jax.effects_barrier()`` before reading

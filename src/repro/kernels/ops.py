@@ -31,6 +31,10 @@ Slicing arithmetic is shared with :mod:`repro.core.ozaki` /
 :mod:`repro.kernels.slicing`, so all paths are bit-for-bit comparable
 in tests.
 
+Each ``pallas_call`` carries a stable name — ``ozaki_int8_tile`` (v2),
+``ozaki_int8_tile_fused`` and ``ozaki_int8_tile_v1`` — which names the
+kernel's ops in a device trace.
+
 On CPU there is no Mosaic backend: pass ``interpret=True`` (the
 benchmarks do) to run the kernel through the Pallas interpreter —
 correctness-only, but it exercises the exact same kernel body that
@@ -255,6 +259,7 @@ def split_gemm_pallas(a_sl, b_sl, num_splits: int,
         ],
         compiler_params=_compiler_params(bm, bn, bk),
         interpret=interpret,
+        name="ozaki_int8_tile",
     )(ii, jj, wexp, a_sl, b_sl)
     return hi[:m, :n], lo[:m, :n]
 
@@ -320,6 +325,7 @@ def split_gemm_pallas_fused(a_hi, a_lo, b_hi, b_lo, num_splits: int,
         ],
         compiler_params=_compiler_params(bm, bn, bk, fused=True),
         interpret=interpret,
+        name="ozaki_int8_tile_fused",
     )(ii, jj, wexp, a_hi, a_lo, b_hi, b_lo)
     return hi[:m, :n], lo[:m, :n]
 
@@ -381,6 +387,7 @@ def split_gemm_pallas_v1(a_sl, b_sl, num_splits: int,
         ],
         compiler_params=_compiler_params(bm, bn, bk),
         interpret=interpret,
+        name="ozaki_int8_tile_v1",
     )(ii, jj, wexp, a_pairs, b_pairs)
     return hi[:m, :n], lo[:m, :n]
 

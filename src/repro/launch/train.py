@@ -51,6 +51,7 @@ Precision plans (:mod:`repro.tune`) close the loop:
 from __future__ import annotations
 
 import argparse
+import contextlib
 import json
 import time
 from typing import List, Optional, Sequence
@@ -468,26 +469,29 @@ def main(argv: Optional[Sequence[str]] = None) -> List[float]:
         step_fn = jax.jit(train_step, donate_argnums=(0, 1))
         int8_per_step = 0
 
+    def span(name: str, **kw):
+        # Host spans of the loop (one TraceAnnotation each, so a device
+        # trace of the launcher names its idle gaps by them).
+        return (metrics.tracer.span(name, **kw) if metrics is not None
+                else contextlib.nullcontext())
+
     losses: List[float] = []
     t_last = time.perf_counter()
     try:
         for step in range(start, args.steps):
-            batch = jnp.asarray(data.batch(step))
-            if batch_sharding is not None:
-                batch = jax.device_put(batch, batch_sharding)
-            if monitor is not None:
-                monitor.maybe_check(step, params, opt_state, batch)
+            with span("train.data", step=step + 1):
+                batch = jnp.asarray(data.batch(step))
+                if batch_sharding is not None:
+                    batch = jax.device_put(batch, batch_sharding)
+            if monitor is not None and monitor.due(step):
+                with span("train.numerics", step=step + 1):
+                    monitor.check(step, params, opt_state, batch)
             t_step = time.perf_counter()
-            if metrics is not None:
-                with metrics.tracer.span("train_step", step=step + 1):
-                    params, opt_state, loss = step_fn(params,
-                                                      opt_state, batch)
-                    # Blocking inside the span so it measures the whole
-                    # device step, not just the dispatch.
-                    losses.append(float(loss))
-            else:
-                params, opt_state, loss = step_fn(params, opt_state,
-                                                  batch)
+            with span("train.step", step=step + 1):
+                params, opt_state, loss = step_fn(params, opt_state, batch)
+            with span("train.loss", step=step + 1):
+                # Blocks on the device step: train.step covers the
+                # dispatch, train.loss the wait for the step's end.
                 losses.append(float(loss))
             step_ms = (time.perf_counter() - t_step) * 1e3
             if metrics is not None:
@@ -502,8 +506,10 @@ def main(argv: Optional[Sequence[str]] = None) -> List[float]:
                 t_last = now
                 push_metrics()
             if (step + 1) % args.ckpt_every == 0:
-                save_ckpt(step + 1, (params, opt_state))
-        save_ckpt(args.steps, (params, opt_state))
+                with span("train.checkpoint", step=step + 1):
+                    save_ckpt(step + 1, (params, opt_state))
+        with span("train.checkpoint", step=args.steps):
+            save_ckpt(args.steps, (params, opt_state))
     finally:
         if metrics is not None:
             # Drain async site-event callbacks before the final

@@ -9,7 +9,7 @@ retuning.  It joins three things the telemetry stream already records:
 * ``site_exec`` counts — how often each site really executed (scan
   iterations and mesh shards each count);
 * tracer spans — the measured wall time of the run's hot loop
-  (``train_step`` / ``prefill`` / ``decode`` spans).
+  (``train.step`` + ``train.loss`` / ``prefill`` / ``decode`` spans).
 
 and prices each site with the :mod:`repro.kernels.tile_model` analytic
 costs: INT8 pair-GEMMs, modeled MXU cycles, and modeled HBM bytes per
@@ -39,8 +39,8 @@ __all__ = ["AttribRow", "attribution", "publish", "WALL_SPAN_NAMES"]
 #: Span names that measure the hot loop.  When a run recorded any of
 #: these, their total duration is the wall time attributed across
 #: sites; otherwise every span counts (a bare offload microbenchmark).
-WALL_SPAN_NAMES = ("train_step", "prefill", "decode", "decode_tick",
-                   "step", "generate")
+WALL_SPAN_NAMES = ("train.step", "train.loss", "train_step", "prefill",
+                   "decode", "decode_tick", "step", "generate")
 
 #: Demotion step suggested per site: splits drop by 2 (one accuracy
 #: notch in the tuner's ladder), floored at 1.

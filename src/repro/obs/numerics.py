@@ -187,10 +187,14 @@ class NumericsMonitor:
         dtype = meta.dtype if meta is not None else jnp.float32
         return 32.0 * float(jnp.finfo(jnp.dtype(dtype)).eps)
 
+    def due(self, step: int) -> bool:
+        """Whether ``step`` lands on the check period."""
+        return self.every > 0 and step % self.every == 0
+
     def maybe_check(self, step: int, *args,
                     **kwargs) -> Optional[NumericsReport]:
         """Run :meth:`check` when ``step`` lands on the period."""
-        if self.every <= 0 or step % self.every:
+        if not self.due(step):
             return None
         return self.check(step, *args, **kwargs)
 

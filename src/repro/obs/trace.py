@@ -6,8 +6,14 @@ e.g. the train loop converts the loss to float before the span closes,
 and the serve engine ``np.asarray``-s the sampled tokens).  Each closed
 span becomes one event:
 
-``{"type": "span", "name": ..., "ts": <us since tracer start>,``
+``{"type": "span", "name": ..., "ts": <us since the Unix epoch>,``
 ``  "dur": <us>, "tid": <thread id>, "args": {...}}``
+
+A span also opens a ``jax.profiler.TraceAnnotation`` of the same name
+(near-free when no profiler session is recording), and ``ts`` is read
+on the clock the profiler stamps its host events with (the system
+clock, ``time.time_ns``), so a span of the JSONL stream and its event
+in a device trace's host plane line up.
 
 When the tracer is built over an :class:`~repro.obs.events.EventSink`
 the spans stream straight into the JSONL file (bounded memory over long
@@ -25,6 +31,8 @@ import threading
 import time
 from typing import List, Optional
 
+from jax.profiler import TraceAnnotation
+
 __all__ = ["Tracer", "to_chrome"]
 
 
@@ -33,13 +41,15 @@ class Tracer:
 
     def __init__(self, sink=None):
         self._sink = sink
-        self._t0 = time.perf_counter()
         self._lock = threading.Lock()
         #: retained span events (only when no sink streams them out)
         self.events: List[dict] = []
 
-    def _now_us(self) -> float:
-        return (time.perf_counter() - self._t0) * 1e6
+    @staticmethod
+    def _now_us() -> float:
+        # The profiler's host clock (CLOCK_REALTIME), so that a span's
+        # ts matches its TraceAnnotation in a recorded trace.
+        return time.time_ns() / 1e3
 
     @contextlib.contextmanager
     def span(self, name: str, **args):
@@ -51,7 +61,8 @@ class Tracer:
         """
         start = self._now_us()
         try:
-            yield self
+            with TraceAnnotation(str(name)):
+                yield self
         except BaseException:
             args = {**args, "error": True}
             raise

@@ -535,3 +535,60 @@ class TestCallPrimitiveBoundaries:
         a, b = a.astype(jnp.float64), b.astype(jnp.float64)
         ref = float(f(a, b))
         assert abs(float(offload(f, both)(a, b)) - ref) / abs(ref) < 1e-5
+
+
+class TestSiteScopes:
+    """Each site's ops carry its named scope in the compiled program."""
+
+    @staticmethod
+    def _scan_program(c, xs):
+        def body(c, x):
+            y = jnp.tanh(c @ x)                    # offloaded
+            z = y[:, :32].T @ y[:, :32]            # gated: 32 < min_dim
+            return y + jnp.sum(z), None
+        return jax.lax.scan(body, c, xs)[0]
+
+    @staticmethod
+    def _op_names(compiled_text):
+        import re
+        return set(re.findall(r'op_name="([^"]+)"', compiled_text))
+
+    def test_compiled_hlo_names_offloaded_and_native_sites(self):
+        c = jnp.ones((128, 128), jnp.float32) * 0.01
+        xs = jnp.ones((3, 128, 128), jnp.float32) * 0.01
+        pol = PrecisionPolicy(backend="fp64_int8", default_splits=2,
+                              min_dim=64)
+        wrapped = offload(self._scan_program, pol)
+        sites = {s.name: s.offloaded for s in wrapped.sites(c, xs)}
+        assert sites == {"scan0/dot0": True, "scan0/dot1": False}
+        names = self._op_names(
+            jax.jit(wrapped).lower(c, xs).compile().as_text())
+        components = {part for n in names for part in n.split("/")}
+        assert {"ozaki_scan0.dot0", "native_scan0.dot1"} <= components
+        # The scope wraps the whole backend subgraph: the slicing and
+        # the int8 pair products, not only one op.
+        ozaki = [n for n in names if "/ozaki_scan0.dot0/" in n]
+        assert len(ozaki) > 5
+        assert any(n.endswith("/dot_general") for n in ozaki)
+
+    def test_backward_runs_under_the_site_scope(self, operands):
+        a, b = operands
+        pol = PrecisionPolicy(backend="fp64_int8", default_splits=2,
+                              min_dim=64)
+        grad = jax.jit(jax.grad(offload(lambda a, b: jnp.sum(a @ b),
+                                        pol)))
+        names = self._op_names(grad.lower(a, b).compile().as_text())
+        backward = [n for n in names
+                    if "transpose" in n and "/ozaki_dot0/" in n]
+        assert any(n.endswith("/dot_general") for n in backward)
+
+    def test_site_scope_format(self):
+        from repro.core.intercept import Site, site_scope
+
+        def site(name, offloaded):
+            return Site(name, (4, 4), (4, 4), jnp.float32, offloaded, 2, "")
+
+        assert site_scope(site("scan0/dot3", True)) == "ozaki_scan0.dot3"
+        assert (site_scope(site("while2/cond/dot0", False))
+                == "native_while2.cond.dot0")
+        assert site_scope(site("dot0", True)) == "ozaki_dot0"

@@ -346,6 +346,156 @@ class TestOnSiteEvent:
         assert counts.get("dot0", 0) >= 1
 
 
+class TestSiteEventStaging:
+    """One host callback a call reports every statically counted site."""
+
+    POLICY = PrecisionPolicy(backend="fp64_int8", default_splits=2,
+                             min_dim=64)
+
+    @staticmethod
+    def _scan(c, xs):
+        def body(c, x):
+            return jnp.tanh(c @ x), None
+        c, _ = jax.lax.scan(body, c, xs)
+        return c @ xs[0]
+
+    @staticmethod
+    def _while(a):
+        def body(v):
+            i, x = v
+            return i + 1, jnp.tanh(x @ x)
+        return jax.lax.while_loop(lambda v: v[0] < 3, body, (0, a))[1] @ a
+
+    @staticmethod
+    def _callbacks(lowered_text):
+        import re
+        return len(re.findall(r"custom_call @\w*callback", lowered_text))
+
+    def _counting(self, f):
+        counts = {}
+
+        def handler(p):
+            counts[p["site"]] = counts.get(p["site"], 0) + 1
+
+        return offload(f, self.POLICY, on_site_event=handler), counts
+
+    def test_scan_program_stages_one_callback(self):
+        c = jnp.ones((128, 128), jnp.float32) * 0.01
+        xs = jnp.ones((3, 128, 128), jnp.float32) * 0.01
+        wrapped, _ = self._counting(self._scan)
+        assert self._callbacks(jax.jit(wrapped).lower(c, xs).as_text()) == 1
+
+    def test_while_site_keeps_its_own_callback(self):
+        a = jnp.ones((128, 128), jnp.float32) * 0.01
+        wrapped, counts = self._counting(self._while)
+        # One in the while body, one a call for the top-level site.
+        assert self._callbacks(jax.jit(wrapped).lower(a).as_text()) == 2
+        for _ in range(2):
+            jax.jit(wrapped)(a)
+        jax.effects_barrier()
+        assert counts == {"while0/dot0": 6, "dot0": 2}
+
+    def test_scan_counts_equal_per_execution_counts(self):
+        c = jnp.ones((128, 128), jnp.float32) * 0.01
+        xs = jnp.ones((3, 128, 128), jnp.float32) * 0.01
+        wrapped, counts = self._counting(self._scan)
+        step = jax.jit(wrapped)
+        for _ in range(2):
+            step(c, xs)
+        jax.effects_barrier()
+        assert counts == {"scan0/dot0": 6, "dot0": 2}
+        mult = {s.name: s.mult for s in wrapped.sites(c, xs)}
+        assert counts == {name: 2 * m for name, m in mult.items()}
+
+    @pytest.mark.parametrize("abstract", [False, True])
+    def test_shard_map_counts_once_per_shard(self, abstract):
+        import contextlib
+
+        from jax.sharding import Mesh, PartitionSpec as P
+
+        mesh = Mesh(np.array(jax.devices()[:4]), ("dp",))
+
+        def f(a, b):
+            def per_shard(a_s, b_s):
+                def body(c, _):
+                    return jnp.tanh(c @ b_s), None
+                c, _ = jax.lax.scan(body, a_s, None, length=2)
+                return c @ b_s
+            # Under jax.set_mesh the staged shard_map holds an
+            # AbstractMesh, which knows no devices.
+            return jax.shard_map(per_shard,
+                                 **({} if abstract else {"mesh": mesh}),
+                                 in_specs=(P("dp"), P()),
+                                 out_specs=P("dp"))(a, b)
+
+        a = jnp.ones((512, 128), jnp.float32) * 0.01
+        b = jnp.ones((128, 128), jnp.float32) * 0.01
+        wrapped, counts = self._counting(f)
+        step = jax.jit(wrapped)
+        with jax.set_mesh(mesh) if abstract else contextlib.nullcontext():
+            assert self._callbacks(step.lower(a, b).as_text()) == 1
+            step(a, b)
+        jax.effects_barrier()
+        # 4 shards; the scan body runs twice on each.
+        assert counts == {"shmap0/scan0/dot0": 8, "shmap0/dot0": 4}
+        assert all(s.spmd == "dp=4" for s in wrapped.sites(a, b))
+
+    def test_external_grad_reports_mult_executions(self):
+        c = jnp.ones((128, 128), jnp.float32) * 0.01
+        xs = jnp.ones((3, 128, 128), jnp.float32) * 0.01
+        wrapped, counts = self._counting(self._scan)
+        grad = jax.jit(jax.grad(lambda c, xs: jnp.sum(wrapped(c, xs))))
+        grad(c, xs)
+        jax.effects_barrier()
+        # The per-call callback sits outside every loop, so external AD
+        # no longer hoists a loop site's report down to one a call.
+        assert counts == {"scan0/dot0": 3, "dot0": 1}
+
+
+    def test_remat_train_step_stages_one_callback(self):
+        from repro.launch.train import build_train_step
+        from repro.train import AdamW
+
+        cfg = LMConfig(name="test_obs_step", vocab_size=256, num_layers=2,
+                       d_model=128, num_heads=4, num_kv_heads=2,
+                       head_dim=32, d_ff=256, max_seq_len=128, remat=True)
+        model, opt = Model(cfg), AdamW()
+        params = jax.eval_shape(model.init_params, jax.random.PRNGKey(0))
+        state = jax.eval_shape(opt.init, params)
+        batch = jax.ShapeDtypeStruct((1, 129), jnp.int32)
+        wrapped, _ = self._counting(build_train_step(model, opt))
+        sites = wrapped.sites(params, state, batch)
+        assert sum(s.mult for s in sites if s.offloaded) > 1
+        lowered = jax.jit(wrapped).lower(params, state, batch).as_text()
+        assert self._callbacks(lowered) == 1
+
+
+class TestTracerOnProfilerClock:
+    def test_span_lines_up_with_its_host_plane_event(self, tmp_path):
+        from jax.profiler import ProfileData
+
+        tr = Tracer()
+        options = jax.profiler.ProfileOptions()
+        options.python_tracer_level = 0
+        jax.profiler.start_trace(str(tmp_path), profiler_options=options)
+        try:
+            with tr.span("obs.clock_check"):
+                jnp.ones(8).block_until_ready()
+        finally:
+            jax.profiler.stop_trace()
+        (path,) = tmp_path.glob("plugins/profile/*/*.xplane.pb")
+        data = ProfileData.from_file(str(path))
+        env = data.find_plane_with_name("Task Environment")
+        start_ns = dict(env.stats)["profile_start_time"]
+        host = data.find_plane_with_name("/host:CPU")
+        (event,) = [e for line in host.lines for e in line.events
+                    if e.name == "obs.clock_check"]
+        (span,) = tr.events
+        # Host-plane times are relative to the session's start.
+        assert abs(span["ts"] * 1e3 - (start_ns + event.start_ns)) < 1e6
+        assert abs(span["dur"] * 1e3 - event.duration_ns) < 1e6
+
+
 class TestNumericsMonitor:
     def _fn(self, a, b):
         return jnp.sum(a @ b)
