@@ -86,6 +86,7 @@ from jax import export as _jax_export  # not auto-imported by `import jax`
 from jax.extend import core as jex_core
 
 from .backends import GemmBackend, get_backend
+from .ozaki import fold_runs
 from .precision import PrecisionPolicy
 
 __all__ = ["offload", "site_report", "transform_jaxpr", "Site",
@@ -150,13 +151,18 @@ class Site:
     for Pallas-family backends, ``tiles``: the analytic tile model's
     block/schedule pick for this site's geometry
     (:meth:`repro.kernels.tile_model.TileDecision.summary`).
+    ``int8_dots`` is the number of INT8 dots one execution of an
+    offloaded site's forward product issues: one per
+    :func:`repro.core.ozaki.fold_runs` run of its split count and
+    contraction, four times that for a complex site (0 when native).
     """
 
     def __init__(self, name: str, lhs_shape, rhs_shape, dtype,
                  offloaded: bool, splits: int, reason: str, *,
                  m: int = 0, k: int = 0, n: int = 0, batch: int = 1,
                  mult: int = 1, spmd_axes=(), backend: str = "",
-                 eligible: bool = False, tiles: dict | None = None):
+                 eligible: bool = False, tiles: dict | None = None,
+                 int8_dots: int = 0):
         self.name = name
         self.lhs_shape = tuple(lhs_shape)
         self.rhs_shape = tuple(rhs_shape)
@@ -170,6 +176,7 @@ class Site:
         self.backend = backend
         self.eligible = eligible
         self.tiles = dict(tiles) if tiles else None
+        self.int8_dots = int8_dots
 
     @property
     def flops(self) -> int:
@@ -341,9 +348,12 @@ def _classify(eqn, policy: PrecisionPolicy, name: str, mult: int = 1,
         # executes native.
         return skip("demoted to dgemm", eligible=True, backend=backend)
     splits = policy.splits_for(name)
+    cplx = 4 if jnp.issubdtype(dtype, jnp.complexfloating) else 1
     return Site(name, lhs_aval.shape, rhs_aval.shape, dtype,
                 True, splits, "", eligible=True, backend=backend,
                 tiles=_tile_choice(backend, m, k, n, splits, dtype),
+                int8_dots=cplx * len(fold_runs(splits, k,
+                                               policy.slice_bits)),
                 **geom)
 
 
@@ -487,7 +497,7 @@ def transform_jaxpr(closed, policy: PrecisionPolicy,
 
     ``on_site_event`` is the telemetry hook: a host callable receiving
     one static payload dict (site name, backend spec, splits, shapes,
-    extents, flops) per *execution* of each offloaded site — per
+    extents, flops, int8 dots) per *execution* of each offloaded site — per
     ``scan`` iteration, per local mesh shard of a ``shard_map``.  Where
     that count is static (a site at top level, under ``scan`` or under
     ``shard_map``: ``Site.mult`` times the local shards), one
@@ -556,6 +566,7 @@ def transform_jaxpr(closed, policy: PrecisionPolicy,
             "batch": site.batch, "mult": site.mult,
             "spmd_axes": [list(ax) for ax in site.spmd_axes],
             "flops": site.flops,
+            "int8_dots": site.int8_dots,
             "tiles": dict(site.tiles) if site.tiles else None,
         }
 
@@ -843,7 +854,7 @@ PersistInfo = namedtuple("PersistInfo", ["disk_hits",
 
 #: Bumped whenever the persisted payload layout changes; part of the
 #: cache key, so stale-format files are simply never looked up.
-_PERSIST_FORMAT = 1
+_PERSIST_FORMAT = 2
 
 
 def _site_payload(sites: Sequence[Site]) -> list:
@@ -855,7 +866,8 @@ def _site_payload(sites: Sequence[Site]) -> list:
              "n": int(s.n), "batch": int(s.batch), "mult": int(s.mult),
              "spmd_axes": [[a, int(x)] for a, x in s.spmd_axes],
              "backend": s.backend, "eligible": bool(s.eligible),
-             "tiles": s.tiles} for s in sites]
+             "tiles": s.tiles, "int8_dots": int(s.int8_dots)}
+            for s in sites]
 
 
 def _sites_from_payload(payload: list) -> List[Site]:
@@ -864,7 +876,8 @@ def _sites_from_payload(payload: list) -> List[Site]:
                  k=p["k"], n=p["n"], batch=p["batch"], mult=p["mult"],
                  spmd_axes=[tuple(a) for a in p["spmd_axes"]],
                  backend=p["backend"], eligible=p["eligible"],
-                 tiles=p["tiles"]) for p in payload]
+                 tiles=p["tiles"], int8_dots=p["int8_dots"])
+            for p in payload]
 
 
 def _sites_bytes(sites: Sequence[Site]) -> bytes:

@@ -12,9 +12,19 @@ and the high-precision product is recovered by accumulating the pair
 products ``S_i(A) @ S_j(B)`` with the appropriate power-of-two weights.
 
 With ``s`` slices per operand we follow the standard truncated scheme
-and keep only the pairs with ``i + j < s`` — ``s*(s+1)/2`` GEMMs — so
-the split count tunes accuracy continuously: each extra split buys
-roughly ``w`` more mantissa bits.
+and keep only the pairs with ``i + j < s`` — ``s*(s+1)/2`` pair
+products of MXU work — so the split count tunes accuracy continuously:
+each extra split buys roughly ``w`` more mantissa bits.
+
+Pairs with the same shift ``i + j`` carry the same power-of-two weight,
+so their INT32 products are summed exactly in int32 before any float
+work (:func:`fold_runs` caps a run where int32 could overflow).  A run
+``(i, t-i), i = i0..i1-1`` is one INT8 GEMM over a contraction of
+``(i1-i0)*k``: ``[A_i0 | ... | A_(i1-1)] @ [B_(t-i0); ...; B_(t-i1+1)]``,
+a slab of A's slices laid side by side along K against a slab of B's
+stacked in reverse along K.  At practical ``k`` a GEMM therefore
+issues ``s`` INT8 dots (contractions ``k, 2k, ..., s*k``) and ``s``
+accumulator folds.
 
 Two accumulators are provided:
 
@@ -40,6 +50,7 @@ import numpy as np
 __all__ = [
     "SLICE_BITS",
     "complex_matmul_via_real",
+    "fold_runs",
     "num_pair_gemms",
     "real_pair_matmul",
     "pair_indices",
@@ -49,15 +60,19 @@ __all__ = [
 
 # Bits of mantissa carried per int8 slice.  Slice values live in
 # [-2**(SLICE_BITS-1), 2**(SLICE_BITS-1)] so an int8 comfortably holds
-# them and k-long INT32 dot products cannot overflow for any practical
-# k (|q_a*q_b| <= 2**(2w-2); k < 2**(33-2w)).  Six bits per slice keeps
+# them, and |q_a*q_b| <= 2**(2w-2): a run of r pair products summed
+# over a contraction of k stays exact in int32 while
+# r*k < 2**(33-2w) (2**21 pair-elements at w=6; see fold_runs, which
+# shortens runs to keep it).  Six bits per slice keeps
 # the s=3..9 accuracy ladder strictly monotone before hitting the f64
 # reference floor, mirroring the paper's Table 1 trend.
 SLICE_BITS = 6
 
 
 def num_pair_gemms(num_splits: int) -> int:
-    """Number of INT8 GEMMs issued for a given split count."""
+    """Number of INT8 slice-pair products (MXU work, in units of one
+    ``m*k*n`` GEMM) for a given split count.  The jnp path issues them
+    as :func:`fold_runs` groups them, not one dot each."""
     return num_splits * (num_splits + 1) // 2
 
 
@@ -73,6 +88,37 @@ def pair_indices(num_splits: int) -> tuple[np.ndarray, np.ndarray]:
     ii = np.array([p[0] for p in pairs], dtype=np.int32)
     jj = np.array([p[1] for p in pairs], dtype=np.int32)
     return ii, jj
+
+
+def fold_runs(num_splits: int, k: int,
+              slice_bits: int = SLICE_BITS) -> tuple[tuple[int, int], ...]:
+    """Runs of :func:`pair_indices` whose INT32 products are summed in
+    int32 and folded into the accumulator once.
+
+    Returns ``(start, stop)`` ranges into the pair order: consecutive,
+    each of one shift ``i + j``, their union all pairs in order.  Every
+    term of a pair product is at most ``2**(2w-2)`` in magnitude
+    (slices lie in ``[-2**(w-1), 2**(w-1)]``), so a run of ``r`` pairs
+    over a contraction of ``k`` sums exactly in int32 while
+
+        r * k * 2**(2*slice_bits - 2) < 2**31,
+
+    and each run is capped at the largest such ``r``: at ``w = 6``,
+    ``r * k < 2**21``.  Where the cap is 1 (``k >= 2**20`` at
+    ``w = 6``) every pair is its own run, one fold each; a single pair
+    is exact for ``k < 2**(33-2w)``.
+    """
+    ii, jj = pair_indices(num_splits)
+    cap = max(1, (2**31 - 1) // (max(k, 1) << (2 * slice_bits - 2)))
+    shifts = ii + jj
+    runs = []
+    start = 0
+    for p in range(1, len(ii) + 1):
+        if (p == len(ii) or shifts[p] != shifts[start]
+                or p - start == cap):
+            runs.append((start, p))
+            start = p
+    return tuple(runs)
 
 
 def _exact_pow2(e: jax.Array, dtype) -> jax.Array:
@@ -104,6 +150,22 @@ def _pow2_scale(x: jax.Array, axis: int) -> jax.Array:
     return _exact_pow2(e.astype(jnp.int32), absmax.dtype)
 
 
+def _slices(x: jax.Array, num_splits: int, axis: int, slice_bits: int):
+    """The int8 slices of ``x``, most significant first, and its sigma
+    (with ``axis`` kept): the recurrence :func:`slice_matrix` stacks."""
+    compute_dtype = jnp.float64 if jax.config.jax_enable_x64 else jnp.float32
+    x = x.astype(compute_dtype)
+    sigma = _pow2_scale(x, axis=axis)
+    r = x / sigma  # |r| <= 0.5, scaling by a power of two is exact
+    radix = float(2 ** slice_bits)
+    out = []
+    for _ in range(num_splits):
+        q = jnp.round(r * radix)  # |q| <= 2**(slice_bits-1) after step 1
+        out.append(q.astype(jnp.int8))
+        r = r * radix - q  # exact: both operands share an exponent window
+    return out, sigma
+
+
 def slice_matrix(x: jax.Array, num_splits: int, axis: int,
                  slice_bits: int = SLICE_BITS):
     """Split ``x`` into int8 slices along its value (mantissa) axis.
@@ -118,36 +180,48 @@ def slice_matrix(x: jax.Array, num_splits: int, axis: int,
     element (relative to sigma): the splitting itself is exact in f64
     arithmetic, only the truncation to ``num_splits`` slices loses bits.
     """
-    compute_dtype = jnp.float64 if jax.config.jax_enable_x64 else jnp.float32
-    x = x.astype(compute_dtype)
-    sigma = _pow2_scale(x, axis=axis)
-    r = x / sigma  # |r| <= 0.5, scaling by a power of two is exact
-    radix = float(2 ** slice_bits)
-    out = []
-    for _ in range(num_splits):
-        q = jnp.round(r * radix)  # |q| <= 2**(slice_bits-1) after step 1
-        out.append(q.astype(jnp.int8))
-        r = r * radix - q  # exact: both operands share an exponent window
+    out, sigma = _slices(x, num_splits, axis, slice_bits)
     return jnp.stack(out), jnp.squeeze(sigma, axis=axis)
 
 
-def _int8_pair_products(a_sl, b_sl, ii, jj):
-    """Batched INT8 GEMMs over the selected slice pairs -> int32 (p,m,n)."""
-    a_p = jnp.take(a_sl, jnp.asarray(ii), axis=0)  # (p, m, k) int8
-    b_p = jnp.take(b_sl, jnp.asarray(jj), axis=0)  # (p, k, n) int8
-    return jax.lax.dot_general(
-        a_p, b_p,
-        dimension_numbers=(((2,), (1,)), ((0,), (0,))),
-        preferred_element_type=jnp.int32)
+def _run_products(a_k, b_k, k, num_splits, runs):
+    """One INT8 GEMM per fold run -> ``[(shift, int32 (m, n))]``.
+
+    ``a_k`` is ``[A_0 | ... | A_(s-1)]`` (m, s*k) and ``b_k`` is
+    ``[B_(s-1); ...; B_0]`` (s*k, n).  The run of pairs ``(i, t-i)``,
+    ``i0 <= i < i1``, reads A's columns ``[i0*k, i1*k)`` and the rows of
+    ``b_k`` holding ``B_(t-i0)`` down to ``B_(t-i1+1)``, which lie
+    side by side in that order: both operands are contiguous slabs.
+
+    Each product is written out whole (an optimization barrier): left
+    free to fuse the folds into the dots' output fusions, XLA:TPU took
+    8x as long to compile the SmolLM-360M train step for a v5e.
+    """
+    ii, jj = pair_indices(num_splits)
+    shifts, prods = [], []
+    for start, stop in runs:
+        shift = int(ii[start] + jj[start])
+        lo, hi = int(ii[start]) * k, (int(ii[stop - 1]) + 1) * k
+        off = (num_splits - 1 - shift) * k
+        shifts.append(shift)
+        prods.append(jax.lax.dot_general(
+            jax.lax.slice_in_dim(a_k, lo, hi, axis=1),
+            jax.lax.slice_in_dim(b_k, off + lo, off + hi, axis=0),
+            dimension_numbers=(((1,), (0,)), ((), ())),
+            preferred_element_type=jnp.int32))
+    return list(zip(shifts, jax.lax.optimization_barrier(prods)))
 
 
-def _accumulate_f64(prod, shifts, slice_bits):
-    """Weighted float64 accumulation of the INT32 pair products."""
-    # shifts is a static numpy array: build exact power-of-two weights
-    # host-side (jnp.exp2 is NOT exact for integer args on XLA CPU).
-    w = np.ldexp(1.0, -(np.asarray(shifts) + 2) * slice_bits)
-    return jnp.einsum("p,pmn->mn", jnp.asarray(w, jnp.float64),
-                      prod.astype(jnp.float64))
+def _accumulate_f64(prods, slice_bits):
+    """Weighted float64 sum of the runs' INT32 products."""
+    c = None
+    for shift, prod in prods:  # runs ordered large -> small
+        # Exact host-side power of two (jnp.exp2 is NOT exact for
+        # integer args on XLA CPU).
+        term = prod.astype(jnp.float64) * np.ldexp(1.0, -(shift + 2)
+                                                   * slice_bits)
+        c = term if c is None else c + term
+    return c
 
 
 def _two_sum(acc, term):
@@ -159,14 +233,15 @@ def _two_sum(acc, term):
 
 
 def _fold_df32(acc, comp, prod, w):
-    """Fold one INT32 pair product, weighted by ``w``, into (acc, comp).
+    """Fold one INT32 run product, weighted by ``w``, into (acc, comp).
 
-    ``prod`` is split exactly into hi/lo float32 parts (hi is integral
-    and |prod| stays far below 2**31 for practical k/slice_bits, so the
-    cast back to int32 is exact — and unlike int64 it does not warn
-    when jax_enable_x64 is off).  ``w`` is a non-negative power of two,
-    so the weighting is exact in f32.  The one step shared by the jnp
-    path and the Pallas kernels, which keeps them bit-identical.
+    ``prod`` is split exactly into hi/lo float32 parts (hi is integral,
+    and under :func:`fold_runs`' cap |prod| <= 2**31 - 2**(2w-2), which
+    f32 rounds to no more than itself, so the cast back to int32 is
+    exact — and unlike int64 it does not warn when jax_enable_x64 is
+    off).  ``w`` is a non-negative power of two, so the weighting is
+    exact in f32.  The one step shared by the jnp path and the Pallas
+    kernels, which keeps them bit-identical.
     """
     hi = prod.astype(jnp.float32)
     lo = (prod - hi.astype(prod.dtype)).astype(jnp.float32)
@@ -176,22 +251,23 @@ def _fold_df32(acc, comp, prod, w):
     return acc, comp + err
 
 
-def _accumulate_df32(prod, shifts, slice_bits, num_splits):
+def _accumulate_df32(prods, slice_bits, num_splits):
     """Compensated double-float32 accumulation.
 
-    Each INT32 pair product is folded into a compensated (sum, err)
+    Each run's INT32 product is folded into a compensated (sum, err)
     float32 pair by :func:`_fold_df32`, with a *non-negative*
     power-of-two weight (exact in f32, never underflows).  The caller
     divides by the deferred scale 2**(w*(s+1)) at combine time.
     """
     smax = num_splits - 1
-    # Positive shifts: pair (i, j) gets weight 2**(w*(smax - i - j)).
-    # Exact host-side powers of two (jnp.exp2 is approximate on CPU).
-    w = np.ldexp(np.float32(1.0), (smax - np.asarray(shifts)) * slice_bits)
-    acc = jnp.zeros(prod.shape[1:], jnp.float32)
-    comp = jnp.zeros(prod.shape[1:], jnp.float32)
-    for p in range(prod.shape[0]):  # pairs ordered large -> small
-        acc, comp = _fold_df32(acc, comp, prod[p], jnp.float32(w[p]))
+    shape = prods[0][1].shape
+    acc = jnp.zeros(shape, jnp.float32)
+    comp = jnp.zeros(shape, jnp.float32)
+    for shift, prod in prods:  # runs ordered large -> small
+        # Shift t gets weight 2**(w*(smax - t)), an exact host-side
+        # power of two (jnp.exp2 is approximate on CPU).
+        w = np.ldexp(np.float32(1.0), (smax - shift) * slice_bits)
+        acc, comp = _fold_df32(acc, comp, prod, jnp.float32(w))
     deferred = 2.0 ** (-slice_bits * (smax + 2))
     return acc, comp, deferred
 
@@ -203,25 +279,30 @@ def _real_ozaki(a, b, num_splits, accumulator, out_dtype, slice_bits):
     k2, n = b.shape
     if k != k2:
         raise ValueError(f"shape mismatch {a.shape} @ {b.shape}")
-    a_sl, sigma_a = slice_matrix(a, num_splits, axis=1,
-                                 slice_bits=slice_bits)
-    b_sl, sigma_b = slice_matrix(b, num_splits, axis=0,
-                                 slice_bits=slice_bits)
-    ii, jj = pair_indices(num_splits)
-    prod = _int8_pair_products(a_sl, b_sl, ii, jj)
-    shifts = ii + jj
-    if accumulator == "f64":
-        c = _accumulate_f64(prod, shifts, slice_bits)
-        c = c.astype(out_dtype)
-    elif accumulator == "df32":
-        acc, comp, deferred = _accumulate_df32(prod, shifts, slice_bits,
-                                               num_splits)
-        c = (acc.astype(out_dtype) + comp.astype(out_dtype)) * deferred
-    else:
+    if accumulator not in ("df32", "f64"):
         raise ValueError(f"unknown accumulator {accumulator!r};"
                          " expected 'df32' or 'f64'")
-    scale = (sigma_a[:, None] * sigma_b[None, :]).astype(out_dtype)
-    return c * scale
+    with jax.named_scope("phase_slice"):
+        a_sl, sigma_a = _slices(a, num_splits, 1, slice_bits)
+        b_sl, sigma_b = _slices(b, num_splits, 0, slice_bits)
+        a_k = jnp.concatenate(a_sl, axis=1)        # (m, s*k)
+        b_k = jnp.concatenate(b_sl[::-1], axis=0)  # (s*k, n), reversed
+        # Written out once for every run's dot to read: left fusible,
+        # XLA:TPU re-derives the slabs inside each dot's fusion, which
+        # ran the SmolLM-360M train step 5% slower on a v5e.
+        a_k, b_k = jax.lax.optimization_barrier((a_k, b_k))
+    with jax.named_scope("phase_pairs"):
+        prods = _run_products(a_k, b_k, k, num_splits,
+                              fold_runs(num_splits, k, slice_bits))
+    with jax.named_scope("phase_fold"):
+        if accumulator == "f64":
+            c = _accumulate_f64(prods, slice_bits).astype(out_dtype)
+        else:
+            acc, comp, deferred = _accumulate_df32(prods, slice_bits,
+                                                   num_splits)
+            c = (acc.astype(out_dtype) + comp.astype(out_dtype)) * deferred
+        scale = (sigma_a * sigma_b).astype(out_dtype)
+        return c * scale
 
 
 def real_pair_matmul(real_matmul, a, b, real_out):
@@ -261,8 +342,10 @@ def ozaki_matmul(a, b, num_splits: int = 6, accumulator: str = "df32",
     Args:
       a: (m, k) real or complex floating array.
       b: (k, n) real or complex floating array.
-      num_splits: slice count ``s``; issues ``s*(s+1)/2`` INT8 GEMMs and
-        carries roughly ``slice_bits * s`` mantissa bits.
+      num_splits: slice count ``s``; does ``s*(s+1)/2`` INT8 pair
+        products of MXU work, issued as one INT8 dot per
+        :func:`fold_runs` run (``s`` at practical ``k``), and carries
+        roughly ``slice_bits * s`` mantissa bits.
       accumulator: ``"df32"`` (compensated float32 pairs, FP64-free) or
         ``"f64"`` (plain float64 accumulation).
       out_dtype: result dtype; defaults to the common input dtype.

@@ -2,9 +2,11 @@
 
 One kernel computes the whole emulated GEMM: the grid walks
 ``(m-tiles, n-tiles, slice-pairs, k-tiles)`` and every step issues one
-INT8xINT8->INT32 tile product on the MXU and sums it over the k-tiles
-in an int32 VMEM scratch; after a pair's last k-tile the full product
-is weighted by the pair's power-of-two shift and folded into a
+INT8xINT8->INT32 tile product on the MXU and sums it in an int32 VMEM
+scratch, over the k-tiles and over the pairs of one
+:func:`repro.core.ozaki.fold_runs` run (pairs of one shift); after the
+run's last pair and k-tile the summed product is weighted by the run's
+power-of-two shift and folded into a
 compensated float32 accumulator held in the revisited output tiles
 (TwoSum, the reference path's own step, so the ~48-bit "df32" accuracy
 survives the single-f32 output constraint of FP64-free hardware).  The kernel emits separate hi/lo
@@ -59,11 +61,12 @@ import functools
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.core.ozaki import (SLICE_BITS, _fold_df32, pair_indices,
-                              slice_matrix)
+from repro.core.ozaki import (SLICE_BITS, _fold_df32, fold_runs,
+                              pair_indices, slice_matrix)
 from repro.kernels import slicing, tile_model
 from repro.kernels.tile_model import LANE, SUBLANE_INT8, align_up
 
@@ -89,25 +92,31 @@ def _pow2_f32(e, shape):
     return jax.lax.bitcast_convert_type(bits, jnp.float32)
 
 
-def _accumulate(hi_ref, lo_ref, acc_ref, part, wexp):
-    """Sum one INT32 tile product over the k-tiles, then fold it in.
+def _accumulate(hi_ref, lo_ref, acc_ref, part, wexp, edge):
+    """Sum one INT32 tile product over a run's k-tiles and pairs, then
+    fold it in.
 
-    The shared tail of every kernel body.  The k reduction stays in
-    int32 (exact) in the ``acc_ref`` scratch; after the last k-tile the
-    pair's full product is weighted by ``2**wexp`` and folded into the
-    hi/lo refs by :func:`repro.core.ozaki._fold_df32`, the step the jnp
-    df32 path takes for the same pair.  So the kernel equals that path
-    bit for bit at any tiling, and its compensation sees one term per
-    pair rather than one per k-tile.
+    The shared tail of every kernel body.  ``edge`` is the pair's
+    entry of the schedule's run edges (:func:`_pair_schedule_arrays`):
+    bit 0 marks the first pair of a fold run, bit 1 its last.  The
+    reduction over the k-tiles and the run's pairs stays in int32
+    (exact) in the ``acc_ref`` scratch, reset at the run's first pair
+    and k-tile; after its last pair and k-tile the run's product is
+    weighted by ``2**wexp`` and folded into the hi/lo refs by
+    :func:`repro.core.ozaki._fold_df32`, the step the jnp df32 path
+    takes for the same run.  So the kernel equals that path bit for bit
+    at any tiling, and its compensation sees one term per run rather
+    than one per k-tile.
     """
     p = pl.program_id(2)
     kt = pl.program_id(3)
+    first = jnp.logical_and(kt == 0, (edge & 1) == 1)
 
-    @pl.when(kt == 0)
+    @pl.when(first)
     def _():
         acc_ref[...] = part
 
-    @pl.when(kt > 0)
+    @pl.when(jnp.logical_not(first))
     def _():
         acc_ref[...] += part
 
@@ -116,15 +125,16 @@ def _accumulate(hi_ref, lo_ref, acc_ref, part, wexp):
         hi_ref[...] = jnp.zeros_like(hi_ref)
         lo_ref[...] = jnp.zeros_like(lo_ref)
 
-    @pl.when(kt == pl.num_programs(3) - 1)
+    @pl.when(jnp.logical_and(kt == pl.num_programs(3) - 1,
+                             (edge & 2) == 2))
     def _():
         prod = acc_ref[...]
         hi_ref[...], lo_ref[...] = _fold_df32(
             hi_ref[...], lo_ref[...], prod, _pow2_f32(wexp, prod.shape))
 
 
-def _split_gemm_kernel_v2(ii_ref, jj_ref, wexp_ref, a_ref, b_ref,
-                          hi_ref, lo_ref, acc_ref):
+def _split_gemm_kernel_v2(ii_ref, jj_ref, wexp_ref, edge_ref, a_ref,
+                          b_ref, hi_ref, lo_ref, acc_ref):
     """Grid: (m/bm, n/bn, num_pairs, k/bk). One INT8 tile product.
 
     The slice pair for step ``p`` was already selected by the BlockSpec
@@ -134,17 +144,17 @@ def _split_gemm_kernel_v2(ii_ref, jj_ref, wexp_ref, a_ref, b_ref,
     (pair index, k-tile) and double as the compensated accumulator.
     """
     del ii_ref, jj_ref  # consumed by the index maps
+    p = pl.program_id(2)
     part = jax.lax.dot_general(
         a_ref[0], b_ref[0],
         dimension_numbers=(((1,), (0,)), ((), ())),
         preferred_element_type=jnp.int32)
-    _accumulate(hi_ref, lo_ref, acc_ref, part,
-                wexp_ref[pl.program_id(2)])
+    _accumulate(hi_ref, lo_ref, acc_ref, part, wexp_ref[p], edge_ref[p])
 
 
-def _split_gemm_kernel_fused(ii_ref, jj_ref, wexp_ref, ah_ref, al_ref,
-                             bh_ref, bl_ref, hi_ref, lo_ref, acc_ref, *,
-                             num_splits, slice_bits):
+def _split_gemm_kernel_fused(ii_ref, jj_ref, wexp_ref, edge_ref, ah_ref,
+                             al_ref, bh_ref, bl_ref, hi_ref, lo_ref,
+                             acc_ref, *, num_splits, slice_bits):
     """Fused variant: quantize f32-pair tiles to int8 in VMEM first."""
     p = pl.program_id(2)
     a_q = slicing.quantize_tile(ah_ref[...], al_ref[...], ii_ref[p],
@@ -155,7 +165,7 @@ def _split_gemm_kernel_fused(ii_ref, jj_ref, wexp_ref, ah_ref, al_ref,
         a_q, b_q,
         dimension_numbers=(((1,), (0,)), ((), ())),
         preferred_element_type=jnp.int32)
-    _accumulate(hi_ref, lo_ref, acc_ref, part, wexp_ref[p])
+    _accumulate(hi_ref, lo_ref, acc_ref, part, wexp_ref[p], edge_ref[p])
 
 
 def _compiler_params(bm: int, bn: int, bk: int, fused: bool = False):
@@ -186,13 +196,23 @@ def _block(dim: int, requested: int, multiple: int) -> int:
     return align_up(min(requested, align_up(dim, multiple)), multiple)
 
 
-def _pair_schedule_arrays(num_splits: int, slice_bits: int):
-    """(ii, jj, wexp) int32 device arrays for the scalar-prefetch grid."""
+def _pair_schedule_arrays(num_splits: int, slice_bits: int, k: int):
+    """(ii, jj, wexp, edge) int32 device arrays for the scalar-prefetch
+    grid.
+
+    ``edge`` marks the :func:`repro.core.ozaki.fold_runs` runs of a
+    contraction of ``k`` (the operands' own extent, not the padded one,
+    so the runs are the jnp path's): bit 0 on a run's first pair, bit 1
+    on its last.
+    """
     ii, jj = pair_indices(num_splits)
     smax = num_splits - 1
     wexp = (smax - (ii + jj)) * slice_bits
-    return (jnp.asarray(ii, jnp.int32), jnp.asarray(jj, jnp.int32),
-            jnp.asarray(wexp, jnp.int32))
+    edge = np.zeros(ii.shape, np.int32)
+    for start, stop in fold_runs(num_splits, k, slice_bits):
+        edge[start] |= 1
+        edge[stop - 1] |= 2
+    return tuple(jnp.asarray(x, jnp.int32) for x in (ii, jj, wexp, edge))
 
 
 @functools.partial(jax.jit, static_argnames=(
@@ -221,7 +241,7 @@ def split_gemm_pallas(a_sl, b_sl, num_splits: int,
     """
     _, m, k = a_sl.shape
     _, _, n = b_sl.shape
-    ii, jj, wexp = _pair_schedule_arrays(num_splits, slice_bits)
+    ii, jj, wexp, edge = _pair_schedule_arrays(num_splits, slice_bits, k)
     num_pairs = ii.shape[0]
 
     bm = _block(m, block_m, SUBLANE_INT8)
@@ -234,19 +254,19 @@ def split_gemm_pallas(a_sl, b_sl, num_splits: int,
     grid = (mp // bm, np_ // bn, num_pairs, kp // bk)
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=3,
+        num_scalar_prefetch=4,
         grid=grid,
         in_specs=[
             pl.BlockSpec((1, bm, bk),
-                         lambda i, j, p, kt, ii, jj, we: (ii[p], i, kt)),
+                         lambda i, j, p, kt, ii, jj, we, ed: (ii[p], i, kt)),
             pl.BlockSpec((1, bk, bn),
-                         lambda i, j, p, kt, ii, jj, we: (jj[p], kt, j)),
+                         lambda i, j, p, kt, ii, jj, we, ed: (jj[p], kt, j)),
         ],
         out_specs=[
             pl.BlockSpec((bm, bn),
-                         lambda i, j, p, kt, ii, jj, we: (i, j)),
+                         lambda i, j, p, kt, ii, jj, we, ed: (i, j)),
             pl.BlockSpec((bm, bn),
-                         lambda i, j, p, kt, ii, jj, we: (i, j)),
+                         lambda i, j, p, kt, ii, jj, we, ed: (i, j)),
         ],
         scratch_shapes=[pltpu.VMEM((bm, bn), jnp.int32)],
     )
@@ -260,7 +280,7 @@ def split_gemm_pallas(a_sl, b_sl, num_splits: int,
         compiler_params=_compiler_params(bm, bn, bk),
         interpret=interpret,
         name="ozaki_int8_tile",
-    )(ii, jj, wexp, a_sl, b_sl)
+    )(ii, jj, wexp, edge, a_sl, b_sl)
     return hi[:m, :n], lo[:m, :n]
 
 
@@ -287,7 +307,7 @@ def split_gemm_pallas_fused(a_hi, a_lo, b_hi, b_lo, num_splits: int,
     """
     m, k = a_hi.shape
     _, n = b_hi.shape
-    ii, jj, wexp = _pair_schedule_arrays(num_splits, slice_bits)
+    ii, jj, wexp, edge = _pair_schedule_arrays(num_splits, slice_bits, k)
     num_pairs = ii.shape[0]
 
     bm = _block(m, block_m, SUBLANE_INT8)
@@ -300,18 +320,18 @@ def split_gemm_pallas_fused(a_hi, a_lo, b_hi, b_lo, num_splits: int,
     grid = (mp // bm, np_ // bn, num_pairs, kp // bk)
 
     a_spec = pl.BlockSpec((bm, bk),
-                          lambda i, j, p, kt, ii, jj, we: (i, kt))
+                          lambda i, j, p, kt, ii, jj, we, ed: (i, kt))
     b_spec = pl.BlockSpec((bk, bn),
-                          lambda i, j, p, kt, ii, jj, we: (kt, j))
+                          lambda i, j, p, kt, ii, jj, we, ed: (kt, j))
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=3,
+        num_scalar_prefetch=4,
         grid=grid,
         in_specs=[a_spec, a_spec, b_spec, b_spec],
         out_specs=[
             pl.BlockSpec((bm, bn),
-                         lambda i, j, p, kt, ii, jj, we: (i, j)),
+                         lambda i, j, p, kt, ii, jj, we, ed: (i, j)),
             pl.BlockSpec((bm, bn),
-                         lambda i, j, p, kt, ii, jj, we: (i, j)),
+                         lambda i, j, p, kt, ii, jj, we, ed: (i, j)),
         ],
         scratch_shapes=[pltpu.VMEM((bm, bn), jnp.int32)],
     )
@@ -326,7 +346,7 @@ def split_gemm_pallas_fused(a_hi, a_lo, b_hi, b_lo, num_splits: int,
         compiler_params=_compiler_params(bm, bn, bk, fused=True),
         interpret=interpret,
         name="ozaki_int8_tile_fused",
-    )(ii, jj, wexp, a_hi, a_lo, b_hi, b_lo)
+    )(ii, jj, wexp, edge, a_hi, a_lo, b_hi, b_lo)
     return hi[:m, :n], lo[:m, :n]
 
 
@@ -346,7 +366,7 @@ def split_gemm_pallas_v1(a_sl, b_sl, num_splits: int,
     """
     _, m, k = a_sl.shape
     _, _, n = b_sl.shape
-    ii, jj, wexp = _pair_schedule_arrays(num_splits, slice_bits)
+    ii, jj, wexp, edge = _pair_schedule_arrays(num_splits, slice_bits, k)
     a_pairs = jnp.take(a_sl, ii, axis=0)
     b_pairs = jnp.take(b_sl, jj, axis=0)
 
@@ -362,19 +382,19 @@ def split_gemm_pallas_v1(a_sl, b_sl, num_splits: int,
     # The v2 body; only the index maps differ: step p reads gathered
     # pair p instead of looking its slices up in the schedule.
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=3,
+        num_scalar_prefetch=4,
         grid=grid,
         in_specs=[
             pl.BlockSpec((1, bm, bk),
-                         lambda i, j, p, kt, ii, jj, we: (p, i, kt)),
+                         lambda i, j, p, kt, ii, jj, we, ed: (p, i, kt)),
             pl.BlockSpec((1, bk, bn),
-                         lambda i, j, p, kt, ii, jj, we: (p, kt, j)),
+                         lambda i, j, p, kt, ii, jj, we, ed: (p, kt, j)),
         ],
         out_specs=[
             pl.BlockSpec((bm, bn),
-                         lambda i, j, p, kt, ii, jj, we: (i, j)),
+                         lambda i, j, p, kt, ii, jj, we, ed: (i, j)),
             pl.BlockSpec((bm, bn),
-                         lambda i, j, p, kt, ii, jj, we: (i, j)),
+                         lambda i, j, p, kt, ii, jj, we, ed: (i, j)),
         ],
         scratch_shapes=[pltpu.VMEM((bm, bn), jnp.int32)],
     )
@@ -388,7 +408,7 @@ def split_gemm_pallas_v1(a_sl, b_sl, num_splits: int,
         compiler_params=_compiler_params(bm, bn, bk),
         interpret=interpret,
         name="ozaki_int8_tile_v1",
-    )(ii, jj, wexp, a_pairs, b_pairs)
+    )(ii, jj, wexp, edge, a_pairs, b_pairs)
     return hi[:m, :n], lo[:m, :n]
 
 

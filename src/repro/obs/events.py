@@ -159,8 +159,11 @@ class MetricsRun:
 
         Called on the host once per *execution* of each offloaded site
         (scan iterations and mesh shards each count): increments the
-        ``site_exec`` counter labeled by site name and, on the first
-        execution of a site, emits its static ``site_exec`` record —
+        ``site_exec`` counter labeled by site name, adds the payload's
+        ``int8_dots`` (the INT8 dots that execution issued,
+        ``Site.int8_dots``) to the ``int8_dots`` counter of the same
+        label and, on the first execution of a site, emits its static
+        ``site_exec`` record —
         so the JSONL stream proves the hook fired even if the process
         dies before the registry snapshot is flushed.
         """
@@ -168,6 +171,9 @@ class MetricsRun:
         def handler(payload: dict) -> None:
             site = payload.get("site", "?")
             self.registry.counter("site_exec", site=site).inc()
+            if payload.get("int8_dots"):
+                self.registry.counter("int8_dots", site=site).inc(
+                    payload["int8_dots"])
             with self._lock:
                 first = site not in self._declared_exec
                 if first:

@@ -28,8 +28,9 @@ a restarted server skips re-tracing.  Because the persisted program is
 serialized via ``jax.export`` — which cannot carry debug callbacks —
 the per-execution site-event hook is replaced by *static accounting*:
 after each program call the runner bumps ``site_exec`` by each
-offloaded site's static trip multiplicity, which equals the hook's
-count exactly for these forward-only programs.
+offloaded site's static trip multiplicity (and ``int8_dots`` by that
+times the site's ``int8_dots``), which equals the hook's count exactly
+for these forward-only programs.
 """
 
 from __future__ import annotations
@@ -235,6 +236,8 @@ class Runner:
                 continue
             self.metrics.registry.counter(
                 "site_exec", site=s.name).inc(s.mult)
+            self.metrics.registry.counter(
+                "int8_dots", site=s.name).inc(s.mult * s.int8_dots)
             if s.name not in self._seen_static:
                 self._seen_static.add(s.name)
                 self.metrics.event(

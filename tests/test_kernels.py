@@ -62,7 +62,7 @@ class TestV2BitIdentity:
 
     @pytest.mark.parametrize("m,k,n", [(37, 130, 51), (100, 200, 60),
                                        (64, 96, 64), (1, 129, 1)])
-    @pytest.mark.parametrize("num_splits", [3, 5, 9])
+    @pytest.mark.parametrize("num_splits", [3, 4, 5, 9])
     @pytest.mark.parametrize("dtype", [jnp.float32, jnp.float64])
     def test_odd_shapes_all_splits(self, m, k, n, num_splits, dtype):
         a, b = _pair(m, k, n, 7, dtype)
@@ -71,6 +71,22 @@ class TestV2BitIdentity:
         c_ref = ozaki_ref(a, b, num_splits=num_splits,
                           accumulator="df32", out_dtype=jnp.float64)
         assert float(jnp.max(jnp.abs(c_pal - c_ref))) == 0.0
+
+    @pytest.mark.parametrize("k,edges", [
+        # One run per shift: a run's first pair opens (1), its last
+        # folds (2), a lone pair does both (3).
+        (960, [3, 1, 2, 1, 0, 2, 1, 0, 0, 2]),
+        # Runs cut at two pairs (fold_runs' int32 cap).
+        (2**19 + 2**18, [3, 1, 2, 1, 2, 3, 1, 2, 1, 2]),
+        (2**20, [3] * 10),
+    ])
+    def test_schedule_marks_the_fold_runs(self, k, edges):
+        from repro.core.ozaki import fold_runs
+
+        *_, edge = ops._pair_schedule_arrays(4, 6, k)
+        assert edge.tolist() == edges
+        assert [p for p, e in enumerate(edges) if e & 1] == \
+            [start for start, _ in fold_runs(4, k)]
 
     def test_v1_matches_v2_bitwise(self):
         # Same slices, same schedule, same TwoSum stream: the legacy
@@ -143,7 +159,7 @@ class TestFusedSlicing:
     """In-kernel quantization vs the shared slicing spec."""
 
     @pytest.mark.parametrize("m,k,n", [(37, 130, 51), (64, 96, 64)])
-    @pytest.mark.parametrize("num_splits", [3, 6, 9])
+    @pytest.mark.parametrize("num_splits", [3, 4, 6, 9])
     def test_fused_f32_bitwise_vs_reference(self, m, k, n, num_splits):
         # For f32 sources lo == 0, the pair recurrence collapses to the
         # core slicing recurrence, and the fused path must equal the

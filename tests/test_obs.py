@@ -452,6 +452,29 @@ class TestSiteEventStaging:
         assert counts == {"scan0/dot0": 3, "dot0": 1}
 
 
+    def test_int8_dots_count_runs_times_mult(self, tmp_path):
+        from repro.core.ozaki import fold_runs
+
+        c = jnp.ones((128, 128), jnp.float32) * 0.01
+        xs = jnp.ones((3, 128, 128), jnp.float32) * 0.01
+        with MetricsRun(tmp_path) as run:
+            wrapped = offload(self._scan, self.POLICY,
+                              on_site_event=run.site_event_handler())
+            step = jax.jit(wrapped)
+            assert self._callbacks(step.lower(c, xs).as_text()) == 1
+            for _ in range(2):
+                step(c, xs)
+            jax.effects_barrier()
+            dots = {m["labels"]["site"]: m["value"]
+                    for m in run.registry.snapshot()
+                    if m["name"] == "int8_dots"}
+        sites = wrapped.sites(c, xs)
+        # s = 2 at k = 128: two runs, so two INT8 dots an execution.
+        assert all(s.int8_dots == len(fold_runs(2, 128)) == 2
+                   for s in sites)
+        assert dots == {s.name: 2 * s.mult * s.int8_dots for s in sites}
+        assert dots == {"scan0/dot0": 12, "dot0": 4}
+
     def test_remat_train_step_stages_one_callback(self):
         from repro.launch.train import build_train_step
         from repro.train import AdamW
@@ -468,6 +491,28 @@ class TestSiteEventStaging:
         assert sum(s.mult for s in sites if s.offloaded) > 1
         lowered = jax.jit(wrapped).lower(params, state, batch).as_text()
         assert self._callbacks(lowered) == 1
+
+
+class TestPhaseScopes:
+    def test_offloaded_site_ops_carry_phase_scopes(self):
+        # The engine's phases nest inside the site's scope, so each op
+        # is still charged to its site and also to its phase.
+        import re
+
+        def f(a, b):
+            return jnp.tanh(a @ b)
+
+        a = jnp.ones((128, 128), jnp.float32)
+        pol = PrecisionPolicy(backend="fp64_int8", default_splits=4,
+                              min_dim=64)
+        hlo = jax.jit(offload(f, pol)).lower(a, a).compile().as_text()
+        names = re.findall(r'op_name="([^"]*)"', hlo)
+        for phase in ("phase_slice", "phase_pairs", "phase_fold"):
+            assert any(re.search(rf"(^|/)ozaki_dot0/(.*/)?{phase}/", n)
+                       for n in names), phase
+        # (Names of reducer bodies are relative: only the ops' own.)
+        assert not any("phase_" in n and "ozaki_dot0/" not in n
+                       for n in names if n.startswith("jit("))
 
 
 class TestTracerOnProfilerClock:
