@@ -12,10 +12,10 @@ Layers:
 
 from .backends import (GemmBackend, example_specs, get_backend,
                        register_backend, registered_families)
-from .intercept import (CacheInfo, PersistInfo, Site, offload,
-                        site_report, transform_jaxpr)
+from .intercept import (CacheInfo, Native, PersistInfo, Site, SiteList,
+                        offload, site_report, transform_jaxpr)
 from .ozaki import (SLICE_BITS, num_pair_gemms, ozaki_matmul,
-                    pair_indices, slice_matrix)
+                    ozaki_ragged_dot, pair_indices, slice_matrix)
 from .precision import (AdaptiveGemm, PrecisionPolicy, SiteState,
                         canonical_site, estimate_rel_error,
                         measure_splits, predict_splits,
@@ -29,14 +29,17 @@ __all__ = [
     "GemmBackend",
     "PrecisionPolicy",
     "Site",
+    "SiteList",
     "SiteState",
     "estimate_rel_error",
     "example_specs",
     "get_backend",
     "measure_splits",
+    "Native",
     "num_pair_gemms",
     "offload",
     "ozaki_matmul",
+    "ozaki_ragged_dot",
     "pair_indices",
     "PersistInfo",
     "predict_splits",

@@ -39,7 +39,7 @@ from typing import Callable, Dict, List, Optional
 import jax
 import jax.numpy as jnp
 
-from .ozaki import complex_matmul_via_real, ozaki_matmul
+from .ozaki import complex_matmul_via_real, ozaki_matmul, ozaki_ragged_dot
 from .precision import (AdaptiveGemm, PrecisionPolicy,
                         splits_for_tolerance)
 
@@ -71,7 +71,18 @@ class GemmBackend:
       by split-free engines (``"dgemm"``) and by ``"adaptive"``;
     * ``site`` — stable site name, used by stateful backends for
       per-site caching and by policies for per-site overrides.
+
+    A backend with a grouped form also defines
+    ``ragged_dot(lhs, rhs, group_sizes, dims, out_dtype=None,
+    num_splits=None, site="default")``, the product of
+    ``jax.lax.ragged_dot_general`` in the two forms of
+    :func:`repro.core.ozaki.ragged_form`; where it is None (the Pallas
+    kernels) the offload transform leaves grouped sites native and says
+    so in the site's ``reason``.
     """
+
+    #: The grouped product (see above), or None.
+    ragged_dot = None
 
     #: The spec string this backend was built from (round-trips through
     #: :func:`get_backend`).
@@ -146,6 +157,14 @@ class OzakiBackend(GemmBackend):
                             out_dtype=out_dtype,
                             slice_bits=self.policy.slice_bits)
 
+    def ragged_dot(self, lhs, rhs, group_sizes, dims, *, out_dtype=None,
+                   num_splits=None, site: str = "default"):
+        return ozaki_ragged_dot(
+            lhs, rhs, group_sizes, dims,
+            num_splits=self.resolve_splits(num_splits, site),
+            accumulator=self.policy.accumulator, out_dtype=out_dtype,
+            slice_bits=self.policy.slice_bits)
+
 
 class PallasBackend(OzakiBackend):
     """Fused Pallas split-GEMM kernel (:mod:`repro.kernels.ops`).
@@ -161,7 +180,10 @@ class PallasBackend(OzakiBackend):
     autotuning sweep.  ``"pallas_int8*:fused"`` enables in-kernel
     slicing (operands enter as f32 hi/lo pairs and are quantized
     tile-by-tile in VMEM; slices never round-trip through HBM).
+    The kernels have no grouped form (``ragged_dot`` is None).
     """
+
+    ragged_dot = None
 
     def __init__(self, spec, policy, splits: Optional[int] = None,
                  fused: bool = False):
@@ -241,6 +263,20 @@ class AdaptiveBackend(GemmBackend):
                                 out_dtype=out_dtype,
                                 slice_bits=self.policy.slice_bits)
         return self.gemm(a, b, site=site, out_dtype=out_dtype)
+
+    def ragged_dot(self, lhs, rhs, group_sizes, dims, *, out_dtype=None,
+                   num_splits=None, site: str = "default"):
+        """At the a-priori split count of the contraction extent, as
+        inside a trace: grouped operands are never probed."""
+        del num_splits, site
+        (contract, _), _ = dims.dot_dimension_numbers
+        s = splits_for_tolerance(self.target_rel,
+                                 k=jnp.shape(lhs)[contract[0]],
+                                 slice_bits=self.policy.slice_bits)
+        return ozaki_ragged_dot(lhs, rhs, group_sizes, dims, num_splits=s,
+                                accumulator=self.policy.accumulator,
+                                out_dtype=out_dtype,
+                                slice_bits=self.policy.slice_bits)
 
 
 # --------------------------------------------------------------------------

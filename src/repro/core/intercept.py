@@ -1,4 +1,4 @@
-"""Automatic BLAS offload: a jaxpr->jaxpr transform over ``dot_general``.
+"""Automatic BLAS offload: a jaxpr->jaxpr transform over matrix products.
 
 The paper intercepts BLAS calls of an *unmodified* application at the
 linker level and redirects large GEMMs to the INT8 emulation engine.
@@ -36,18 +36,32 @@ What the transform covers:
   ``jax.jit``;
 * reverse-mode AD: each offloaded site carries a ``custom_vjp`` whose
   backward pass runs the *same* backend on the transposed operands
-  ("emulated backward"), so ``jax.grad`` works through offloaded code.
+  ("emulated backward"), so ``jax.grad`` works through offloaded code;
+* grouped products, ``ragged_dot_general`` (what ``jax.lax.ragged_dot``
+  and its VJP bind), in the two forms
+  :func:`repro.core.ozaki.ragged_form` names: rows ragged against a
+  stack of group matrices (the forward and dX), and a ragged contraction
+  (dW).  They run through the backend's ``ragged_dot``, with a
+  ``custom_vjp`` whose backward products are grouped too; a backend
+  without a grouped form (the Pallas kernels) leaves them native.
 
 Functions wrapped in ``jax.custom_jvp``/``jax.custom_vjp`` are left
 opaque — rewriting their primal would silently discard the user's
-derivative rule — so their internal matmuls stay native.
+derivative rule — so their internal matmuls stay native.  Every
+contraction left native that is not a ``dot_general`` the gates kept
+(contractions inside such calls, ``conv_general_dilated``, grouped
+products in another form or under a backend without a grouped form)
+is listed by primitive and name in the site list's ``native``
+(:class:`SiteList`).
 
 Site naming is structural and **shared verbatim** between
 :func:`site_report` and :func:`offload`: ``dot{i}`` numbers the
 ``dot_general`` sites of a scope in program order (call-like primitives
-are inlined into the enclosing scope), and control-flow/SPMD bodies
-extend the path — ``scan0/dot1``, ``while2/cond/dot0``,
-``cond1/br0/dot0``, ``shmap0/dot1``, ``shmap0/scan0/dot0``.
+are inlined into the enclosing scope), ``ragged{i}`` its grouped sites
+on a count of their own, and control-flow/SPMD bodies
+extend the path — ``scan0/dot1``, ``scan0/ragged1``,
+``while2/cond/dot0``, ``cond1/br0/dot0``, ``shmap0/dot1``,
+``shmap0/scan0/dot0``.
 ``PrecisionPolicy.site_splits`` keys against exactly these names, which
 is the paper's "enumerate first, then tune per site" workflow.
 
@@ -83,30 +97,71 @@ import jax.numpy as jnp
 import numpy as np
 
 from jax import export as _jax_export  # not auto-imported by `import jax`
+from jax._src import source_info_util
 from jax.extend import core as jex_core
 
 from .backends import GemmBackend, get_backend
-from .ozaki import fold_runs
+from .ozaki import (RAGGED_CONTRACTION, RAGGED_ROWS, fold_runs,
+                    ragged_form)
 from .precision import PrecisionPolicy
 
 __all__ = ["offload", "site_report", "transform_jaxpr", "Site",
-           "CacheInfo", "PersistInfo", "OFFLOAD_CACHE_SIZE"]
+           "SiteList", "Native", "CacheInfo", "PersistInfo",
+           "OFFLOAD_CACHE_SIZE"]
 
-# Call-like primitives whose body jaxpr is inlined into the enclosing
-# scope: they neither change shapes nor iterate, so their sites share
-# the enclosing scope's dot numbering.  ("jit" is a nested jax.jit;
-# "remat2" is the primitive behind jax.checkpoint/jax.remat, and
-# inlining it only trades the rematerialization schedule, not values
-# or derivatives.)
-# Control-flow primitives (scan/while/cond) get their own scope path
-# and dedicated rebuild handlers below.  Custom-derivative calls
-# (custom_jvp_call / custom_vjp_call*) are deliberately NOT inlined:
-# their bodies define their own differentiation semantics
-# (stop-gradients, stabilized rules), so inlining the primal would
-# silently replace the user's rule under jax.grad.  They take the
-# default native re-bind and their internal matmuls stay native; wrap
-# the function's *caller* if those sites matter.
-_INLINE_PRIMITIVES = {"jit", "closed_call", "remat2"}
+#: How the walker and the evaluator treat a primitive: the one table of
+#: primitive names both read (any other primitive is re-bound as is).
+#:
+#: * ``dot`` / ``ragged``: a site (``dot_general``, ``ragged_dot_general``);
+#: * ``inline``: call-like primitives whose body is inlined into the
+#:   enclosing scope — they neither change shapes nor iterate, so their
+#:   sites share the enclosing scope's numbering ("jit" is a nested
+#:   jax.jit; "remat2" is the primitive behind jax.checkpoint, and
+#:   inlining it only trades the rematerialization schedule, not values
+#:   or derivatives);
+#: * ``scan`` / ``while`` / ``cond`` / ``shmap``: bodies with their own
+#:   scope path and rebuild handlers;
+#: * ``pvary`` / ``psum_invariant``: shard_map's varying-axis artifacts,
+#:   undone in a rebuilt shard_map body;
+#: * ``contraction``: a contraction with no Ozaki path, left native and
+#:   listed in :attr:`SiteList.native`;
+#: * ``opaque``: custom-derivative calls, deliberately NOT inlined: their
+#:   bodies define their own differentiation semantics (stop-gradients,
+#:   stabilized rules), so inlining the primal would silently replace
+#:   the user's rule under jax.grad.  They take the default native
+#:   re-bind; the contractions inside are listed as native.  Wrap the
+#:   function's *caller* if those sites matter.
+_PRIMITIVES = {
+    "dot_general": "dot", "ragged_dot_general": "ragged",
+    "jit": "inline", "closed_call": "inline", "remat2": "inline",
+    "scan": "scan", "while": "while", "cond": "cond", "shard_map": "shmap",
+    "pvary": "pvary", "psum_invariant": "psum_invariant",
+    "conv_general_dilated": "contraction",
+    "custom_jvp_call": "opaque", "custom_vjp_call": "opaque",
+    "custom_vjp_call_jaxpr": "opaque",
+}
+
+#: A contraction the transform leaves native that is not a site the
+#: gates kept native: its primitive, structural name and why.
+Native = namedtuple("Native", ["primitive", "name", "reason"])
+
+
+class SiteList(list):
+    """The :class:`Site` records of one traced program, in discovery
+    order, and ``native``: every contraction left native that is not a
+    ``dot_general`` the gates kept (:class:`Native` records — grouped
+    sites left native, ``conv_general_dilated``, contractions inside
+    custom-derivative calls)."""
+
+    def __init__(self, sites=(), skipped=()):
+        super().__init__(sites)
+        self.skipped = tuple(skipped)
+
+    @property
+    def native(self) -> Tuple[Native, ...]:
+        grouped = tuple(Native(s.primitive, s.name, s.reason) for s in self
+                        if s.primitive != "dot_general" and not s.offloaded)
+        return grouped + self.skipped
 
 
 def _check_overrides(policy: PrecisionPolicy, decisions) -> None:
@@ -155,6 +210,11 @@ class Site:
     offloaded site's forward product issues: one per
     :func:`repro.core.ozaki.fold_runs` run of its split count and
     contraction, four times that for a complex site (0 when native).
+    ``primitive`` is the site's contraction primitive; a grouped site
+    (``ragged_dot_general``) has ``group_count`` groups, its contraction
+    ``k`` (the rows, for a ragged contraction) and ``m`` at their static
+    upper bound: how many rows a call really routes only the running
+    program knows (the ``rows`` of its site events).
     """
 
     def __init__(self, name: str, lhs_shape, rhs_shape, dtype,
@@ -162,7 +222,8 @@ class Site:
                  m: int = 0, k: int = 0, n: int = 0, batch: int = 1,
                  mult: int = 1, spmd_axes=(), backend: str = "",
                  eligible: bool = False, tiles: dict | None = None,
-                 int8_dots: int = 0):
+                 int8_dots: int = 0, primitive: str = "dot_general",
+                 group_count: int = 0):
         self.name = name
         self.lhs_shape = tuple(lhs_shape)
         self.rhs_shape = tuple(rhs_shape)
@@ -177,12 +238,15 @@ class Site:
         self.eligible = eligible
         self.tiles = dict(tiles) if tiles else None
         self.int8_dots = int8_dots
+        self.primitive = primitive
+        self.group_count = group_count
 
     @property
     def flops(self) -> int:
         """Per-step FLOPs of this site, summed over mesh shards.
 
-        ``2*batch*m*k*n`` per execution, times the static trip
+        ``2*batch*m*k*n`` per execution (a grouped site's rows at their
+        upper bound: every row meets one group), times the static trip
         multiplicity, times the enclosing SPMD axis sizes (every shard
         runs the per-shard GEMM once), times 4 for the complex
         four-real-GEMM decomposition.
@@ -259,10 +323,25 @@ def site_scope(site: "Site") -> str:
     return f"{kind}_{site.name.replace('/', '.')}"
 
 
-def _walk_sites(jaxpr, prefix: str = "", dot_counter=None,
-                flow_counter=None, out=None, mult: int = 1,
-                spmd=()) -> List[Tuple[Any, str, int, tuple]]:
-    """Enumerate ``dot_general`` equations with their structural names.
+#: Body-carrying primitives share one count in a scope: ``scan0``,
+#: ``cond1``, ``while2``.
+_FLOW = ("scan", "while", "cond", "shmap")
+
+
+def _next_name(prefix: str, kind: str, counters: dict) -> str:
+    """``{prefix}{kind}{i}``: sites of each kind, and the bodies of
+    :data:`_FLOW` together, numbered in program order in their scope."""
+    key = "flow" if kind in _FLOW else kind
+    i = counters.get(key, 0)
+    counters[key] = i + 1
+    return f"{prefix}{kind}{i}"
+
+
+def _walk_sites(jaxpr, prefix: str = "", counters=None, out=None,
+                mult: int = 1, spmd=(),
+                native=None) -> List[Tuple[Any, str, int, tuple]]:
+    """Enumerate the sites (``dot_general``, ``ragged_dot_general``
+    equations) with their structural names.
 
     This single walker is the naming authority: both :func:`site_report`
     and the offload transform consume its ``(eqn, name, mult, spmd)``
@@ -271,55 +350,85 @@ def _walk_sites(jaxpr, prefix: str = "", dot_counter=None,
     lengths; ``while`` bodies and ``cond`` branches count as one — the
     trip count is dynamic) and ``spmd`` the enclosing SPMD axes as
     ``(name, size)`` pairs, both consumed by the site records the
-    tuner calibrates against.
+    tuner calibrates against.  ``native``, where given, collects a
+    :class:`Native` record of each contraction that is no site.
     """
-    dot_counter = [0] if dot_counter is None else dot_counter
-    flow_counter = [0] if flow_counter is None else flow_counter
+    counters = {} if counters is None else counters
     out = [] if out is None else out
     for eqn in jaxpr.eqns:
         prim = eqn.primitive.name
-        if prim == "dot_general":
-            out.append((eqn, f"{prefix}dot{dot_counter[0]}", mult, spmd))
-            dot_counter[0] += 1
-        elif prim in _INLINE_PRIMITIVES:
+        kind = _PRIMITIVES.get(prim)
+        if kind in ("dot", "ragged"):
+            out.append((eqn, _next_name(prefix, kind, counters), mult,
+                        spmd))
+        elif kind == "inline":
             for sub, _ in _subjaxprs(eqn):
-                _walk_sites(sub, prefix, dot_counter, flow_counter, out,
-                            mult, spmd)
-        elif prim == "shard_map":
+                _walk_sites(sub, prefix, counters, out, mult, spmd, native)
+        elif kind == "contraction":
+            name = _next_name(prefix, "conv", counters)
+            if native is not None:
+                native.append(Native(prim, name,
+                                     f"{prim} has no Ozaki path"))
+        elif kind == "opaque":
+            pfx = _next_name(prefix, "custom", counters) + "/"
+            if native is not None:
+                inner, inner_native = [], []
+                for sub, _ in _subjaxprs(eqn):
+                    _walk_sites(sub, pfx, out=inner, native=inner_native)
+                native.extend(Native(e.primitive.name, name,
+                                     f"inside {prim}: its derivative rule "
+                                     "is kept") for e, name, _, _ in inner)
+                native.extend(inner_native)
+        elif kind == "shmap":
             # The body sees *per-shard* shapes: sites inside get their
             # offload decision (and size gate) against the local block,
             # so the per-device Ozaki schedule matches a single-device
             # run on one shard.
             _walk_sites(eqn.params["jaxpr"],
-                        f"{prefix}shmap{flow_counter[0]}/", out=out,
-                        mult=mult,
-                        spmd=spmd + _mesh_axes(eqn.params["mesh"]))
-            flow_counter[0] += 1
-        elif prim == "scan":
+                        _next_name(prefix, "shmap", counters) + "/",
+                        out=out, mult=mult,
+                        spmd=spmd + _mesh_axes(eqn.params["mesh"]),
+                        native=native)
+        elif kind == "scan":
             body = eqn.params["jaxpr"]
-            _walk_sites(body.jaxpr, f"{prefix}scan{flow_counter[0]}/",
-                        out=out, mult=mult * int(eqn.params["length"]),
-                        spmd=spmd)
-            flow_counter[0] += 1
-        elif prim == "while":
-            pfx = f"{prefix}while{flow_counter[0]}/"
+            _walk_sites(body.jaxpr, _next_name(prefix, "scan", counters)
+                        + "/", out=out,
+                        mult=mult * int(eqn.params["length"]), spmd=spmd,
+                        native=native)
+        elif kind == "while":
+            pfx = _next_name(prefix, "while", counters) + "/"
             _walk_sites(eqn.params["cond_jaxpr"].jaxpr, pfx + "cond/",
-                        out=out, mult=mult, spmd=spmd)
+                        out=out, mult=mult, spmd=spmd, native=native)
             _walk_sites(eqn.params["body_jaxpr"].jaxpr, pfx, out=out,
-                        mult=mult, spmd=spmd)
-            flow_counter[0] += 1
-        elif prim == "cond":
-            pfx = f"{prefix}cond{flow_counter[0]}/"
+                        mult=mult, spmd=spmd, native=native)
+        elif kind == "cond":
+            pfx = _next_name(prefix, "cond", counters) + "/"
             for bi, br in enumerate(eqn.params["branches"]):
                 _walk_sites(br.jaxpr, f"{pfx}br{bi}/", out=out,
-                            mult=mult, spmd=spmd)
-            flow_counter[0] += 1
+                            mult=mult, spmd=spmd, native=native)
     return out
 
 
+def _has_grouped_form(spec: str, policy: PrecisionPolicy) -> bool:
+    """Whether the backend ``spec`` names computes grouped products."""
+    try:
+        backend = get_backend(spec, policy=policy)
+    except (ValueError, RuntimeError):
+        return False
+    return getattr(backend, "ragged_dot", None) is not None
+
+
 def _classify(eqn, policy: PrecisionPolicy, name: str, mult: int = 1,
-              spmd=()) -> Site:
-    """Decide whether one dot_general equation gets offloaded."""
+              spmd=(), grouped=None) -> Site:
+    """Decide whether one site's equation gets offloaded.
+
+    ``grouped(spec)`` says whether the backend ``spec`` has a grouped
+    form (:func:`_has_grouped_form` by default).
+    """
+    if eqn.primitive.name == "ragged_dot_general":
+        return _classify_ragged(eqn, policy, name, mult, spmd,
+                                grouped or (lambda spec: _has_grouped_form(
+                                    spec, policy)))
     lhs_aval, rhs_aval = (v.aval for v in eqn.invars)
     dtype = eqn.outvars[0].aval.dtype
     # The same normalization that will execute (batch dims excluded,
@@ -354,6 +463,49 @@ def _classify(eqn, policy: PrecisionPolicy, name: str, mult: int = 1,
                 tiles=_tile_choice(backend, m, k, n, splits, dtype),
                 int8_dots=cplx * len(fold_runs(splits, k,
                                                policy.slice_bits)),
+                **geom)
+
+
+def _classify_ragged(eqn, policy, name, mult, spmd, grouped) -> Site:
+    """Decide whether one ``ragged_dot_general`` equation gets offloaded.
+
+    ``m``/``k``/``n`` are those of each group's product, with the rows
+    at their static upper bound: rows x k x n for ragged rows, and
+    k x rows x n for a ragged contraction (the rows are contracted).
+    """
+    lhs, rhs, sizes = (v.aval for v in eqn.invars)
+    dtype = eqn.outvars[0].aval.dtype
+    dims = eqn.params["ragged_dot_dimension_numbers"]
+    form = ragged_form(dims)
+    m = k = n = 0
+    if form == "rows" and lhs.ndim == 2 and rhs.ndim == 3:
+        m, k, n = lhs.shape[0], lhs.shape[1], rhs.shape[2]
+    elif form == "contraction" and lhs.ndim == 2 and rhs.ndim == 2:
+        m, k, n = lhs.shape[1], lhs.shape[0], rhs.shape[1]
+    else:
+        form = None
+    geom = dict(m=m, k=k, n=n, batch=1, mult=mult, spmd_axes=spmd,
+                primitive="ragged_dot_general", group_count=sizes.shape[0])
+
+    def skip(reason, eligible=False, backend=""):
+        return Site(name, lhs.shape, rhs.shape, dtype, False, 0, reason,
+                    eligible=eligible, backend=backend, **geom)
+
+    if form is None or eqn.params.get("group_offset") is not None:
+        return skip("no grouped Ozaki form for these dimension numbers")
+    if not jnp.issubdtype(dtype, jnp.floating):
+        return skip(f"dtype {jnp.dtype(dtype).name}")
+    if min(m, k, n) < policy.min_dim:
+        return skip(f"min(m,k,n)={min(m, k, n)} < min_dim={policy.min_dim}")
+    backend = policy.backend_for(name)
+    if backend == "dgemm":
+        return skip("demoted to dgemm", eligible=True, backend=backend)
+    if not grouped(backend):
+        return skip(f"{backend} has no grouped form", backend=backend)
+    splits = policy.splits_for(name)
+    return Site(name, lhs.shape, rhs.shape, dtype, True, splits, "",
+                eligible=True, backend=backend,
+                int8_dots=len(fold_runs(splits, k, policy.slice_bits)),
                 **geom)
 
 
@@ -477,6 +629,52 @@ def _site_dot(backend: GemmBackend, site: Site, dims: "_DotDims",
     return dot
 
 
+def _site_ragged(backend: GemmBackend, site: Site, dims, out_dtype):
+    """The backend-routed, AD-aware replacement for one grouped site.
+
+    Forward: ``backend.ragged_dot`` in the site's form.  Backward
+    (``custom_vjp``): both cotangents as grouped products through the
+    same backend — of ragged rows ``(m, k) x (g, k, n)``, dX is ragged
+    rows against the groups' transposes and dW a ragged contraction; of
+    a ragged contraction ``(m, k) x (m, n) -> (g, k, n)``, both are
+    ragged rows.
+    """
+
+    def rd(lhs, rhs, sizes, form_dims, odt):
+        return backend.ragged_dot(lhs, rhs, sizes, form_dims,
+                                  out_dtype=odt, num_splits=site.splits,
+                                  site=site.name)
+
+    def fwd_impl(lhs, rhs, sizes):
+        return rd(lhs, rhs, sizes, dims, out_dtype)
+
+    if not getattr(backend, "supports_vjp", True):
+        return fwd_impl
+
+    @jax.custom_vjp
+    def grouped(lhs, rhs, sizes):
+        return fwd_impl(lhs, rhs, sizes)
+
+    def grouped_fwd(lhs, rhs, sizes):
+        return fwd_impl(lhs, rhs, sizes), (lhs, rhs, sizes)
+
+    def grouped_bwd(res, g):
+        lhs, rhs, sizes = res
+        with jax.named_scope(site_scope(site)):
+            if ragged_form(dims) == "rows":
+                dl = rd(g, jnp.swapaxes(rhs, 1, 2), sizes, RAGGED_ROWS,
+                        lhs.dtype)
+                dr = rd(lhs, g, sizes, RAGGED_CONTRACTION, rhs.dtype)
+            else:
+                dl = rd(rhs, jnp.swapaxes(g, 1, 2), sizes, RAGGED_ROWS,
+                        lhs.dtype)
+                dr = rd(lhs, g, sizes, RAGGED_ROWS, rhs.dtype)
+        return dl, dr, None
+
+    grouped.defvjp(grouped_fwd, grouped_bwd)
+    return grouped
+
+
 def transform_jaxpr(closed, policy: PrecisionPolicy,
                     backend: GemmBackend | None = None,
                     on_site_event=None):
@@ -501,40 +699,35 @@ def transform_jaxpr(closed, policy: PrecisionPolicy,
     ``scan`` iteration, per local mesh shard of a ``shard_map``.  Where
     that count is static (a site at top level, under ``scan`` or under
     ``shard_map``: ``Site.mult`` times the local shards), one
-    zero-operand ``jax.debug.callback`` at the top level of the
+    ``jax.debug.callback`` at the top level of the
     transformed program makes all of a call's reports at once, so the
     device waits on the host once a call, not once a site execution.
+    A grouped site's payload also carries ``rows``: the rows that
+    execution routed (``sum(group_sizes)``), which only the running
+    program knows; the rows of the grouped sites under ``scan`` leave
+    the loop as extra outputs, and they are the one callback's only
+    operands (with no grouped site it takes none).
     A site under ``while`` or ``cond``, whose trip count only execution
     knows, keeps its own callback beside its backend call, outside its
     scope and never inside the ``custom_vjp`` (debug effects cannot
     stage through custom-derivative rules): it fires once per
-    iteration or taken branch.  Callbacks carry **zero** array
-    operands: the payload is host-built at transform time, the hook
+    iteration or taken branch; so does a grouped site under
+    ``shard_map``, whose rows differ by shard.  Those callbacks carry
+    no array operand but a grouped site's rows: the payload is
+    host-built at transform time, the hook
     adds no device compute, and — load-bearing, not just an
     optimization — an operand-carrying callback inside a loop body is
     *dropped entirely* by JAX's partial-eval when the loop is
     differentiated, whereas the zero-operand form is merely hoisted.
     Consequence: under reverse-mode AD *outside* the transformed
     function, a ``while``/``cond`` site reports once per call, not once
-    per iteration; every other site still reports ``Site.mult`` times
+    per iteration (and a grouped one there not at all); every other
+    site still reports ``Site.mult`` times
     (forward-only programs count exactly).  Handlers run on the
     runtime's callback threads and must follow the np-asarray-first
     rule: never launch jax ops from the handler.
     """
     backend = backend or get_backend(policy.backend, policy=policy)
-    sites: List[Site] = []
-    decisions: Dict[str, Site] = {}
-    for eqn, name, mult, spmd in _walk_sites(closed.jaxpr):
-        site = _classify(eqn, policy, name, mult, spmd)
-        sites.append(site)
-        decisions[name] = site
-    _check_overrides(policy, decisions)
-    # An instrumentation backend (calibration) sees the full site
-    # decisions — shapes, extents, trip multiplicity, SPMD axes —
-    # before the first matmul call, which only carries the site name.
-    observe = getattr(backend, "observe_sites", None)
-    if observe is not None:
-        observe(decisions)
 
     # Per-site backend routing: a site whose resolved spec differs
     # from the policy default (plan promotions, e.g. a single site on
@@ -546,16 +739,40 @@ def transform_jaxpr(closed, policy: PrecisionPolicy,
     engines: Dict[str, GemmBackend] = {policy.backend: backend}
     authoritative = getattr(backend, "intercepts_all_sites", False)
 
-    def engine_for(site: Site) -> GemmBackend:
+    def engine(spec: str) -> GemmBackend:
         if authoritative:
             return backend
-        spec = site.backend or policy.backend
         if spec not in engines:
             engines[spec] = get_backend(spec, policy=policy)
         return engines[spec]
 
+    def grouped(spec: str) -> bool:
+        try:
+            return getattr(engine(spec), "ragged_dot", None) is not None
+        except (ValueError, RuntimeError):
+            return False
+
+    native: List[Native] = []
+    sites: List[Site] = []
+    decisions: Dict[str, Site] = {}
+    for eqn, name, mult, spmd in _walk_sites(closed.jaxpr, native=native):
+        site = _classify(eqn, policy, name, mult, spmd, grouped)
+        sites.append(site)
+        decisions[name] = site
+    sites = SiteList(sites, native)
+    _check_overrides(policy, decisions)
+    # An instrumentation backend (calibration) sees the full site
+    # decisions — shapes, extents, trip multiplicity, SPMD axes —
+    # before the first matmul call, which only carries the site name.
+    observe = getattr(backend, "observe_sites", None)
+    if observe is not None:
+        observe(decisions)
+
+    def engine_for(site: Site) -> GemmBackend:
+        return engine(site.backend or policy.backend)
+
     def site_payload(site: Site) -> dict:
-        return {
+        payload = {
             "site": site.name,
             "backend": site.backend or policy.backend,
             "splits": int(site.splits),
@@ -569,20 +786,30 @@ def transform_jaxpr(closed, policy: PrecisionPolicy,
             "int8_dots": site.int8_dots,
             "tiles": dict(site.tiles) if site.tiles else None,
         }
+        if site.group_count:
+            payload["group_count"] = site.group_count
+        return payload
 
-    def stage_site_events(counted) -> None:
+    def stage_site_events(counted, rows=()) -> None:
         # Static (payload, executions) pairs, built host-side once per
-        # staging; the callback takes zero array operands so it costs
-        # nothing on device and cannot trip the np-asarray-first rule
-        # itself.
+        # staging.  ``rows`` are (site, rows of each execution) of the
+        # grouped sites, the callback's only operands; without them it
+        # takes none and costs nothing on device.
         counted = tuple(counted)
+        names = [name for name, _ in rows]
 
-        def report():
+        def report(*values):
+            routed = {name: np.asarray(v).reshape(-1)
+                      for name, v in zip(names, values)}
             for payload, execs in counted:
-                for _ in range(execs):
-                    on_site_event(dict(payload))
+                got = routed.get(payload["site"])
+                for i in range(execs):
+                    event = dict(payload)
+                    if got is not None:
+                        event["rows"] = int(got[i])
+                    on_site_event(event)
 
-        jax.debug.callback(report)
+        jax.debug.callback(report, *(v for _, v in rows))
 
     # Executions per call of each offloaded site with a static count,
     # in program order (reported by the one per-call callback), and the
@@ -599,17 +826,51 @@ def transform_jaxpr(closed, policy: PrecisionPolicy,
     def read_env(env, v):
         return v.val if isinstance(v, jex_core.Literal) else env[v]
 
+    def run_site(site, eqn, invals, prefix, rows):
+        """One site's replacement (or native re-bind) under its scope;
+        its execution counted for the site-event hook."""
+        ragged = site.primitive == "ragged_dot_general"
+        # An authoritative instrumentation backend must see
+        # every *eligible* site — including ones a plan
+        # demoted to native — or re-calibration under a
+        # from_plan policy would re-promote pathological
+        # sites unmeasured.
+        if on_site_event is not None and site.offloaded:
+            routed = ([(site.name, jnp.sum(invals[2]))] if ragged
+                      else [])
+            if _dynamic_trip(prefix) or (ragged and "shmap" in prefix):
+                stage_site_events([(site_payload(site), 1)], routed)
+            else:
+                static_execs[site.name] = site.mult * shards_of(prefix)
+                if rows is not None:
+                    rows.extend(routed)
+        with jax.named_scope(site_scope(site)):
+            if ragged and site.offloaded:
+                dims = eqn.params["ragged_dot_dimension_numbers"]
+                fn = _site_ragged(engine_for(site), site, dims,
+                                  eqn.outvars[0].aval.dtype)
+                return [fn(*invals)]
+            if site.offloaded or (authoritative and site.eligible
+                                  and not ragged):
+                dims = _DotDims(eqn.params["dimension_numbers"],
+                                site.lhs_shape, site.rhs_shape)
+                fn = _site_dot(engine_for(site), site, dims,
+                               eqn.outvars[0].aval.dtype)
+                return [fn(invals[0], invals[1])]
+            return [eqn.primitive.bind(*invals, **eqn.params)]
+
     # Decisions are keyed by the structural *name*, and the evaluator
     # re-derives names with the exact counter discipline of
     # _walk_sites.  Keying by equation identity would be wrong: JAX's
     # tracing cache reuses one body jaxpr object (hence the same eqn
     # objects) for every call of a jit-ed inner function, so distinct
-    # sites can share an eqn.
+    # sites can share an eqn.  Each equation is re-bound under the
+    # scopes of its own name stack (:func:`_scopes_only`), so that the
+    # program's ``jax.named_scope``s survive the rewrite.  ``rows``
+    # collects (site, routed rows) of the scope's grouped sites.
     def eval_rewritten(jaxpr, consts: Sequence[Any], args: Sequence[Any],
-                       prefix: str = "", dot_counter=None,
-                       flow_counter=None):
-        dot_counter = [0] if dot_counter is None else dot_counter
-        flow_counter = [0] if flow_counter is None else flow_counter
+                       prefix: str = "", counters=None, rows=None):
+        counters = {} if counters is None else counters
         env = {}
         for var, const in zip(jaxpr.constvars, consts):
             env[var] = const
@@ -619,109 +880,77 @@ def transform_jaxpr(closed, policy: PrecisionPolicy,
         for eqn in jaxpr.eqns:
             invals = [read_env(env, v) for v in eqn.invars]
             prim = eqn.primitive.name
-            if prim == "dot_general":
-                site = decisions[f"{prefix}dot{dot_counter[0]}"]
-                dot_counter[0] += 1
-                # An authoritative instrumentation backend must see
-                # every *eligible* site — including ones a plan
-                # demoted to native — or re-calibration under a
-                # from_plan policy would re-promote pathological
-                # sites unmeasured.
-                if on_site_event is not None and site.offloaded:
-                    if _dynamic_trip(prefix):
-                        stage_site_events([(site_payload(site), 1)])
-                    else:
-                        static_execs[site.name] = (site.mult
-                                                   * shards_of(prefix))
-                with jax.named_scope(site_scope(site)):
-                    if site.offloaded or (authoritative and site.eligible):
-                        dims = _DotDims(eqn.params["dimension_numbers"],
-                                        site.lhs_shape, site.rhs_shape)
-                        fn = _site_dot(engine_for(site), site, dims,
-                                       eqn.outvars[0].aval.dtype)
-                        outvals = [fn(invals[0], invals[1])]
-                    else:
-                        outvals = [eqn.primitive.bind(*invals,
-                                                      **eqn.params)]
-            elif prim in _INLINE_PRIMITIVES:
-                # Inlining a jit discards its partitioning params, so
-                # NamedSharding annotations on the inner jit are
-                # re-applied as sharding constraints around the inlined
-                # body — offload(jax.jit(fn, in_shardings=...)) keeps
-                # partitioning exactly as the user declared it.
-                if prim == "jit":
-                    invals = _apply_shardings(
-                        invals, eqn.params.get("in_shardings"))
-                outvals = None
-                for sub, sub_consts in _subjaxprs(eqn):
-                    outvals = eval_rewritten(sub, sub_consts, invals,
-                                             prefix, dot_counter,
-                                             flow_counter)
-                if outvals is None:  # no body found — bind natively
-                    outvals = eqn.primitive.bind(*invals, **eqn.params)
+            kind = _PRIMITIVES.get(prim)
+            name_stack = (source_info_util.current_name_stack()
+                          + _scopes_only(eqn.source_info.name_stack))
+            with source_info_util.user_context(eqn.source_info.traceback,
+                                               name_stack=name_stack):
+                if kind in ("dot", "ragged"):
+                    site = decisions[_next_name(prefix, kind, counters)]
+                    outvals = run_site(site, eqn, invals, prefix, rows)
+                elif kind == "inline":
+                    outvals = _eval_inline(eqn, invals, eval_rewritten,
+                                           prefix, counters, rows)
+                elif kind == "shmap":
+                    pfx = _next_name(prefix, "shmap", counters) + "/"
+                    local_shards[pfx] = _local_shards(eqn.params["mesh"])
+                    outvals = _eval_shard_map(eqn, invals, eval_rewritten,
+                                              pfx)
+                elif kind == "pvary":
+                    # shard_map's varying-axis tracking (check_vma)
+                    # stages pvary markers into the body; they are
+                    # physically the identity, and replaying them under
+                    # the check_vma=False rebuild corrupts the transpose
+                    # rule — drop them.
+                    outvals = list(invals)
+                elif kind == "psum_invariant":
+                    # Same story for psum_invariant (the tracked psum):
+                    # replay it as the plain collective so values AND
+                    # cotangents come out right under the
+                    # check_vma=False rebuild.  One bind over *all*
+                    # operands: a bucketed gradient all-reduce stages one
+                    # multi-operand psum per bucket, and replaying it per
+                    # operand would silently de-fuse the buckets the
+                    # overlap path exists to create.
+                    outvals = list(jax.lax.psum(
+                        tuple(invals), tuple(eqn.params["axes"]),
+                        axis_index_groups=eqn.params.get(
+                            "axis_index_groups")))
+                elif kind == "scan":
+                    pfx = _next_name(prefix, "scan", counters) + "/"
+                    outvals = _eval_scan(eqn, invals, eval_rewritten, pfx,
+                                         rows)
+                elif kind == "while":
+                    pfx = _next_name(prefix, "while", counters) + "/"
+                    outvals = _eval_while(eqn, invals, eval_rewritten, pfx)
+                elif kind == "cond":
+                    pfx = _next_name(prefix, "cond", counters) + "/"
+                    outvals = _eval_cond(eqn, invals, eval_rewritten, pfx)
+                else:
+                    # Canonical re-bind (same as jax.core.eval_jaxpr):
+                    # get_bind_params re-wraps staged params — e.g. the
+                    # jvp/fwd/bwd rules of opaque custom-derivative
+                    # calls — into bindable form; plain primitives pass
+                    # through.
+                    subfuns, bind_params = eqn.primitive.get_bind_params(
+                        eqn.params)
+                    outvals = eqn.primitive.bind(*subfuns, *invals,
+                                                 **bind_params)
                     if not eqn.primitive.multiple_results:
                         outvals = [outvals]
-                elif prim == "jit":
-                    outvals = _apply_shardings(
-                        outvals, eqn.params.get("out_shardings"))
-            elif prim == "shard_map":
-                pfx = f"{prefix}shmap{flow_counter[0]}/"
-                flow_counter[0] += 1
-                local_shards[pfx] = _local_shards(eqn.params["mesh"])
-                outvals = _eval_shard_map(eqn, invals, eval_rewritten,
-                                          pfx)
-            elif prim == "pvary":
-                # shard_map's varying-axis tracking (check_vma) stages
-                # pvary markers into the body; they are physically the
-                # identity, and replaying them under the
-                # check_vma=False rebuild corrupts the transpose rule —
-                # drop them.
-                outvals = list(invals)
-            elif prim == "psum_invariant":
-                # Same story for psum_invariant (the tracked psum):
-                # replay it as the plain collective so values AND
-                # cotangents come out right under the check_vma=False
-                # rebuild.  One bind over *all* operands: a bucketed gradient
-                # all-reduce stages one multi-operand psum per bucket,
-                # and replaying it per operand would silently de-fuse
-                # the buckets the overlap path exists to create.
-                outvals = list(jax.lax.psum(
-                    tuple(invals), tuple(eqn.params["axes"]),
-                    axis_index_groups=eqn.params.get(
-                        "axis_index_groups")))
-            elif prim == "scan":
-                pfx = f"{prefix}scan{flow_counter[0]}/"
-                flow_counter[0] += 1
-                outvals = _eval_scan(eqn, invals, eval_rewritten, pfx)
-            elif prim == "while":
-                pfx = f"{prefix}while{flow_counter[0]}/"
-                flow_counter[0] += 1
-                outvals = _eval_while(eqn, invals, eval_rewritten, pfx)
-            elif prim == "cond":
-                pfx = f"{prefix}cond{flow_counter[0]}/"
-                flow_counter[0] += 1
-                outvals = _eval_cond(eqn, invals, eval_rewritten, pfx)
-            else:
-                # Canonical re-bind (same as jax.core.eval_jaxpr):
-                # get_bind_params re-wraps staged params — e.g. the
-                # jvp/fwd/bwd rules of opaque custom-derivative calls —
-                # into bindable form; plain primitives pass through.
-                subfuns, bind_params = eqn.primitive.get_bind_params(
-                    eqn.params)
-                outvals = eqn.primitive.bind(*subfuns, *invals,
-                                             **bind_params)
-                if not eqn.primitive.multiple_results:
-                    outvals = [outvals]
             for var, val in zip(eqn.outvars, outvals):
                 env[var] = val
 
         return [read_env(env, v) for v in jaxpr.outvars]
 
     def interp(*flat_args):
-        outs = eval_rewritten(closed.jaxpr, closed.consts, flat_args)
+        rows: List[Tuple[str, Any]] = []
+        outs = eval_rewritten(closed.jaxpr, closed.consts, flat_args,
+                              rows=rows)
         if static_execs:
-            stage_site_events((site_payload(decisions[name]), execs)
-                              for name, execs in static_execs.items())
+            stage_site_events(((site_payload(decisions[name]), execs)
+                               for name, execs in static_execs.items()),
+                              rows)
         return outs
 
     in_specs = [jax.ShapeDtypeStruct(v.aval.shape, v.aval.dtype)
@@ -730,25 +959,72 @@ def transform_jaxpr(closed, policy: PrecisionPolicy,
     return transformed, sites
 
 
-def _eval_scan(eqn, invals, eval_body, prefix):
-    """Rebuild a ``scan`` with its body routed through the rewriter."""
+def _scopes_only(name_stack):
+    """``name_stack`` without its transforms.
+
+    A transform (``jvp``, ``transpose``) prints wrapped around the next
+    scope: a site scope pushed under it would read
+    ``transpose(jvp(ozaki_dot1))`` instead of one ``ozaki_dot1``
+    component of the op's name.
+    """
+    return source_info_util.NameStack(tuple(
+        e for e in name_stack.stack
+        if isinstance(e, source_info_util.Scope)))
+
+
+def _eval_inline(eqn, invals, eval_body, prefix, counters, rows):
+    """Inline a call-like equation's body into the enclosing scope.
+
+    Inlining a jit discards its partitioning params, so NamedSharding
+    annotations on the inner jit are re-applied as sharding constraints
+    around the inlined body — offload(jax.jit(fn, in_shardings=...))
+    keeps partitioning exactly as the user declared it.
+    """
+    jit = eqn.primitive.name == "jit"
+    if jit:
+        invals = _apply_shardings(invals, eqn.params.get("in_shardings"))
+    outvals = None
+    for sub, sub_consts in _subjaxprs(eqn):
+        outvals = eval_body(sub, sub_consts, invals, prefix, counters, rows)
+    if outvals is None:  # no body found — bind natively
+        outvals = eqn.primitive.bind(*invals, **eqn.params)
+        if not eqn.primitive.multiple_results:
+            outvals = [outvals]
+    elif jit:
+        outvals = _apply_shardings(outvals, eqn.params.get("out_shardings"))
+    return outvals
+
+
+def _eval_scan(eqn, invals, eval_body, prefix, rows=None):
+    """Rebuild a ``scan`` with its body routed through the rewriter.
+
+    The rows of the body's grouped sites (``rows``, see
+    :func:`transform_jaxpr`) leave the loop as extra stacked outputs,
+    one per iteration.
+    """
     p = eqn.params
     nc, ncar = p["num_consts"], p["num_carry"]
     body = p["jaxpr"]
     consts = invals[:nc]
     init = tuple(invals[nc:nc + ncar])
     xs = tuple(invals[nc + ncar:])
+    names: List[str] = []
 
     def body_fun(carry, x):
         # Fresh counters per trace of the body: scan may re-trace it
         # (carry fixed-point), and names must restart each time.
+        routed = None if rows is None else []
         outs = eval_body(body.jaxpr, body.consts, [*consts, *carry, *x],
-                         prefix)
-        return tuple(outs[:ncar]), tuple(outs[ncar:])
+                         prefix, rows=routed)
+        names[:] = [name for name, _ in routed or ()]
+        return tuple(outs[:ncar]), (tuple(outs[ncar:]),
+                                    tuple(v for _, v in routed or ()))
 
-    carry_out, ys = jax.lax.scan(body_fun, init, xs, length=p["length"],
-                                 reverse=p["reverse"],
-                                 unroll=p.get("unroll", 1))
+    carry_out, (ys, routed) = jax.lax.scan(
+        body_fun, init, xs, length=p["length"], reverse=p["reverse"],
+        unroll=p.get("unroll", 1))
+    if rows is not None:
+        rows.extend(zip(names, routed))
     return [*carry_out, *ys]
 
 
@@ -854,30 +1130,36 @@ PersistInfo = namedtuple("PersistInfo", ["disk_hits",
 
 #: Bumped whenever the persisted payload layout changes; part of the
 #: cache key, so stale-format files are simply never looked up.
-_PERSIST_FORMAT = 2
+_PERSIST_FORMAT = 3
 
 
-def _site_payload(sites: Sequence[Site]) -> list:
-    """Site records as plain JSON data (the persisted decision set)."""
-    return [{"name": s.name, "lhs_shape": list(s.lhs_shape),
+def _site_payload(sites: Sequence[Site]) -> dict:
+    """Site records and the native contractions (a :class:`SiteList`)
+    as plain JSON data (the persisted decision set)."""
+    return {"sites": [{"name": s.name, "lhs_shape": list(s.lhs_shape),
              "rhs_shape": list(s.rhs_shape), "dtype": s.dtype.name,
              "offloaded": bool(s.offloaded), "splits": int(s.splits),
              "reason": s.reason, "m": int(s.m), "k": int(s.k),
              "n": int(s.n), "batch": int(s.batch), "mult": int(s.mult),
              "spmd_axes": [[a, int(x)] for a, x in s.spmd_axes],
              "backend": s.backend, "eligible": bool(s.eligible),
-             "tiles": s.tiles, "int8_dots": int(s.int8_dots)}
-            for s in sites]
+             "tiles": s.tiles, "int8_dots": int(s.int8_dots),
+             "primitive": s.primitive, "group_count": int(s.group_count)}
+            for s in sites],
+            "native": [list(n) for n in getattr(sites, "skipped", ())]}
 
 
-def _sites_from_payload(payload: list) -> List[Site]:
-    return [Site(p["name"], p["lhs_shape"], p["rhs_shape"], p["dtype"],
-                 p["offloaded"], p["splits"], p["reason"], m=p["m"],
-                 k=p["k"], n=p["n"], batch=p["batch"], mult=p["mult"],
-                 spmd_axes=[tuple(a) for a in p["spmd_axes"]],
-                 backend=p["backend"], eligible=p["eligible"],
-                 tiles=p["tiles"], int8_dots=p["int8_dots"])
-            for p in payload]
+def _sites_from_payload(payload: dict) -> SiteList:
+    return SiteList(
+        [Site(p["name"], p["lhs_shape"], p["rhs_shape"], p["dtype"],
+              p["offloaded"], p["splits"], p["reason"], m=p["m"],
+              k=p["k"], n=p["n"], batch=p["batch"], mult=p["mult"],
+              spmd_axes=[tuple(a) for a in p["spmd_axes"]],
+              backend=p["backend"], eligible=p["eligible"],
+              tiles=p["tiles"], int8_dots=p["int8_dots"],
+              primitive=p["primitive"], group_count=p["group_count"])
+         for p in payload["sites"]],
+        [Native(*n) for n in payload["native"]])
 
 
 def _sites_bytes(sites: Sequence[Site]) -> bytes:
@@ -1203,7 +1485,7 @@ def offload(fn, policy: PrecisionPolicy | None = None, *,
                                        entry.transformed.consts, *flat)
         return jax.tree_util.tree_unflatten(entry.out_tree, out_flat)
 
-    def sites(*args, **kwargs) -> List[Site]:
+    def sites(*args, **kwargs) -> SiteList:
         _, entry = build(args, kwargs)
         return entry.sites
 
@@ -1235,16 +1517,20 @@ def site_report(fn, policy: PrecisionPolicy | None = None):
     """Enumerate the BLAS-3 sites ``offload`` would rewrite in ``fn``.
 
     Returns a function with the same signature as ``fn`` that returns a
-    list of :class:`Site` records instead of computing.  The names are
+    :class:`SiteList` of :class:`Site` records instead of computing
+    (its ``native`` lists the contractions left native that are no
+    gated ``dot_general``).  The names are
     the same structural names :func:`offload` uses (one shared walker),
     so they are valid ``PrecisionPolicy.site_splits`` keys.
     """
     policy = policy or PrecisionPolicy()
 
-    def reporter(*args, **kwargs) -> List[Site]:
+    def reporter(*args, **kwargs) -> SiteList:
         closed = jax.make_jaxpr(fn)(*args, **kwargs)
-        return [_classify(eqn, policy, name, mult, spmd)
-                for eqn, name, mult, spmd in _walk_sites(closed.jaxpr)]
+        native: List[Native] = []
+        walked = _walk_sites(closed.jaxpr, native=native)
+        return SiteList([_classify(eqn, policy, name, mult, spmd)
+                         for eqn, name, mult, spmd in walked], native)
 
     reporter.__name__ = f"site_report({getattr(fn, '__name__', 'fn')})"
     return reporter

@@ -52,6 +52,8 @@ __all__ = [
     "complex_matmul_via_real",
     "fold_runs",
     "num_pair_gemms",
+    "ozaki_ragged_dot",
+    "ragged_form",
     "real_pair_matmul",
     "pair_indices",
     "slice_matrix",
@@ -184,14 +186,24 @@ def slice_matrix(x: jax.Array, num_splits: int, axis: int,
     return jnp.stack(out), jnp.squeeze(sigma, axis=axis)
 
 
-def _run_products(a_k, b_k, k, num_splits, runs):
-    """One INT8 GEMM per fold run -> ``[(shift, int32 (m, n))]``.
+def _int8_dot(a, b):
+    return jax.lax.dot_general(a, b,
+                               dimension_numbers=(((1,), (0,)), ((), ())),
+                               preferred_element_type=jnp.int32)
+
+
+def _run_products(a_k, b_k, k, num_splits, runs, dot=_int8_dot,
+                  a_axis=1, b_axis=0):
+    """One INT8 GEMM per fold run -> ``[(shift, int32 product)]``.
 
     ``a_k`` is ``[A_0 | ... | A_(s-1)]`` (m, s*k) and ``b_k`` is
     ``[B_(s-1); ...; B_0]`` (s*k, n).  The run of pairs ``(i, t-i)``,
     ``i0 <= i < i1``, reads A's columns ``[i0*k, i1*k)`` and the rows of
     ``b_k`` holding ``B_(t-i0)`` down to ``B_(t-i1+1)``, which lie
     side by side in that order: both operands are contiguous slabs.
+    ``dot(a_slab, b_slab)`` multiplies one run's slabs, cut from axes
+    ``a_axis`` and ``b_axis`` (the grouped products lay the slices out
+    on other axes: :func:`ozaki_ragged_dot`).
 
     Each product is written out whole (an optimization barrier): left
     free to fuse the folds into the dots' output fusions, XLA:TPU took
@@ -204,11 +216,9 @@ def _run_products(a_k, b_k, k, num_splits, runs):
         lo, hi = int(ii[start]) * k, (int(ii[stop - 1]) + 1) * k
         off = (num_splits - 1 - shift) * k
         shifts.append(shift)
-        prods.append(jax.lax.dot_general(
-            jax.lax.slice_in_dim(a_k, lo, hi, axis=1),
-            jax.lax.slice_in_dim(b_k, off + lo, off + hi, axis=0),
-            dimension_numbers=(((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.int32))
+        prods.append(dot(
+            jax.lax.slice_in_dim(a_k, lo, hi, axis=a_axis),
+            jax.lax.slice_in_dim(b_k, off + lo, off + hi, axis=b_axis)))
     return list(zip(shifts, jax.lax.optimization_barrier(prods)))
 
 
@@ -272,6 +282,20 @@ def _accumulate_df32(prods, slice_bits, num_splits):
     return acc, comp, deferred
 
 
+def _check_accumulator(accumulator):
+    if accumulator not in ("df32", "f64"):
+        raise ValueError(f"unknown accumulator {accumulator!r};"
+                         " expected 'df32' or 'f64'")
+
+
+def _fold(prods, num_splits, accumulator, out_dtype, slice_bits):
+    """The runs' INT32 products folded into ``out_dtype``, unscaled."""
+    if accumulator == "f64":
+        return _accumulate_f64(prods, slice_bits).astype(out_dtype)
+    acc, comp, deferred = _accumulate_df32(prods, slice_bits, num_splits)
+    return (acc.astype(out_dtype) + comp.astype(out_dtype)) * deferred
+
+
 @functools.partial(jax.jit, static_argnames=("num_splits", "accumulator",
                                              "out_dtype", "slice_bits"))
 def _real_ozaki(a, b, num_splits, accumulator, out_dtype, slice_bits):
@@ -279,9 +303,7 @@ def _real_ozaki(a, b, num_splits, accumulator, out_dtype, slice_bits):
     k2, n = b.shape
     if k != k2:
         raise ValueError(f"shape mismatch {a.shape} @ {b.shape}")
-    if accumulator not in ("df32", "f64"):
-        raise ValueError(f"unknown accumulator {accumulator!r};"
-                         " expected 'df32' or 'f64'")
+    _check_accumulator(accumulator)
     with jax.named_scope("phase_slice"):
         a_sl, sigma_a = _slices(a, num_splits, 1, slice_bits)
         b_sl, sigma_b = _slices(b, num_splits, 0, slice_bits)
@@ -295,14 +317,132 @@ def _real_ozaki(a, b, num_splits, accumulator, out_dtype, slice_bits):
         prods = _run_products(a_k, b_k, k, num_splits,
                               fold_runs(num_splits, k, slice_bits))
     with jax.named_scope("phase_fold"):
-        if accumulator == "f64":
-            c = _accumulate_f64(prods, slice_bits).astype(out_dtype)
-        else:
-            acc, comp, deferred = _accumulate_df32(prods, slice_bits,
-                                                   num_splits)
-            c = (acc.astype(out_dtype) + comp.astype(out_dtype)) * deferred
+        c = _fold(prods, num_splits, accumulator, out_dtype, slice_bits)
         scale = (sigma_a * sigma_b).astype(out_dtype)
         return c * scale
+
+
+#: ``ragged_dot_general`` dimension numbers of the two grouped forms
+#: :func:`ozaki_ragged_dot` computes, both as ``jax.grad`` of
+#: ``jax.lax.ragged_dot`` emits them: rows ragged against a stack of
+#: group matrices, ``(m, k) x (g, k, n) -> (m, n)`` (the forward and
+#: dX), and a ragged contraction, ``(m, k) x (m, n) -> (g, k, n)`` (dW).
+RAGGED_ROWS = jax.lax.RaggedDotDimensionNumbers(
+    dot_dimension_numbers=(((1,), (1,)), ((), ())),
+    lhs_ragged_dimensions=(0,), rhs_group_dimensions=(0,))
+RAGGED_CONTRACTION = jax.lax.RaggedDotDimensionNumbers(
+    dot_dimension_numbers=(((0,), (0,)), ((), ())),
+    lhs_ragged_dimensions=(0,), rhs_group_dimensions=())
+
+
+def ragged_form(dims) -> str | None:
+    """``"rows"`` or ``"contraction"`` for the two forms above, else None."""
+    for name, form in (("rows", RAGGED_ROWS),
+                       ("contraction", RAGGED_CONTRACTION)):
+        if (dims.dot_dimension_numbers == form.dot_dimension_numbers
+                and tuple(dims.lhs_ragged_dimensions)
+                == form.lhs_ragged_dimensions
+                and tuple(dims.rhs_group_dimensions)
+                == form.rhs_group_dimensions):
+            return name
+    return None
+
+
+#: The int8 grouped products' precision, stated so that an enclosing
+#: ``jax.default_matmul_precision("highest")`` does not reach them:
+#: Mosaic refuses XLA:TPU's grouped-product kernel on int8 operands at
+#: float32 contract precision.
+_INT8 = jax.lax.Precision.DEFAULT
+
+
+@functools.partial(jax.jit, static_argnames=("form", "num_splits",
+                                             "accumulator", "out_dtype",
+                                             "slice_bits"))
+def _ragged_ozaki(a, b, group_sizes, form, num_splits, accumulator,
+                  out_dtype, slice_bits):
+    _check_accumulator(accumulator)
+    s = num_splits
+    if form == "rows":
+        # a (m, k) per row; b (g, k, n) per group and column.  Runs
+        # concatenate slices along k, as the dense product does.
+        k = a.shape[1]
+        with jax.named_scope("phase_slice"):
+            a_sl, sigma_a = _slices(a, s, 1, slice_bits)
+            b_sl, sigma_b = _slices(b, s, 1, slice_bits)
+            a_k = jnp.concatenate(a_sl, axis=1)        # (m, s*k)
+            b_k = jnp.concatenate(b_sl[::-1], axis=1)  # (g, s*k, n)
+            a_k, b_k = jax.lax.optimization_barrier((a_k, b_k))
+
+        def dot(x, y):
+            return jax.lax.ragged_dot_general(
+                x, y, group_sizes, RAGGED_ROWS, precision=_INT8,
+                preferred_element_type=jnp.int32)
+
+        with jax.named_scope("phase_pairs"):
+            prods = _run_products(a_k, b_k, k, s,
+                                  fold_runs(s, k, slice_bits), dot,
+                                  a_axis=1, b_axis=1)
+        with jax.named_scope("phase_fold"):
+            # Row i takes its group's column scales.  XLA:TPU leaves the
+            # rows past the groups unwritten: they are zeroed here.
+            g, m = b.shape[0], a.shape[0]
+            c = _fold(prods, s, accumulator, out_dtype, slice_bits)
+            group = jnp.repeat(jnp.arange(g), group_sizes,
+                               total_repeat_length=m)
+            scale = (sigma_a * sigma_b[:, 0, :][group]).astype(out_dtype)
+            grouped = jnp.arange(m) < jnp.sum(group_sizes)
+            return jnp.where(grouped[:, None], c * scale, 0)
+    # Ragged contraction: a (m, k) and b (m, n), each scaled along its
+    # free axis over all m rows (across groups: still powers of two).
+    # Slices interleave row by row, (m, s, ...), so that a run's slabs
+    # flatten to (m*r, ...) with each group's rows still contiguous.
+    m = a.shape[0]
+    with jax.named_scope("phase_slice"):
+        a_sl, sigma_a = _slices(a, s, 0, slice_bits)
+        b_sl, sigma_b = _slices(b, s, 0, slice_bits)
+        a_k = jnp.stack(a_sl, axis=1)        # (m, s, k)
+        b_k = jnp.stack(b_sl[::-1], axis=1)  # (m, s, n)
+        a_k, b_k = jax.lax.optimization_barrier((a_k, b_k))
+
+    def dot(x, y):
+        r = x.shape[1]
+        return jax.lax.ragged_dot_general(
+            x.reshape(m * r, -1), y.reshape(m * r, -1),
+            group_sizes * r, RAGGED_CONTRACTION, precision=_INT8,
+            preferred_element_type=jnp.int32)
+
+    with jax.named_scope("phase_pairs"):
+        prods = _run_products(a_k, b_k, 1, s, fold_runs(s, m, slice_bits),
+                              dot, a_axis=1, b_axis=1)
+    with jax.named_scope("phase_fold"):
+        c = _fold(prods, s, accumulator, out_dtype, slice_bits)
+        scale = sigma_a[0][None, :, None] * sigma_b[0][None, None, :]
+        return c * scale.astype(out_dtype)
+
+
+def ozaki_ragged_dot(lhs, rhs, group_sizes, dims, num_splits: int = 6,
+                     accumulator: str = "df32", out_dtype=None,
+                     slice_bits: int = SLICE_BITS):
+    """Emulated high-precision grouped product via INT8 split GEMMs.
+
+    ``jax.lax.ragged_dot_general(lhs, rhs, group_sizes, dims)`` for the
+    two forms of :func:`ragged_form`: each operand is sliced once
+    (:func:`slice_matrix`'s recurrence), one int8
+    ``ragged_dot_general`` into int32 is issued per :func:`fold_runs`
+    run, and the runs fold as the dense product's do.  Rows past
+    ``sum(group_sizes)`` come out zero.
+    """
+    form = ragged_form(dims)
+    if form is None:
+        raise ValueError(f"no grouped Ozaki form for {dims}")
+    if num_splits < 1:
+        raise ValueError(f"num_splits must be >= 1, got {num_splits}")
+    lhs, rhs = jnp.asarray(lhs), jnp.asarray(rhs)
+    if out_dtype is None:
+        out_dtype = jnp.result_type(lhs.dtype, rhs.dtype)
+    return _ragged_ozaki(lhs, rhs, jnp.asarray(group_sizes, jnp.int32),
+                         form, num_splits, accumulator,
+                         jnp.dtype(out_dtype), slice_bits)
 
 
 def real_pair_matmul(real_matmul, a, b, real_out):

@@ -165,6 +165,12 @@ def _describe_sites(sites) -> None:
         offload_log.info(f"{len(off)} sites stay native "
                          "(size/dtype gate), e.g. "
                          + "; ".join(repr(s) for s in off[:3]))
+    native = getattr(sites, "native", ())
+    if native:
+        offload_log.warning(
+            f"{len(native)} contractions left native besides the gated "
+            "dot_general sites: " + "; ".join(
+                f"{n.primitive} {n.name} ({n.reason})" for n in native))
 
 
 def _parse(argv):

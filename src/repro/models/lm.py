@@ -32,6 +32,30 @@ paged == dense is an exact equivalence, not an approximate one.
 
 No framework dependency (flax/optax are not in the container): params
 are plain dicts, initialization is explicit.
+
+DeepSeek-V3 blocks (:class:`~repro.configs.LMConfig` with
+``kv_lora_rank``, and possibly ``num_experts``, set) train through the same
+:meth:`Model.loss`: the ``first_dense_layers`` leading dense layers run
+once each (``params["dense"]``, stacked), then the expert layers under
+the one ``scan`` (``params["blocks"]``).  The serving programs cover the
+Llama block only.  Multi-head latent attention (:meth:`Model._mla`)
+follows DeepSeek-V2/V3 without query compression (``q_lora_rank``
+null); its departures from the published code:
+
+* RoPE rotates half-dimension pairs (:func:`_rope`), where DeepSeek
+  rotates interleaved pairs: the two differ by a fixed permutation of
+  the RoPE columns of ``wq`` and ``wkv_a``;
+* no YaRN rope scaling, so the softmax scale is ``1/sqrt(qk_head_dim)``.
+
+The expert layer (:meth:`Model._moe`) scores all ``num_experts``
+experts with a sigmoid, picks ``experts_per_tok`` by score plus the
+``noaux_tc`` correction bias (a buffer held at zero here, so the pick is
+by score), normalizes the picked scores to sum 1 and scales them by
+``routed_scaling``.  It keeps the (token, choice) pairs of the experts
+it holds (``LMConfig.experts_held``), sorts them by expert, and runs
+each expert projection as one ``jax.lax.ragged_dot`` over the held
+experts' stacked weights; absent experts add nothing (on one device
+there is no exchange), and the shared expert is added once.
 """
 
 from __future__ import annotations
@@ -149,6 +173,9 @@ class Model:
     """
 
     def __init__(self, cfg: LMConfig, tp_axis: str | None = None):
+        if tp_axis and cfg.mla:
+            raise NotImplementedError(
+                "tensor parallelism covers the Llama block only")
         self.cfg = cfg
         self.tp_axis = tp_axis
         self.dtype = jnp.dtype(cfg.dtype)
@@ -180,6 +207,8 @@ class Model:
 
         s_in = d ** -0.5
         s_out = s_in / (2 * L) ** 0.5  # residual-branch damping
+        if cfg.mla:
+            return self._init_deepseek(keys[0])
         params = {
             "embed": init(keys[0], (cfg.vocab_size, d), 0.02),
             "blocks": {
@@ -198,6 +227,60 @@ class Model:
         if not cfg.tie_embeddings:
             params["lm_head"] = jnp.zeros((d, cfg.vocab_size),
                                           self.param_dtype)
+        return params
+
+    def _init_deepseek(self, rng) -> dict:
+        """Parameters of a DeepSeek-V3 block stack (MLA, with or without
+        experts).
+
+        ``dense`` stacks the leading dense layers and ``blocks`` the
+        expert layers (all layers in ``blocks`` without experts); the
+        held experts' weights stack on the axis after the layer axis.
+        Normal(0, 0.02) matrices, unit norms, as DeepSeek initializes.
+        """
+        cfg = self.cfg
+        d, H = cfg.d_model, cfg.num_heads
+        keys = iter(jax.random.split(rng, 32))
+
+        def init(*shape):
+            w = 0.02 * jax.random.normal(next(keys), shape, jnp.float32)
+            return w.astype(self.param_dtype)
+
+        def ones(*shape):
+            return jnp.ones(shape, self.param_dtype)
+
+        def attention(L):
+            r, v = cfg.kv_lora_rank, cfg.v_head_dim
+            return {"attn_norm": ones(L, d), "wq": init(L, d, cfg.q_dim),
+                    "wkv_a": init(L, d, r + cfg.qk_rope_head_dim),
+                    "kv_norm": ones(L, r),
+                    "wkv_b": init(L, r, H * (cfg.qk_nope_head_dim + v)),
+                    "wo": init(L, H * v, d)}
+
+        def swiglu(L, prefix, *lead, f):
+            return {f"{prefix}gate": init(L, *lead, d, f),
+                    f"{prefix}up": init(L, *lead, d, f),
+                    f"{prefix}down": init(L, *lead, f, d)}
+
+        Ld = cfg.dense_layers
+        params = {"embed": init(cfg.vocab_size, d)}
+        dense = {**attention(Ld), "mlp_norm": ones(Ld, d),
+                 **swiglu(Ld, "w_", f=cfg.d_ff)}
+        if cfg.moe:
+            Lm = cfg.num_layers - Ld
+            params["dense"] = dense
+            params["blocks"] = {
+                **attention(Lm), "mlp_norm": ones(Lm, d),
+                "router": init(Lm, d, cfg.num_experts),
+                **swiglu(Lm, "expert_", cfg.held[1], f=cfg.moe_d_ff)}
+            if cfg.shared_d_ff:
+                params["blocks"].update(
+                    swiglu(Lm, "shared_", f=cfg.shared_d_ff))
+        else:
+            params["blocks"] = dense
+        params["final_norm"] = ones(d)
+        if not cfg.tie_embeddings:
+            params["lm_head"] = init(d, cfg.vocab_size)
         return params
 
     # -- shared block pieces -----------------------------------------
@@ -233,6 +316,99 @@ class Model:
         return x + self._tp_out((gate * up).astype(x.dtype)
                                 @ lp["w_down"])
 
+    def _attend(self, lp, x, positions, mask):
+        """x plus the layer's attention (GQA or MLA), full context."""
+        if self.cfg.mla:
+            return self._mla(lp, x, positions, mask)
+        q, k, v = self._qkv(lp, x, positions)
+        H = q.shape[2]
+        o = _sdpa(q, self._repeat_kv(k, H), self._repeat_kv(v, H), mask)
+        return self._attn_out(lp, x, o)
+
+    def _mla(self, lp, x, positions, mask):
+        """Multi-head latent attention, DeepSeek-V3 without query
+        compression (departures in the module docstring).
+
+        Keys and values come from one latent of width ``kv_lora_rank``
+        (RMS-normalized), and one RoPE key of width ``qk_rope_head_dim``
+        is shared by all heads.
+        """
+        cfg = self.cfg
+        B, T = x.shape[:2]
+        H, nope = cfg.num_heads, cfg.qk_nope_head_dim
+        r, rope = cfg.kv_lora_rank, cfg.qk_rope_head_dim
+        h = _rms_norm(x, lp["attn_norm"], cfg.norm_eps)
+        q = (h @ lp["wq"]).reshape(B, T, H, nope + rope)
+        ckv = h @ lp["wkv_a"]
+        latent = _rms_norm(ckv[..., :r], lp["kv_norm"], cfg.norm_eps)
+        kv = (latent @ lp["wkv_b"]).reshape(B, T, H, -1)
+        k_pe = _rope(ckv[..., None, r:], positions, cfg.rope_theta)
+        q = jnp.concatenate(
+            [q[..., :nope], _rope(q[..., nope:], positions, cfg.rope_theta)],
+            axis=-1)
+        k = jnp.concatenate(
+            [kv[..., :nope], jnp.broadcast_to(k_pe, (B, T, H, rope))],
+            axis=-1)
+        o = _sdpa(q, k, kv[..., nope:], mask)
+        return x + o.reshape(B, T, -1) @ lp["wo"]
+
+    def _route(self, lp, h):
+        """(experts (N, k), weights (N, k)) each token of ``h`` (N, d)
+        picks, over all ``num_experts`` experts."""
+        cfg = self.cfg
+        with jax.named_scope("moe_route"):
+            scores = jax.nn.sigmoid((h @ lp["router"]).astype(jnp.float32))
+            # noaux_tc picks by score plus a correction bias, a buffer
+            # held at zero here: the pick is by score.
+            _, expert = jax.lax.top_k(scores, cfg.experts_per_tok)
+            weight = jnp.take_along_axis(scores, expert, axis=-1)
+            weight = (weight / (jnp.sum(weight, -1, keepdims=True) + 1e-20)
+                      * cfg.routed_scaling)
+        return expert, weight
+
+    def _moe(self, lp, x):
+        """x plus the expert layer of its normalized rows."""
+        B, T, d = x.shape
+        h = _rms_norm(x, lp["mlp_norm"], self.cfg.norm_eps)
+        return x + self._experts(lp, h.reshape(B * T, d)).reshape(B, T, d)
+
+    def _experts(self, lp, h):
+        """The expert layer of ``h`` (N, d): the routed experts held
+        here, and the shared expert (the routing is in the module
+        docstring)."""
+        cfg = self.cfg
+        first, held = cfg.held
+        k = cfg.experts_per_tok
+        expert, weight = self._route(lp, h)
+        with jax.named_scope("moe_dispatch"):
+            local = expert.reshape(-1) - first
+            mine = (local >= 0) & (local < held)
+            local = jnp.where(mine, local, held)  # absent experts last
+            order = jnp.argsort(local, stable=True)
+            token = order // k
+            sizes = jnp.bincount(local, length=held + 1)[:held].astype(
+                jnp.int32)
+            # Rows past the held pairs belong to no group, and XLA:TPU's
+            # grouped product leaves them unwritten: they are selected
+            # away here and in the combine, forward and backward.
+            routed = mine[order]
+            rows = jnp.where(routed[:, None], h[token], 0)
+            weight = jnp.where(mine, weight.reshape(-1), 0.0)[order]
+        gate = jax.lax.ragged_dot(rows, lp["expert_gate"], sizes)
+        up = jax.lax.ragged_dot(rows, lp["expert_up"], sizes)
+        act = (jax.nn.silu(gate.astype(jnp.float32))
+               * up.astype(jnp.float32)).astype(h.dtype)
+        out = jax.lax.ragged_dot(act, lp["expert_down"], sizes)
+        with jax.named_scope("moe_combine"):
+            out = (jnp.where(routed[:, None], out, 0)
+                   * weight[:, None].astype(out.dtype))
+            y = jnp.zeros(h.shape, out.dtype).at[token].add(out)
+        if cfg.shared_d_ff:
+            gate = jax.nn.silu((h @ lp["shared_gate"]).astype(jnp.float32))
+            up = (h @ lp["shared_up"]).astype(jnp.float32)
+            y = y + (gate * up).astype(h.dtype) @ lp["shared_down"]
+        return y
+
     def _repeat_kv(self, kv, num_heads):
         """(B, S, KV, d) -> (B, S, H, d) for grouped-query attention.
 
@@ -260,18 +436,21 @@ class Model:
         causal = jnp.tril(jnp.ones((T, T), bool))
         mask = jnp.broadcast_to(causal, (B, T, T))
 
-        def block(x, lp):
-            q, k, v = self._qkv(lp, x, positions)
-            H = q.shape[2]
-            o = _sdpa(q, self._repeat_kv(k, H), self._repeat_kv(v, H),
-                      mask)
-            x = self._attn_out(lp, x, o)
-            x = self._mlp(lp, x)
-            return x, None
+        def dense(x, lp):
+            return self._mlp(lp, self._attend(lp, x, positions, mask)), None
+
+        def expert(x, lp):
+            return self._moe(lp, self._attend(lp, x, positions, mask)), None
 
         if cfg.remat:
-            block = jax.checkpoint(block)
-        x, _ = jax.lax.scan(block, x, params["blocks"])
+            dense, expert = jax.checkpoint(dense), jax.checkpoint(expert)
+        # DeepSeek-V3: the leading dense layers once each, then the
+        # expert layers under the scan.
+        for i in range(cfg.dense_layers if cfg.moe else 0):
+            x, _ = dense(x, jax.tree_util.tree_map(lambda a: a[i],
+                                                   params["dense"]))
+        x, _ = jax.lax.scan(expert if cfg.moe else dense, x,
+                            params["blocks"])
         return self._head(params, x)
 
     def loss(self, params, tokens) -> jax.Array:
@@ -295,9 +474,15 @@ class Model:
 
     # -- KV-cache programs (serving) ---------------------------------
 
+    def _llama_only(self, what: str) -> None:
+        if self.cfg.mla:
+            raise NotImplementedError(
+                f"{what} holds the Llama block's keys and values only")
+
     def init_cache(self, batch: int, max_len: int) -> dict:
         """Empty cache: stacked K/V buffers + per-slot lengths."""
         cfg = self.cfg
+        self._llama_only("the KV cache")
         shape = (cfg.num_layers, batch, cfg.num_kv_heads, max_len,
                  cfg.head_dim)
         return {"k": jnp.zeros(shape, self.dtype),
@@ -416,6 +601,7 @@ class Model:
         the full cache dict around these pools.
         """
         cfg = self.cfg
+        self._llama_only("the paged KV cache")
         shape = (cfg.num_layers, num_blocks, cfg.num_kv_heads,
                  block_size, cfg.head_dim)
         return {"k": jnp.zeros(shape, self.dtype),

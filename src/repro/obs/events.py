@@ -162,7 +162,9 @@ class MetricsRun:
         ``site_exec`` counter labeled by site name, adds the payload's
         ``int8_dots`` (the INT8 dots that execution issued,
         ``Site.int8_dots``) to the ``int8_dots`` counter of the same
-        label and, on the first execution of a site, emits its static
+        label, a grouped site's ``rows`` (the rows that execution
+        routed) to its ``grouped_rows`` counter and, on the first
+        execution of a site, emits its static
         ``site_exec`` record —
         so the JSONL stream proves the hook fired even if the process
         dies before the registry snapshot is flushed.
@@ -174,6 +176,9 @@ class MetricsRun:
             if payload.get("int8_dots"):
                 self.registry.counter("int8_dots", site=site).inc(
                     payload["int8_dots"])
+            if "rows" in payload:
+                self.registry.counter("grouped_rows", site=site).inc(
+                    payload["rows"])
             with self._lock:
                 first = site not in self._declared_exec
                 if first:

@@ -132,3 +132,36 @@ def test_must_real_pair_gemm_compiles(one_chip):
     blk = ((1024, 1024), jnp.float64)
     text = _compile(gemm, one_chip, blk, blk, blk, blk)
     assert "c128" not in text
+
+
+@pytest.mark.parametrize("form", ["rows", "contraction"])
+def test_grouped_ozaki_compiles_to_int8_ragged_dots(one_chip, form):
+    # Moonlight's expert up-projection on one chip's 8 experts, its rows
+    # at their bound (2048 tokens x 6 picks): XLA:TPU takes the int8
+    # grouped products into int32 (a ragged-dot kernel, no dense loop).
+    from repro.core.ozaki import (RAGGED_CONTRACTION, RAGGED_ROWS,
+                                  ozaki_ragged_dot)
+
+    rows, d, f, g = 12288, 2048, 1408, 8
+    dims = RAGGED_ROWS if form == "rows" else RAGGED_CONTRACTION
+    rhs = (g, d, f) if form == "rows" else (rows, f)
+
+    def grouped(a, b, sizes):
+        return ozaki_ragged_dot(a, b, sizes, dims, num_splits=4)
+
+    shapes = [((rows, d), jnp.float32), (rhs, jnp.float32),
+              ((g,), jnp.int32)]
+    text = _compile(grouped, one_chip, *shapes)
+    assert "ragged-dot-metadata" in text
+    assert text.count("s8[") > 0
+    # Under the cell's `highest`, the int8 grouped products keep their
+    # own precision: Mosaic refuses the kernel at f32 contract precision
+    # on the chip, after this compile has passed.
+    with jax.default_matmul_precision("highest"):
+        lowered = jax.jit(grouped).lower(*[
+            jax.ShapeDtypeStruct(s, dt, sharding=one_chip)
+            for s, dt in shapes]).as_text()
+    ragged = [line for line in lowered.splitlines()
+              if "chlo.ragged_dot" in line]
+    assert len(ragged) == 4
+    assert not any("HIGHEST" in line for line in ragged)
