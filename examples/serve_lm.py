@@ -8,8 +8,7 @@ the decode_32k/long_500k dry-run cells lower at 512 devices.
   PYTHONPATH=src python examples/serve_lm.py
   PYTHONPATH=src python examples/serve_lm.py --preset tiny
   PYTHONPATH=src python examples/serve_lm.py --temperature 0.8 --seed 7
-  PYTHONPATH=src python examples/serve_lm.py --splits 6 \\
-      --warm-cache-dir /tmp/serve-cache   # 2nd run skips re-tracing
+  PYTHONPATH=src python examples/serve_lm.py --splits 6 --min-dim 1
 """
 
 import argparse
@@ -57,15 +56,11 @@ def main(argv=None):
     ap.add_argument("--splits", type=int, default=0,
                     help="offload every GEMM at this split count "
                          "(a plain PrecisionPolicy; no plan artifact "
-                         "needed — handy with --warm-cache-dir)")
+                         "needed)")
     ap.add_argument("--min-dim", type=int, default=128,
                     help="with --splits: offload only GEMMs whose m, k "
                          "and n are all at least this (decode GEMMs "
                          "have m = one row per slot)")
-    ap.add_argument("--warm-cache-dir", default="",
-                    help="persist jaxpr-transform decisions/programs "
-                         "here so a restarted server warm-starts "
-                         "without re-tracing (needs --plan/--splits)")
     ap.add_argument("--keep-logits", action="store_true",
                     help="keep each emitted token's logits row on the "
                          "returned requests (Request.logits)")
@@ -127,7 +122,6 @@ def main(argv=None):
                     block_size=args.block_size,
                     chunk_tokens=args.chunk_tokens,
                     chunk_token_budget=args.chunk_token_budget,
-                    warm_cache_dir=args.warm_cache_dir or None,
                     scheduler_policy=args.scheduler_policy,
                     metrics_port=args.metrics_port)
     if engine.metrics_server is not None:

@@ -12,8 +12,8 @@ Layers:
 
 from .backends import (GemmBackend, example_specs, get_backend,
                        register_backend, registered_families)
-from .intercept import (CacheInfo, Native, PersistInfo, Site, SiteList,
-                        offload, site_report, transform_jaxpr)
+from .intercept import (CacheInfo, Native, Site, SiteList, offload,
+                        site_report, transform_jaxpr)
 from .ozaki import (SLICE_BITS, num_pair_gemms, ozaki_matmul,
                     ozaki_ragged_dot, pair_indices, slice_matrix)
 from .precision import (AdaptiveGemm, PrecisionPolicy, SiteState,
@@ -41,7 +41,6 @@ __all__ = [
     "ozaki_matmul",
     "ozaki_ragged_dot",
     "pair_indices",
-    "PersistInfo",
     "predict_splits",
     "register_backend",
     "registered_families",

@@ -83,11 +83,7 @@ Public API
 
 from __future__ import annotations
 
-import dataclasses
-import hashlib
-import json
 import math
-import os
 import warnings
 from collections import OrderedDict, namedtuple
 from typing import Any, Dict, List, Sequence, Tuple
@@ -96,7 +92,6 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from jax import export as _jax_export  # not auto-imported by `import jax`
 from jax._src import source_info_util
 from jax.extend import core as jex_core
 
@@ -106,8 +101,7 @@ from .ozaki import (RAGGED_CONTRACTION, RAGGED_ROWS, fold_runs,
 from .precision import PrecisionPolicy
 
 __all__ = ["offload", "site_report", "transform_jaxpr", "Site",
-           "SiteList", "Native", "CacheInfo", "PersistInfo",
-           "OFFLOAD_CACHE_SIZE"]
+           "SiteList", "Native", "CacheInfo", "OFFLOAD_CACHE_SIZE"]
 
 #: How the walker and the evaluator treat a primitive: the one table of
 #: primitive names both read (any other primitive is re-bound as is).
@@ -1118,177 +1112,10 @@ def _signature(flat_args):
 CacheInfo = namedtuple("CacheInfo", ["hits", "misses", "maxsize",
                                      "currsize"])
 
-#: ``wrapped.persist_info()`` record for the on-disk transform cache:
-#: ``disk_hits`` — entries restored with a runnable exported program
-#: (no re-trace, no re-transform); ``disk_decisions_hits`` — entries
-#: whose site decisions were restored and byte-verified but whose
-#: program had to be re-traced (no exported artifact on disk);
-#: ``disk_misses`` — entries traced fresh and written out.
-PersistInfo = namedtuple("PersistInfo", ["disk_hits",
-                                         "disk_decisions_hits",
-                                         "disk_misses", "directory"])
 
-#: Bumped whenever the persisted payload layout changes; part of the
-#: cache key, so stale-format files are simply never looked up.
-_PERSIST_FORMAT = 3
+#: One transform-cache entry.
+_Entry = namedtuple("_Entry", ["transformed", "sites", "out_tree"])
 
-
-def _site_payload(sites: Sequence[Site]) -> dict:
-    """Site records and the native contractions (a :class:`SiteList`)
-    as plain JSON data (the persisted decision set)."""
-    return {"sites": [{"name": s.name, "lhs_shape": list(s.lhs_shape),
-             "rhs_shape": list(s.rhs_shape), "dtype": s.dtype.name,
-             "offloaded": bool(s.offloaded), "splits": int(s.splits),
-             "reason": s.reason, "m": int(s.m), "k": int(s.k),
-             "n": int(s.n), "batch": int(s.batch), "mult": int(s.mult),
-             "spmd_axes": [[a, int(x)] for a, x in s.spmd_axes],
-             "backend": s.backend, "eligible": bool(s.eligible),
-             "tiles": s.tiles, "int8_dots": int(s.int8_dots),
-             "primitive": s.primitive, "group_count": int(s.group_count)}
-            for s in sites],
-            "native": [list(n) for n in getattr(sites, "skipped", ())]}
-
-
-def _sites_from_payload(payload: dict) -> SiteList:
-    return SiteList(
-        [Site(p["name"], p["lhs_shape"], p["rhs_shape"], p["dtype"],
-              p["offloaded"], p["splits"], p["reason"], m=p["m"],
-              k=p["k"], n=p["n"], batch=p["batch"], mult=p["mult"],
-              spmd_axes=[tuple(a) for a in p["spmd_axes"]],
-              backend=p["backend"], eligible=p["eligible"],
-              tiles=p["tiles"], int8_dots=p["int8_dots"],
-              primitive=p["primitive"], group_count=p["group_count"])
-         for p in payload["sites"]],
-        [Native(*n) for n in payload["native"]])
-
-
-def _sites_bytes(sites: Sequence[Site]) -> bytes:
-    """Canonical byte encoding of the decision set.  Two processes that
-    take the same decisions produce *identical bytes* — the warm-start
-    restart test compares these files directly."""
-    return json.dumps(_site_payload(sites), sort_keys=True,
-                      separators=(",", ":")).encode()
-
-
-def _persist_key(fn_label, in_tree, sig, policy, plan, hooked) -> str:
-    """Content-address one transform-cache entry.
-
-    Keyed the way jax's own ``compilation_cache`` keys executables: a
-    hash over everything that determines the transform's output — the
-    function identity (label), the input pytree structure and abstract
-    signature, the full policy, the plan fingerprint, and the library
-    versions — so an entry is reused exactly when re-tracing would have
-    reproduced it.
-    """
-    payload = {
-        "format": _PERSIST_FORMAT,
-        "fn": fn_label,
-        "in_tree": str(in_tree),
-        "signature": [[list(shape), str(np.dtype(dt)), bool(weak)]
-                      for shape, dt, weak in sig],
-        "policy": dataclasses.asdict(policy),
-        "plan": getattr(plan, "fingerprint", None),
-        "hooked": bool(hooked),
-        "jax": jax.__version__,
-    }
-    blob = json.dumps(payload, sort_keys=True, separators=(",", ":"))
-    return hashlib.sha256(blob.encode()).hexdigest()[:32]
-
-
-class _DiskCache:
-    """Fingerprinted on-disk transform cache (one dir, flat files).
-
-    ``<key>.json`` holds the canonical site-decision bytes;
-    ``<key>.bin`` holds the ``jax.export``-serialized program when the
-    entry was exportable.  Writes are atomic (tmp + rename), corrupt or
-    missing files degrade to a miss — never an error.
-    """
-
-    def __init__(self, directory):
-        self.directory = str(directory)
-        os.makedirs(self.directory, exist_ok=True)
-
-    def _path(self, key: str, ext: str) -> str:
-        return os.path.join(self.directory, f"{key}.{ext}")
-
-    def load(self, key: str):
-        """-> (raw decision bytes | None, deserialized Exported | None)."""
-        try:
-            with open(self._path(key, "json"), "rb") as f:
-                raw = f.read()
-        except OSError:
-            return None, None
-        exported = None
-        try:
-            with open(self._path(key, "bin"), "rb") as f:
-                exported = _jax_export.deserialize(bytearray(f.read()))
-        except OSError:
-            pass
-        except Exception as exc:  # corrupt/incompatible artifact
-            warnings.warn(f"persisted transform program {key}.bin "
-                          f"unusable ({exc!r}); re-tracing")
-        return raw, exported
-
-    def store(self, key: str, raw_json: bytes,
-              exported_bytes: bytes | None) -> None:
-        self._write(self._path(key, "json"), raw_json)
-        if exported_bytes is not None:
-            self._write(self._path(key, "bin"), exported_bytes)
-
-    def _write(self, path: str, data: bytes) -> None:
-        tmp = f"{path}.tmp.{os.getpid()}"
-        with open(tmp, "wb") as f:
-            f.write(data)
-        os.replace(tmp, path)
-
-
-class _Entry:
-    """One transform-cache entry; ``runnable`` is set for disk-restored
-    exported programs and for ``jit_entries`` wrappers."""
-
-    __slots__ = ("transformed", "sites", "out_tree", "runnable")
-
-    def __init__(self, transformed, sites, out_tree, runnable=None):
-        self.transformed = transformed
-        self.sites = sites
-        self.out_tree = out_tree
-        self.runnable = runnable
-
-
-def _entry_runner(transformed, out_tree):
-    """A jit-compiled callable over the original (args, kwargs)
-    signature that evaluates one transformed jaxpr."""
-
-    def run(*args, **kwargs):
-        flat, _ = jax.tree_util.tree_flatten((args, kwargs))
-        out = jax.core.eval_jaxpr(transformed.jaxpr,
-                                  transformed.consts, *flat)
-        return jax.tree_util.tree_unflatten(out_tree, out)
-
-    return jax.jit(run)
-
-
-def _export_entry(transformed, out_tree, args, kwargs):
-    """``jax.export``-serialize one entry's program, or None.
-
-    Export legitimately fails for programs the serializer cannot carry
-    (debug callbacks, unstable custom calls); the caller then persists
-    decisions only.
-    """
-
-    def run(*a, **kw):
-        flat, _ = jax.tree_util.tree_flatten((a, kw))
-        out = jax.core.eval_jaxpr(transformed.jaxpr,
-                                  transformed.consts, *flat)
-        return jax.tree_util.tree_unflatten(out_tree, out)
-
-    try:
-        exp = _jax_export.export(jax.jit(run))(*args, **kwargs)
-        return exp.serialize()
-    except Exception as exc:
-        warnings.warn(f"transform entry not exportable ({exc!r}); "
-                      "persisting decisions only")
-        return None
 
 #: Default bound on the per-wrapper transform cache.  Serve-style
 #: callers present an open-ended stream of signatures (every padded
@@ -1300,9 +1127,7 @@ def offload(fn, policy: PrecisionPolicy | None = None, *,
             plan=None, plan_match: str = "strict",
             backend: GemmBackend | None = None,
             on_site_event=None,
-            cache_size: int = OFFLOAD_CACHE_SIZE,
-            persist_dir=None, fn_label: str | None = None,
-            on_cache_event=None, jit_entries: bool = False):
+            cache_size: int = OFFLOAD_CACHE_SIZE):
     """Wrap ``fn`` so its large matmuls run through the policy backend.
 
     The first call for a given input signature traces ``fn`` once and
@@ -1345,34 +1170,14 @@ def offload(fn, policy: PrecisionPolicy | None = None, *,
     loop padding every admission wave to a fresh (batch, prompt) shape
     — cannot retain unbounded transformed jaxprs.  Inspect it with
     ``wrapped.cache_info()`` and reset it with ``wrapped.cache_clear()``.
+    It lives in memory only: a restarted process re-traces, and JAX's
+    persistent compilation cache (:mod:`repro.launch.compile_cache`)
+    keeps its compiles warm, save a program's that carries the
+    ``on_site_event`` hook.
 
     The returned wrapper exposes ``wrapped.sites(*args, **kwargs)``,
     the exact :class:`Site` decisions taken for that signature — the
     same objects :func:`site_report` would produce, same names.
-
-    ``persist_dir`` additionally persists the transform cache to disk,
-    content-addressed the way jax's ``compilation_cache.py`` keys
-    executables (function label + input signature + policy + plan
-    fingerprint + library versions; see :func:`_persist_key`).  Each
-    entry is two files: ``<key>.json``, the canonical byte encoding of
-    the site decisions (two processes taking the same decisions write
-    identical bytes), and ``<key>.bin``, the ``jax.export``-serialized
-    program when exportable (it is not when ``on_site_event`` is set —
-    debug callbacks cannot be serialized).  A restarted process that
-    finds both files reuses the program without re-tracing or
-    re-transforming; decisions-only entries are re-traced but
-    byte-verified against the persisted decisions.  ``fn_label`` names
-    the function in the key (defaults to ``fn.__name__`` — pass an
-    explicit stable label, lambdas all share ``"<lambda>"``);
-    ``on_cache_event`` is called with ``"miss"`` / ``"disk_hit"`` /
-    ``"disk_decisions_hit"`` as entries resolve (in-memory hits are
-    silent); ``wrapped.persist_info()`` returns the tallies.
-
-    ``jit_entries=True`` gives every cache entry its own jit-compiled
-    runner over the original call signature, so the wrapper is called
-    *directly* instead of under an outer ``jax.jit`` — required when
-    entries may come from disk as exported programs (which carry their
-    own compilation) and fresh trace fallbacks must match.
     """
     if plan_match not in ("strict", "subset"):
         raise ValueError(f"plan_match must be 'strict' or 'subset', "
@@ -1393,50 +1198,15 @@ def offload(fn, policy: PrecisionPolicy | None = None, *,
         raise ValueError(f"cache_size must be >= 1, got {cache_size}")
     cache: "OrderedDict[Any, _Entry]" = OrderedDict()
     stats = {"hits": 0, "misses": 0}
-    pstats = {"disk_hits": 0, "disk_decisions_hits": 0,
-              "disk_misses": 0}
-    disk = _DiskCache(persist_dir) if persist_dir is not None else None
-    label = fn_label or getattr(fn, "__name__", "fn")
-
-    def _event(kind: str) -> None:
-        if on_cache_event is not None:
-            on_cache_event(kind)
 
     def build(args, kwargs):
         flat, in_tree = jax.tree_util.tree_flatten((args, kwargs))
-        sig = _signature(flat)
-        key = (in_tree, sig)
+        key = (in_tree, _signature(flat))
         entry = cache.get(key)
         if entry is not None:
             stats["hits"] += 1
             cache.move_to_end(key)
             return flat, entry
-
-        raw = dkey = None
-        if disk is not None:
-            dkey = _persist_key(label, in_tree, sig, policy, plan,
-                                on_site_event is not None)
-            raw, exported = disk.load(dkey)
-            if raw is not None:
-                try:
-                    restored = _sites_from_payload(json.loads(raw))
-                except Exception as exc:
-                    warnings.warn(f"persisted transform decisions "
-                                  f"{dkey}.json unreadable ({exc!r}); "
-                                  "re-tracing")
-                    raw = None
-                else:
-                    if exported is not None:
-                        # Full warm start: restored program, zero
-                        # tracing/transform work in this process.
-                        pstats["disk_hits"] += 1
-                        _event("disk_hit")
-                        entry = _Entry(None, restored, None,
-                                       jax.jit(exported.call))
-                        cache[key] = entry
-                        while len(cache) > cache_size:
-                            cache.popitem(last=False)
-                        return flat, entry
 
         stats["misses"] += 1
         closed, out_shape = jax.make_jaxpr(
@@ -1445,33 +1215,8 @@ def offload(fn, policy: PrecisionPolicy | None = None, *,
             closed, policy, backend, on_site_event=on_site_event)
         if plan is not None and plan_match == "strict":
             plan.validate_sites(sites)
-        out_tree = jax.tree_util.tree_structure(out_shape)
-        entry = _Entry(transformed, sites, out_tree)
-        if jit_entries:
-            entry.runnable = _entry_runner(transformed, out_tree)
-        if disk is not None:
-            fresh = _sites_bytes(sites)
-            if raw is not None:
-                # Decisions were on disk (no runnable program): the
-                # re-trace must reproduce them byte-for-byte, or the
-                # environment changed under a colliding key.
-                if fresh != raw:
-                    warnings.warn(
-                        f"persisted transform decisions {dkey}.json "
-                        "do not match this process's re-trace; "
-                        "overwriting with the fresh decisions")
-                    disk.store(dkey, fresh, None)
-                pstats["disk_decisions_hits"] += 1
-                _event("disk_decisions_hit")
-            else:
-                pstats["disk_misses"] += 1
-                _event("miss")
-                exported_bytes = None
-                if on_site_event is None:
-                    exported_bytes = _export_entry(transformed,
-                                                   out_tree, args,
-                                                   kwargs)
-                disk.store(dkey, fresh, exported_bytes)
+        entry = _Entry(transformed, sites,
+                       jax.tree_util.tree_structure(out_shape))
         cache[key] = entry
         while len(cache) > cache_size:
             cache.popitem(last=False)
@@ -1479,8 +1224,6 @@ def offload(fn, policy: PrecisionPolicy | None = None, *,
 
     def wrapped(*args, **kwargs):
         flat, entry = build(args, kwargs)
-        if entry.runnable is not None:
-            return entry.runnable(*args, **kwargs)
         out_flat = jax.core.eval_jaxpr(entry.transformed.jaxpr,
                                        entry.transformed.consts, *flat)
         return jax.tree_util.tree_unflatten(entry.out_tree, out_flat)
@@ -1493,12 +1236,6 @@ def offload(fn, policy: PrecisionPolicy | None = None, *,
         return CacheInfo(stats["hits"], stats["misses"], cache_size,
                          len(cache))
 
-    def persist_info() -> PersistInfo:
-        return PersistInfo(pstats["disk_hits"],
-                           pstats["disk_decisions_hits"],
-                           pstats["disk_misses"],
-                           disk.directory if disk else None)
-
     def cache_clear() -> None:
         cache.clear()
         stats["hits"] = stats["misses"] = 0
@@ -1508,7 +1245,6 @@ def offload(fn, policy: PrecisionPolicy | None = None, *,
     wrapped.policy = policy
     wrapped.backend = backend
     wrapped.cache_info = cache_info
-    wrapped.persist_info = persist_info
     wrapped.cache_clear = cache_clear
     return wrapped
 

@@ -92,24 +92,6 @@ def _site_exec_counts(grouped) -> Dict[str, float]:
     return counts
 
 
-def _cache_event_counts(grouped) -> Dict[str, float]:
-    """Transform-cache resolutions by kind (``miss`` / ``disk_hit`` /
-    ``disk_decisions_hit``): registry snapshot if the run closed
-    cleanly, else the raw ``transform_cache`` event stream."""
-    counts: Dict[str, float] = {}
-    for ev in grouped.get("metric", ()):
-        if (ev.get("kind") == "counter"
-                and ev.get("name") == "transform_cache"):
-            kind = (ev.get("labels") or {}).get("result", "?")
-            counts[kind] = counts.get(kind, 0) + float(
-                ev.get("value", 0))
-    if not counts:
-        for ev in grouped.get("transform_cache", ()):
-            kind = ev.get("result", "?")
-            counts[kind] = counts.get(kind, 0) + 1
-    return counts
-
-
 def _last_gauges(grouped, names) -> Dict[str, float]:
     """Final value of each named gauge (metric events are snapshots in
     write order, so the last one wins)."""
@@ -121,8 +103,7 @@ def _last_gauges(grouped, names) -> Dict[str, float]:
 
 
 def _report_run(run_id: str, events: List[dict], out,
-                check: bool = False,
-                expect_cache_hit: bool = False) -> int:
+                check: bool = False) -> int:
     grouped = _by_type(events)
     failures = 0
     torn = getattr(events, "dropped", 0)
@@ -187,22 +168,6 @@ def _report_run(run_id: str, events: List[dict], out,
                  max(c.get("realized_rel", 0.0) for c in checks),
                  checks[-1].get("budget"),
                  sum(1 for c in checks if c.get("drift"))]], out)
-
-    cache_counts = _cache_event_counts(grouped)
-    if cache_counts or expect_cache_hit:
-        if cache_counts:
-            print("transform cache:", file=out)
-            _table(["result", "count"],
-                   [[k, v] for k, v in sorted(cache_counts.items())],
-                   out)
-        warm = (cache_counts.get("disk_hit", 0)
-                + cache_counts.get("disk_decisions_hit", 0))
-        if expect_cache_hit and warm < 1:
-            print("CHECK FAIL: --expect-cache-hit but the run "
-                  "recorded no disk_hit/disk_decisions_hit transform-"
-                  "cache resolutions (cold trace, or warm_cache_dir "
-                  "not set?)", file=out)
-            failures += 1
 
     reqs = grouped.get("request", [])
     if reqs:
@@ -368,10 +333,6 @@ def main(argv=None, out=None) -> int:
     rep.add_argument("--check", action="store_true",
                      help="exit nonzero unless every offloaded site "
                      "recorded at least one execution")
-    rep.add_argument("--expect-cache-hit", action="store_true",
-                     help="exit nonzero unless the run resolved at "
-                     "least one transform-cache entry from the "
-                     "persistent on-disk cache (warm start)")
 
     exp = sub.add_parser("export", help="write a Chrome trace from "
                          "the run's span events")
@@ -419,9 +380,8 @@ def main(argv=None, out=None) -> int:
         for i, (run_id, events) in enumerate(sorted(runs.items())):
             if i:
                 print("", file=out)
-            failures += _report_run(
-                run_id, events, out, check=args.check,
-                expect_cache_hit=args.expect_cache_hit)
+            failures += _report_run(run_id, events, out,
+                                    check=args.check)
         return 1 if failures else 0
 
     events = [ev for _, evs in sorted(runs.items()) for ev in evs]

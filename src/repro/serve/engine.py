@@ -27,9 +27,7 @@ Tunable-precision serving: pass ``plan=`` (a
 :class:`repro.tune.PrecisionPlan`) or ``policy=`` to run the prefill
 and decode GEMMs through the automatic offload transform — the same
 plan artifact the training loop consumes, applied in subset mode
-because serving traces only the forward sites.  Add
-``warm_cache_dir=`` to persist the transform cache across process
-restarts (see :func:`repro.core.intercept.offload`).
+because serving traces only the forward sites.
 
 Multi-device serving: pass ``mesh=`` to shard the engine across the
 slot (batch) axis — parameters replicated, the KV cache and every
@@ -110,11 +108,6 @@ class Engine:
         e.g. 64 to interleave decode ticks into long-prompt ingestion.
       chunk_token_budget: cap on total real tokens per prefill wave
         (packing budget); ``None`` = unlimited.
-      warm_cache_dir: directory for the persistent jaxpr-transform
-        cache.  A restarted engine pointed at the same directory
-        reuses the prior process's transform decisions (and compiled
-        programs where exportable) without re-tracing.  Single-device,
-        policy/plan runs only.
       scheduler_policy: ``"fifo"`` (default, the pre-refactor order)
         or ``"edf"`` (earliest ``t_enqueue + latency_target_s`` first).
       metrics_port: start a live :class:`repro.obs.MetricsServer` on
@@ -135,7 +128,6 @@ class Engine:
                  num_blocks: Optional[int] = None,
                  chunk_tokens: Optional[int] = None,
                  chunk_token_budget: Optional[int] = None,
-                 warm_cache_dir=None,
                  scheduler_policy: str = "fifo",
                  metrics_port: Optional[int] = None,
                  slo_objective: float = 0.99,
@@ -205,8 +197,7 @@ class Engine:
             dp_size=self._dp_size, slot_sharding=slot_sharding,
             kv_sharding=kv_sharding, policy=policy, plan=plan,
             metrics=metrics, chunk_tokens=chunk_tokens,
-            chunk_token_budget=chunk_token_budget,
-            warm_cache_dir=warm_cache_dir)
+            chunk_token_budget=chunk_token_budget)
         self.slo = None
         if metrics is not None:
             self.slo = SLOTracker(registry=metrics.registry,

@@ -19,18 +19,6 @@ absorb the wave width stop the wave early (head-of-line, order
 preserved) — only relevant for the dense layout, whose chunk padding
 is written in-rectangle; the paged layout routes padding to the trash
 block.
-
-Warm-start transform cache
---------------------------
-With ``warm_cache_dir`` the offload wrapper persists its jaxpr
-transform cache to disk (see :func:`repro.core.intercept.offload`), so
-a restarted server skips re-tracing.  Because the persisted program is
-serialized via ``jax.export`` — which cannot carry debug callbacks —
-the per-execution site-event hook is replaced by *static accounting*:
-after each program call the runner bumps ``site_exec`` by each
-offloaded site's static trip multiplicity (and ``int8_dots`` by that
-times the site's ``int8_dots``), which equals the hook's count exactly
-for these forward-only programs.
 """
 
 from __future__ import annotations
@@ -45,11 +33,8 @@ import numpy as np
 
 from repro.core import offload
 from repro.models import Model
-from repro.obs import get_logger
 
 __all__ = ["Runner", "WaveResult"]
-
-log = get_logger("serve")
 
 
 class _NullSpan:
@@ -104,8 +89,7 @@ class Runner:
                  mesh=None, dp_size: int = 1, slot_sharding=None,
                  kv_sharding=None, policy=None, plan=None,
                  metrics=None, chunk_tokens: Optional[int] = None,
-                 chunk_token_budget: Optional[int] = None,
-                 warm_cache_dir=None):
+                 chunk_token_budget: Optional[int] = None):
         self.model = model
         self.params = params
         self.kv = kv
@@ -123,24 +107,7 @@ class Runner:
         self.chunk_token_budget = (int(chunk_token_budget)
                                    if chunk_token_budget else None)
         self.batch_slots = kv.batch_slots
-        self._persist_dir = None
-        if warm_cache_dir is not None:
-            if policy is None:
-                log.debug("warm_cache_dir ignored: no policy/plan, so "
-                          "there is no transform cache to persist")
-            elif mesh is not None:
-                log.debug("warm_cache_dir ignored under a mesh: "
-                          "exported programs would bake in this "
-                          "process's device topology")
-            else:
-                self._persist_dir = warm_cache_dir
-        # Static site accounting replaces the per-execution debug-
-        # callback hook whenever the transform cache persists (exported
-        # programs cannot carry callbacks).
-        self._static_sites = (self._persist_dir is not None
-                              and metrics is not None)
         self._declared = False
-        self._seen_static: set = set()
 
         if self.layout == "paged":
             prefill_fn = model.prefill_chunk_paged
@@ -148,10 +115,8 @@ class Runner:
         else:
             prefill_fn = model.prefill_chunk
             decode_fn = model.decode_step
-        self._prefill_wrapped, self._prefill_call = self._wrap(
-            prefill_fn, f"serve_prefill_{self.layout}")
-        self._decode_wrapped, self._decode_call = self._wrap(
-            decode_fn, f"serve_decode_{self.layout}")
+        self._prefill_wrapped, self._prefill_call = self._wrap(prefill_fn)
+        self._decode_wrapped, self._decode_call = self._wrap(decode_fn)
 
         self.cache = self._pin(kv.init_cache())
         self._len = np.zeros(self.batch_slots, np.int64)
@@ -164,30 +129,15 @@ class Runner:
 
     # -- program wiring ----------------------------------------------
 
-    def _wrap(self, fn, label):
+    def _wrap(self, fn):
         """(inspectable wrapper, callable) for one serve program."""
         if self.policy is None:
             return None, jax.jit(fn)
-        if self._persist_dir is not None:
-            wrapped = offload(
-                fn, self.policy, plan=self.plan, plan_match="subset",
-                persist_dir=self._persist_dir, fn_label=label,
-                jit_entries=True, on_cache_event=self._cache_event)
-            # jit_entries compiles per cache entry (or runs the
-            # deserialized exported program); no outer jit.
-            return wrapped, wrapped
         hook = (self.metrics.site_event_handler()
                 if self.metrics is not None else None)
         wrapped = offload(fn, self.policy, plan=self.plan,
                           plan_match="subset", on_site_event=hook)
         return wrapped, jax.jit(wrapped)
-
-    def _cache_event(self, kind: str) -> None:
-        if self.metrics is None:
-            return
-        self.metrics.registry.counter("transform_cache",
-                                      result=kind).inc()
-        self.metrics.event("transform_cache", result=kind)
 
     def _pin(self, cache: dict) -> dict:
         """Re-assert slot/kv shardings on the cache pytree (no-op
@@ -226,23 +176,6 @@ class Runner:
         # transform-cache entry the call below hits.
         self.metrics.declare_sites(self._prefill_wrapped.sites(*args))
         self._declared = True
-
-    def _account(self, wrapped, args) -> None:
-        """Static ``site_exec`` accounting for the warm-cache path."""
-        if not self._static_sites or wrapped is None:
-            return
-        for s in wrapped.sites(*args):
-            if not s.offloaded:
-                continue
-            self.metrics.registry.counter(
-                "site_exec", site=s.name).inc(s.mult)
-            self.metrics.registry.counter(
-                "int8_dots", site=s.name).inc(s.mult * s.int8_dots)
-            if s.name not in self._seen_static:
-                self._seen_static.add(s.name)
-                self.metrics.event(
-                    "site_exec", site=s.name, backend=s.backend,
-                    splits=int(s.splits), counted="static")
 
     def sites_for(self, rows: int, width: int):
         """Site decisions of the prefill-chunk program for a wave shape
@@ -421,7 +354,6 @@ class Runner:
                 table_d, tok_d, start_d, piece_d)
         self._declare_once(args)
         k_new, v_new, logits = self._prefill_call(*args)
-        self._account(self._prefill_wrapped, args)
         self.cache = self._pin(dict(self.cache, k=k_new, v=v_new))
         return logits
 
@@ -437,7 +369,6 @@ class Runner:
         args = (self.params, sub_k, sub_v, tok_d, start_d, piece_d)
         self._declare_once(args)
         k_new, v_new, logits = self._prefill_call(*args)
-        self._account(self._prefill_wrapped, args)
         jreal = jnp.asarray(slots)
         self.cache = self._pin(dict(
             self.cache,
@@ -462,7 +393,6 @@ class Runner:
         with span:
             args = (self.params, self.cache, tokens, act)
             cache, logits = self._decode_call(*args)
-            self._account(self._decode_wrapped, args)
             self.cache = self._pin(cache)
             # Blocks, so the span covers the device step.
             toks = self._sample(logits, reqs)

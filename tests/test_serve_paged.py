@@ -1,8 +1,5 @@
 """Layered serve stack: paged == dense, chunked == unchunked, packing,
-sampling validation, and warm-start transform-cache restarts."""
-
-import json
-import os
+sampling validation, and restart determinism of the offloaded programs."""
 
 import jax
 import jax.numpy as jnp
@@ -12,7 +9,7 @@ import pytest
 from repro.configs import LMConfig
 from repro.core import PrecisionPolicy
 from repro.models import Model
-from repro.obs import MetricsRun
+from repro.obs import MetricsRun, load_runs
 from repro.obs.cli import main as obs_main
 from repro.serve import (Engine, PagedKVCache, Request,
                          SamplingParamError, Scheduler)
@@ -283,71 +280,53 @@ class TestScheduler:
         assert sched.pending == 2
 
 
-class TestWarmStart:
-    def _run_once(self, model, params, warm_dir, metrics_dir, prompts):
-        """One serve 'process': fresh engine, fresh transform caches
-        (the offload LRU lives on the wrapper, so a new Engine is a
-        faithful stand-in for a restarted process)."""
-        pol = PrecisionPolicy(default_splits=6, min_dim=32)
-        with MetricsRun(metrics_dir) as run:
-            eng = Engine(model, params, batch_slots=2, max_len=64,
-                         policy=pol, warm_cache_dir=warm_dir,
-                         metrics=run)
+class TestRestart:
+    POLICY = PrecisionPolicy(default_splits=6, min_dim=32)
+
+    def _engine(self, model, params, metrics=None):
+        """One serve 'process': a fresh engine has fresh offload
+        wrappers and transform caches, so it stands in for a restart."""
+        return Engine(model, params, batch_slots=2, max_len=64,
+                      policy=self.POLICY, metrics=metrics)
+
+    def test_fresh_engines_take_identical_decisions(self, model_params):
+        """A restarted server re-traces its programs: it must emit the
+        same tokens and take the same per-site decisions (names,
+        offload, splits), which plan keying relies on."""
+        model, params = model_params
+        prompts = _prompts([20, 24, 28], seed=17)
+        runs = []
+        for _ in range(2):
+            eng = self._engine(model, params)
             out = [r.out for r in eng.run(_reqs(prompts, max_new=4))]
-            info = eng.runner._prefill_wrapped.persist_info()
-            dinfo = eng.runner._decode_wrapped.persist_info()
-        return out, info, dinfo
-
-    def test_restart_reuses_persisted_transforms(self, model_params,
-                                                 tmp_path):
-        """Kill-and-restart: the second process must take byte-identical
-        transform decisions from disk and re-trace nothing."""
-        model, params = model_params
-        warm = tmp_path / "warm"
-        prompts = _prompts([5, 9, 13], seed=17)
-        out1, info1, dinfo1 = self._run_once(
-            model, params, warm, tmp_path / "m1", prompts)
-        assert info1.disk_misses > 0       # cold start wrote entries
-        files1 = {f: (warm / f).read_bytes()
-                  for f in os.listdir(warm) if f.endswith(".json")}
-        assert files1
-
-        out2, info2, dinfo2 = self._run_once(
-            model, params, warm, tmp_path / "m2", prompts)
+            sites = [(s.name, s.offloaded, s.splits)
+                     for s in eng.runner.sites_for(2, 24)]
+            runs.append((out, sites))
+        (out1, sites1), (out2, sites2) = runs
         assert out2 == out1
-        # No re-tracing at all: every program came from disk.
-        assert info2.disk_misses == 0
-        assert info2.disk_hits + info2.disk_decisions_hits > 0
-        assert dinfo2.disk_misses == 0
-        # Byte-identical persisted decisions after the restart.
-        files2 = {f: (warm / f).read_bytes()
-                  for f in os.listdir(warm) if f.endswith(".json")}
-        assert files2 == files1
-        for raw in files2.values():
-            json.loads(raw)  # stays valid JSON
+        assert sites2 == sites1
+        assert any(offloaded for _, offloaded, _ in sites1)
 
-    def test_obs_check_gates_on_cache_hit(self, model_params,
-                                          tmp_path):
-        """The CI smoke assertion: ``obs report --check
-        --expect-cache-hit`` passes on the warm run, fails on cold."""
+    def test_obs_check_passes_on_hook_counted_run(self, model_params,
+                                                  tmp_path):
+        """``obs report --check`` holds a serve run's offloaded sites to
+        the executions its site-event hook counted."""
         model, params = model_params
-        warm = tmp_path / "warm"
-        prompts = _prompts([6, 10], seed=18)
-        self._run_once(model, params, warm, tmp_path / "m1", prompts)
-        self._run_once(model, params, warm, tmp_path / "m2", prompts)
+        with MetricsRun(tmp_path) as run:
+            eng = self._engine(model, params, metrics=run)
+            eng.run(_reqs(_prompts([20, 24], seed=18), max_new=4))
         import io
         buf = io.StringIO()
-        # Cold run: sites executed but nothing came from disk.
-        assert obs_main(["report", str(tmp_path / "m1"), "--check",
-                         "--expect-cache-hit"], out=buf) == 1
-        assert "CHECK FAIL" in buf.getvalue()
-        buf = io.StringIO()
-        # Warm run: offloaded sites still execute (static accounting)
-        # AND the transform cache resolved from disk.
-        assert obs_main(["report", str(tmp_path / "m2"), "--check",
-                         "--expect-cache-hit"], out=buf) == 0, \
-            buf.getvalue()
+        assert obs_main(["report", str(tmp_path), "--check"],
+                        out=buf) == 0, buf.getvalue()
         assert "CHECK OK" in buf.getvalue()
+        events = load_runs(tmp_path)[run.run_id]
+        offloaded = {e["site"] for e in events
+                     if e["type"] == "site_decl" and e["offloaded"]}
+        execs = {e["labels"]["site"]: e["value"] for e in events
+                 if e["type"] == "metric" and e["name"] == "site_exec"}
+        assert offloaded
+        assert all(execs.get(site, 0) >= 1 for site in offloaded)
 
 
 class TestKVCacheManager:
